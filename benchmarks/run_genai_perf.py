@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Measure the genai-perf metric set against the live ``llama_decode`` model.
 
-Run on the TPU bench host (defaults) or CPU (JAX_PLATFORMS=cpu).  Prints the
+Run on the chip (defaults) or the CPU (JAX_PLATFORMS=cpu).  Prints the
 full report per concurrency level; the aggregate numbers extend BASELINE.md
 row 7 with TTFT/ITL percentiles.
 """
@@ -14,11 +14,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-if "JAX_PLATFORMS" in os.environ:
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
 from triton_client_tpu import genai_perf
 from triton_client_tpu.models import zoo

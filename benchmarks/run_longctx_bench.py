@@ -3,8 +3,9 @@
 
 Measures ``longctx_tpu`` p50 at the active preset's sequence length with the
 pallas flash kernel, and (optionally) with XLA fused attention for the same
-request (``--compare-xla`` restarts the harness with TRITON_TPU_FLASH=0 —
-the kernel choice binds at trace time).
+request.  The kernel choice binds at trace time and a chip belongs to one
+process at a time, so with ``--compare-xla`` this process stays off JAX and
+runs the two variants as children, one after the other.
 
     TRITON_TPU_LONGCTX_PRESET=xl python benchmarks/run_longctx_bench.py
 """
@@ -20,11 +21,6 @@ import time
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-if "JAX_PLATFORMS" in os.environ:
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
 
 def measure(n: int = 8) -> dict:
@@ -60,19 +56,19 @@ def main() -> None:
     parser = argparse.ArgumentParser()
     parser.add_argument("-n", type=int, default=8)
     parser.add_argument("--compare-xla", action="store_true",
-                        help="also measure with TRITON_TPU_FLASH=0 in a "
-                        "subprocess (kernel choice binds at trace time)")
+                        help="measure with the flash kernel and then with "
+                        "TRITON_TPU_FLASH=0, each in its own subprocess")
     args = parser.parse_args()
 
-    print(json.dumps(measure(args.n)))
-    if args.compare_xla:
-        import subprocess
+    if not args.compare_xla:
+        print(json.dumps(measure(args.n)))
+        return
+    import subprocess
 
-        env = dict(os.environ)
-        env["TRITON_TPU_FLASH"] = "0"
+    for flash in ("1", "0"):
         subprocess.run(
             [sys.executable, os.path.abspath(__file__), "-n", str(args.n)],
-            env=env, check=True)
+            env=dict(os.environ, TRITON_TPU_FLASH=flash), check=True)
 
 
 if __name__ == "__main__":

@@ -62,11 +62,59 @@ class TestClassifyRoofline:
         r = classify_roofline(10.0, 1.0, pf=1.0, pb=1.0)
         assert "pct_of_peak" not in r  # no compute window -> no pct
 
-    def test_env_peak_bytes_override(self, monkeypatch):
-        monkeypatch.setenv("TRITON_TPU_PEAK_BYTES_PER_S", "123.0")
-        assert costs.peak_bytes_per_s() == 123.0
+
+class TestDevicePeaks:
+    """One table keyed by device_kind; a device it does not list gets no
+    figure — never a default borrowed from another chip."""
+
+    def test_known_kind(self):
+        v5e = costs.device_peaks("TPU v5 lite")
+        assert v5e == {"bf16_flops": 197e12, "int8_ops": 393e12,
+                       "hbm_bytes_per_s": 819e9}
+
+    def test_unknown_kind_yields_none(self):
+        assert costs.device_peaks("TPU v99 imaginary") is None
+
+    def test_cpu_yields_no_row_and_no_verdict(self):
+        # the test process runs on the CPU: no peaks, so no roofline
+        # verdict from the defaults and no MFU from the collector
+        assert jax.local_devices()[0].platform == "cpu"
+        assert costs.device_peaks() is None
+        assert classify_roofline(1e12, 1e9, compute_s=1.0) is None
+        from triton_client_tpu.server.device_stats import \
+            DeviceStatsCollector
+
+        ds = DeviceStatsCollector(window_s=60.0)
+        ds._started_s = 0.0
+        ds.declare_model("m", 1e9)
+        ds.record_execute("m", 2, int(1e9), now=10.0)
+        assert ds.live_mfu("m", now=10.0) is None
+        assert ds.metric_rows(now=10.0)["live_mfu"] == []
+
+    def test_local_kind_resolves_through_the_table(self, monkeypatch):
+        monkeypatch.setattr(costs, "_local_device_kind",
+                            lambda: "TPU v5 lite")
+        r = classify_roofline(197e12, 1e9, compute_s=2.0)
+        # AI far above the 197e12/819e9 ridge: compute bound, and bf16
+        # work divides by the bf16 peak — 197 TFLOP in 2 s is 50%
+        assert r["verdict"] == "compute_bound"
+        assert r["ridge_point"] == pytest.approx(197e12 / 819e9, rel=1e-4)
+        assert r["pct_of_peak"] == pytest.approx(50.0)
+
+    def test_env_overrides_are_gone(self, monkeypatch):
+        # the table is the only source: a stray env value changes nothing
+        monkeypatch.setenv("TRITON_TPU_PEAK_FLOPS", "1")
         monkeypatch.setenv("TRITON_TPU_PEAK_BYTES_PER_S", "junk")
-        assert costs.peak_bytes_per_s() == costs.DEFAULT_PEAK_BYTES_PER_S
+        assert costs.device_peaks("TPU v5 lite")["bf16_flops"] == 197e12
+        assert not hasattr(costs, "peak_bytes_per_s")
+
+    def test_language_v5e_peak_is_the_bf16_row(self):
+        from triton_client_tpu.models import language
+
+        assert language.V5E_PEAK_FLOPS == 197e12
+        with pytest.raises(ValueError, match="DEVICE_PEAKS"):
+            # offline MFU on a device without a row is an error
+            language.serving_mfu(100.0, language.BERT_LARGE, 384, 2)
 
 
 class TestAnalyzeJaxCallable:
@@ -177,12 +225,16 @@ def _infer_moe(server):
 
 
 class TestMoeMfuEndToEnd:
-    def test_moe_tpu_gets_measured_mfu_on_cpu_standin(self, server):
+    def test_moe_tpu_gets_measured_mfu_on_cpu_standin(self, server,
+                                                      monkeypatch):
         # moe_tpu declares NO flops_per_inference (hand-counting the
         # routed expert FFNs would be wrong) — before XLA acquisition it
         # had no MFU at all; now the measured figure is the source.
         # Two infers: the first is the compile sighting (excluded from
-        # the MFU window), the second is steady-state compute.
+        # the MFU window), the second is steady-state compute.  The CPU
+        # has no peak-table row, so it stands in for a listed device.
+        monkeypatch.setattr(costs, "_local_device_kind",
+                            lambda: "TPU v5 lite")
         _infer_moe(server)
         _infer_moe(server)
         snap = server.core.device_stats.snapshot(model="moe_tpu")

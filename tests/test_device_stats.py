@@ -64,16 +64,21 @@ class TestCollector:
             ds.record_execute("m", 1, int(4e9), now=50.0)
         assert ds.duty_cycle("m", now=50.0) == 1.0
 
-    def test_live_mfu_counts_declared_flops_only(self):
+    def test_live_mfu_counts_declared_flops_only(self, monkeypatch):
+        from triton_client_tpu.server import costs
+
+        # the CPU has no peak-table row; stand in for a listed device
+        monkeypatch.setattr(costs, "_local_device_kind",
+                            lambda: "TPU v5 lite")
         ds = DeviceStatsCollector(window_s=60.0)
         ds._started_s = 0.0
         # no FLOPs declared: unknown, not 0%
         ds.record_execute("anon", 1, int(1e9), now=10.0)
         assert ds.live_mfu("anon", now=10.0) is None
-        # declared: flops/compute_s/peak
-        from triton_client_tpu.server.device_stats import peak_flops
-
-        ds.declare_model("m", peak_flops() / 4.0)  # per element
+        # declared: flops/compute_s/bf16 peak of the device kind
+        peak = costs.device_peaks()["bf16_flops"]
+        assert peak == 197e12
+        ds.declare_model("m", peak / 4.0)  # per element
         ds.record_execute("m", 2, int(1e9), now=10.0)  # 2 elements in 1s
         assert ds.live_mfu("m", now=10.0) == pytest.approx(0.5)
 
@@ -134,7 +139,12 @@ class TestCollector:
         assert snap["models"] == {} and snap["ticks"] == {}
         assert snap["transfers"] == {}
 
-    def test_metric_rows_cover_every_family_key(self):
+    def test_metric_rows_cover_every_family_key(self, monkeypatch):
+        from triton_client_tpu.server import costs
+
+        # live_mfu needs a peak-table row; the CPU has none
+        monkeypatch.setattr(costs, "_local_device_kind",
+                            lambda: "TPU v5 lite")
         ds = DeviceStatsCollector(window_s=60.0)
         ds._started_s = 0.0
         ds.declare_model("m", 1e9)
@@ -370,7 +380,8 @@ class TestSnapshotLimit:
 
 # -- end to end: server harness, both protocols, console views ---------------
 
-#: A tiny FLOPs declaration so nv_tpu_live_mfu materializes on CPU.
+#: A tiny FLOPs declaration so nv_tpu_live_mfu materializes once a test
+#: stands a peak-table device in for the CPU (which has no row).
 _FLOPS_PE = 1000.0
 
 
@@ -410,11 +421,20 @@ def _infer_batchy(server, n=1):
 
 
 class TestEndToEnd:
-    def test_metrics_expose_device_and_slo_series(self, server):
+    def test_metrics_expose_device_and_slo_series(self, server,
+                                                  monkeypatch):
+        from triton_client_tpu.server import costs
+
         _infer_batchy(server, n=3)
+        # the CPU has no peak-table row: no MFU gauge, never a default
         text = requests.get(
             f"http://{server.http_url}/metrics").text
         assert 'nv_tpu_duty_cycle{model="batchy"}' in text
+        assert 'nv_tpu_live_mfu{model="batchy"}' not in text
+        monkeypatch.setattr(costs, "_local_device_kind",
+                            lambda: "TPU v5 lite")
+        text = requests.get(
+            f"http://{server.http_url}/metrics").text
         assert 'nv_tpu_live_mfu{model="batchy"}' in text
         assert 'nv_tpu_tick_total{model="batchy",bucket="4"}' in text
         assert 'nv_tpu_pad_waste_ratio{model="batchy",bucket="4"}' in text

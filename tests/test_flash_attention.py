@@ -114,7 +114,7 @@ def test_matches_ring_attention_single_shard():
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("sp",))
     from jax.sharding import PartitionSpec as P
 
-    ring = parallel.shard_map(
+    ring = jax.shard_map(
         lambda q, k, v: tr._ring_attention(q, k, v, cfg),
         mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(),
         check_vma=False,

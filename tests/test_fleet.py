@@ -853,11 +853,18 @@ class TestSupervisorSelfHealing:
                 time.sleep(0.5)
             assert restart_total() >= 1, "".join(lines[-30:])
 
-            # ...and in triton-top (the fleet header counter)
-            buf = io.StringIO()
-            with redirect_stdout(buf):
-                rc = top_main(["--url", f"127.0.0.1:{metrics_port}",
-                               "--once", "--json"])
+            # ...and in triton-top (the fleet header counter); the sibling
+            # may have reported the restart before the restarted worker
+            # has rebound the port triton-top polls
+            deadline = time.time() + 30
+            while True:
+                buf = io.StringIO()
+                with redirect_stdout(buf):
+                    rc = top_main(["--url", f"127.0.0.1:{metrics_port}",
+                                   "--once", "--json"])
+                if rc == 0 or time.time() > deadline:
+                    break
+                time.sleep(0.5)
             assert rc == 0
             snap = json.loads(buf.getvalue())
             assert snap["worker_restarts"] >= 1

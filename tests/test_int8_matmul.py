@@ -67,27 +67,38 @@ class TestKernelMatchesReference:
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-class TestFallbacks:
+class TestNoSilentFallback:
     def test_cpu_backend_uses_reference(self):
-        # no interpret/force on CPU -> identical to reference (bitwise)
+        # no interpret/force on CPU -> identical to reference (bitwise):
+        # the kernel cannot compile off TPU
         x, w, ws = _mk(16, 128, 128, seed=4)
         got = int8_matmul(x, w, ws)
         want = int8_matmul_reference(x, w, ws)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
-    def test_unaligned_k_falls_back(self):
-        # K % 128 != 0 can't take the kernel; reference path, right answer
+    def test_unaligned_k_raises_when_kernel_selected(self):
+        # K % 128 != 0 can't take the kernel: selected (interpret/force)
+        # it raises — explicit blocks or not — instead of running XLA
         x, w, ws = _mk(16, 96, 128, seed=5)
-        got = int8_matmul(x, w, ws, interpret=True)
-        want = int8_matmul_reference(x, w, ws)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert not _mod.fits(96, 128)
+        with pytest.raises(ValueError, match="does not fit the kernel"):
+            int8_matmul(x, w, ws, interpret=True)
+        with pytest.raises(ValueError, match="does not fit the kernel"):
+            int8_matmul(x, w, ws, block_m=32, block_n=128, force=True)
 
-    def test_huge_k_falls_back(self, monkeypatch):
+    def test_huge_k_raises_when_kernel_selected(self, monkeypatch):
         monkeypatch.setattr(_mod, "_MAX_RESIDENT_K", 64)
         x, w, ws = _mk(16, 128, 128, seed=6)
-        got = int8_matmul(x, w, ws, interpret=True)
-        want = int8_matmul_reference(x, w, ws)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        with pytest.raises(ValueError, match="does not fit the kernel"):
+            int8_matmul(x, w, ws, interpret=True)
+
+    def test_forced_on_cpu_raises_not_reference(self):
+        # force = "the kernel or an error": off TPU the compiled kernel
+        # cannot run, and the call must say so
+        x, w, ws = _mk(32, 128, 128, seed=7)
+        with pytest.raises(Exception) as ei:
+            jax.block_until_ready(int8_matmul(x, w, ws, force=True))
+        assert not isinstance(ei.value, AssertionError)
 
 
 class TestQuantizationSemantics:

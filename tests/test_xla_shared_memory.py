@@ -6,8 +6,16 @@ DLPack round-trips with a framework as the interop oracle, numpy set/get,
 serialized BYTES — plus the full cudashm-client end-to-end flow of
 simple_grpc_cudashm_client.py (SURVEY.md §3.5) against the in-process
 harness, where tensors stay device-resident (zero host copy on the infer
-path).
+path), and the out-of-process contract: a client of a server that owns the
+chip works through the staging region alone and never imports JAX.
 """
+
+import os
+import signal
+import subprocess
+import sys
+import time
+import urllib.request
 
 import numpy as np
 import pytest
@@ -94,7 +102,22 @@ class TestNumpy:
 
     def test_invalid_device(self):
         with pytest.raises(xlashm.XlaSharedMemoryException):
-            xlashm.create_shared_memory_region("bad_dev", 64, 99)
+            xlashm.create_shared_memory_region("bad_dev", 64, -1)
+
+    def test_staging_only_region_binds_no_device_slot(self):
+        # no server in this process: the region IS its staging region —
+        # nothing device-side exists, the broker never hears of it
+        src = np.arange(4, dtype=np.int32)
+        assert not broker().server_present
+        h = xlashm.create_shared_memory_region("no_slot", src.nbytes, 0)
+        try:
+            xlashm.set_shared_memory_region(h, [src])
+            assert h.array is None
+            assert broker().lookup(h._uuid) is None
+            np.testing.assert_array_equal(
+                xlashm.get_contents_as_numpy(h, np.int32, [4]), src)
+        finally:
+            xlashm.destroy_shared_memory_region(h)
 
     def test_offset_write_preserves_prior_contents(self):
         # Regression: an offset write after a typed single-value write must
@@ -116,8 +139,9 @@ class TestNumpy:
 
 
 class TestStagingImport:
-    """Cross-process import path: the server-side registry must fall back to
-    the host staging region when the broker slot is not in its process."""
+    """Cross-process import path: a region created with no server in the
+    process has no broker slot, so the server-side registry imports it
+    through the host staging region."""
 
     def test_registry_staging_read(self):
         from triton_client_tpu.server.shm import XlaShmRegistry
@@ -127,10 +151,8 @@ class TestStagingImport:
         h = xlashm.create_shared_memory_region("staging_r", src.nbytes, 0)
         try:
             assert not broker().server_present
-            xlashm.set_shared_memory_region(h, [src])  # writes staging too
+            xlashm.set_shared_memory_region(h, [src])  # writes staging
             raw = xlashm.get_raw_handle(h)
-            # simulate another process: hide the broker slot
-            broker().drop(h._uuid)
             reg = XlaShmRegistry()
             reg.register("staging_r", raw, 0, src.nbytes)
             arr = reg.read(ShmRef("staging_r", src.nbytes, 0), "FP32", (8,))
@@ -151,7 +173,6 @@ class TestStagingImport:
         try:
             xlashm.set_shared_memory_region(h, [src])
             raw = xlashm.get_raw_handle(h)
-            broker().drop(h._uuid)  # simulate another process
             reg = XlaShmRegistry()
             reg.register("cache_r", raw, 0, src.nbytes)
             ref = ShmRef("cache_r", src.nbytes, 0)
@@ -265,6 +286,9 @@ class TestEndToEnd:
         h1 = xlashm.create_shared_memory_region("zc_in1", nbytes, 0)
         try:
             assert broker().server_present  # harness marks co-located mode
+            with pytest.raises(xlashm.XlaSharedMemoryException):
+                # co-located, the device is this process's own: validated
+                xlashm.create_shared_memory_region("bad_dev", 64, 99)
             xlashm.set_shared_memory_region_from_dlpack(h0, [src])
             xlashm.set_shared_memory_region_from_dlpack(h1, [ones])
             # same PjRt buffer, not a copy
@@ -285,6 +309,104 @@ class TestEndToEnd:
             xlashm.destroy_shared_memory_region(h0)
             xlashm.destroy_shared_memory_region(h1)
             client.close()
+
+
+_OUT_OF_PROCESS_CLIENT = r"""
+import sys
+import numpy as np
+import triton_client_tpu.grpc as grpcclient
+import triton_client_tpu.utils.cuda_shared_memory as cudashm
+from triton_client_tpu.perf_analyzer import _make_data, _resolve_model, run_level
+
+grpc_url = sys.argv[1]
+# examples/simple_grpc_cudashm_client.py, as its own process
+client = grpcclient.InferenceServerClient(grpc_url)
+client.unregister_cuda_shared_memory()
+a = np.arange(16, dtype=np.int32).reshape(1, 16)
+b = np.ones((1, 16), dtype=np.int32)
+handles = {}
+for name in ("input0_data", "input1_data", "output0_data", "output1_data"):
+    handles[name] = cudashm.create_shared_memory_region(name, a.nbytes, 0)
+    client.register_cuda_shared_memory(
+        name, cudashm.get_raw_handle(handles[name]), 0, a.nbytes)
+cudashm.set_shared_memory_region(handles["input0_data"], [a])
+cudashm.set_shared_memory_region(handles["input1_data"], [b])
+inputs = [grpcclient.InferInput("INPUT0", [1, 16], "INT32"),
+          grpcclient.InferInput("INPUT1", [1, 16], "INT32")]
+inputs[0].set_shared_memory("input0_data", a.nbytes)
+inputs[1].set_shared_memory("input1_data", a.nbytes)
+outputs = [grpcclient.InferRequestedOutput("OUTPUT0"),
+           grpcclient.InferRequestedOutput("OUTPUT1")]
+outputs[0].set_shared_memory("output0_data", a.nbytes)
+outputs[1].set_shared_memory("output1_data", a.nbytes)
+client.infer("simple", inputs, outputs=outputs)
+assert np.array_equal(cudashm.get_contents_as_numpy(
+    handles["output0_data"], np.int32, [1, 16]), a + b)
+assert np.array_equal(cudashm.get_contents_as_numpy(
+    handles["output1_data"], np.int32, [1, 16]), a - b)
+client.unregister_cuda_shared_memory()
+for h in handles.values():
+    cudashm.destroy_shared_memory_region(h)
+assert not cudashm.allocated_shared_memory_regions()
+# tpu-perf-analyzer -m dense_tpu --shared-memory=xla, the README quickstart
+pa_inputs, pa_outputs, max_batch = _resolve_model(client, "grpc", "dense_tpu", "")
+arrays = _make_data(pa_inputs, {}, 1, max_batch, np.random.default_rng(0))
+client.close()
+res = run_level("grpc", grpc_url, "dense_tpu", "", 2, arrays, pa_outputs,
+                "xla", 1 << 16, 1.0, warmup_s=0.5)
+assert res["errors"] == 0 and res["throughput"] > 0, res
+assert "jax" not in sys.modules, "the xla-shm client imported jax"
+print("OUT-OF-PROCESS-OK")
+"""
+
+
+class TestOutOfProcessClient:
+    """The README quickstart topology: a CLI server owns the device, the
+    xla-shm client is another process.  The client must complete the
+    cudashm example flow and a perf_analyzer xla-shm level without ever
+    importing JAX — on a chip, a second process opening the backend fails
+    (the libtpu lock), so "never imports" is the contract that keeps the
+    quickstart working."""
+
+    def test_subprocess_client_never_imports_jax(self):
+        from triton_client_tpu.server.testing import free_port
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
+        http_port, grpc_port = free_port(), free_port()
+        server = subprocess.Popen(
+            [sys.executable, "-m", "triton_client_tpu.server", "--zoo",
+             "--host", "127.0.0.1", "--http-port", str(http_port),
+             "--grpc-port", str(grpc_port), "--metrics-port", "0"],
+            env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+            text=True)
+        try:
+            deadline = time.monotonic() + 120
+            while True:
+                assert server.poll() is None, server.stdout.read()[-2000:]
+                assert time.monotonic() < deadline, "server never ready"
+                try:
+                    with urllib.request.urlopen(
+                            f"http://127.0.0.1:{http_port}/v2/health/ready",
+                            timeout=2) as r:
+                        if r.status == 200:
+                            break
+                except OSError:
+                    time.sleep(0.2)
+            client = subprocess.run(
+                [sys.executable, "-c", _OUT_OF_PROCESS_CLIENT,
+                 f"127.0.0.1:{grpc_port}"],
+                env=env, capture_output=True, text=True, timeout=120)
+            assert client.returncode == 0, client.stderr[-3000:]
+            assert "OUT-OF-PROCESS-OK" in client.stdout
+        finally:
+            server.send_signal(signal.SIGTERM)
+            try:
+                server.wait(timeout=30)
+            except subprocess.TimeoutExpired:
+                server.kill()
+                server.wait(timeout=10)
 
 
 def _status_names(status):

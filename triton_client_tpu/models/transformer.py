@@ -482,7 +482,7 @@ def _attn_apply(blk, x, cfg: TransformerConfig):
     Sc = x.shape[1]
     positions = lax.axis_index("sp") * Sc + jnp.arange(Sc)
     q, k = _rope(q, k, positions, cfg.rope_theta)
-    if (parallel.axis_size("sp") == 1 and _flash_enabled()
+    if (lax.axis_size("sp") == 1 and _flash_enabled()
             and q.shape[2] >= _flash_min_s()):
         # full LONG sequence on-device: the pallas flash kernel (ops/)
         # replaces the cross-device ring — identical online-softmax math,
@@ -536,11 +536,14 @@ def _ffn_apply(blk, x, cfg: TransformerConfig):
         # dense FFN on the int8 MXU path (see _attn_apply); both matmuls
         # are 2D row-quantized GEMMs with no layout change around them,
         # so they take the fused quantize+matmul pallas kernel — the
-        # int8 activation copy never round-trips HBM
-        fused = _int8_fused_mode()
-        if fused:
-            from ..ops import int8_matmul
+        # int8 activation copy never round-trips HBM — wherever its
+        # full-K-resident design takes the shape.  The choice is made
+        # here, from the shape: the kernel raises on a shape it cannot
+        # take rather than quietly running something else
+        from ..ops import int8_matmul, int8_matmul_fits
 
+        fused = frozenset(name for name in _int8_fused_mode()
+                          if int8_matmul_fits(*blk[name].shape))
         if "w1" in fused:
             he = int8_matmul(h, blk["w1"], blk["w1_scale"])
         else:
@@ -598,7 +601,7 @@ def _pipeline_apply(params, x_mbs, cfg: TransformerConfig):
     x_mbs: [n_micro, mb, Sc, D] embedded microbatches (identical on every pp
     rank).  Returns [n_micro, mb, Sc, D] — valid only on the LAST stage;
     other stages hold garbage that callers must mask."""
-    pp = parallel.axis_size("pp")
+    pp = lax.axis_size("pp")
     stage = lax.axis_index("pp")
     n_micro = x_mbs.shape[0]
     steps = n_micro + pp - 1
@@ -643,7 +646,7 @@ def _local_loss(params, tokens, labels, cfg: TransformerConfig,
     logp = jax.nn.log_softmax(logits, axis=-1)
     lab = labels.reshape(n_micro, mb, Sc)
     nll = -jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
-    is_last = (lax.axis_index("pp") == parallel.axis_size("pp") - 1)
+    is_last = (lax.axis_index("pp") == lax.axis_size("pp") - 1)
     local_sum = jnp.where(is_last, jnp.sum(nll), 0.0)
     return local_sum
 
@@ -721,7 +724,7 @@ def make_grad_fn(mesh: Mesh, cfg: TransformerConfig, n_micro: int = 2):
         grads = {k: g / (count * compute_scale) for k, g in grads.items()}
         return grads, loss / count
 
-    return jax.jit(parallel.shard_map(
+    return jax.jit(jax.shard_map(
         local_grads, mesh=mesh,
         in_specs=(specs, P("dp", "sp"), P("dp", "sp")),
         out_specs=(specs, P()),
@@ -750,7 +753,7 @@ def make_train_step(mesh: Mesh, cfg: TransformerConfig, n_micro: int = 2,
         params, opt = _adam_update(params, grads, opt, lr=lr)
         return params, opt, loss
 
-    sharded = parallel.shard_map(
+    sharded = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(specs, ospecs, P("dp", "sp"), P("dp", "sp")),
         out_specs=(specs, ospecs, P()),
@@ -777,7 +780,7 @@ def make_forward(mesh: Mesh, cfg: TransformerConfig, n_micro: int = 1,
         x = jnp.take(params["embed"].astype(cfg.dtype), tokens, axis=0)
         x_mbs = x.reshape(n_micro, mb, Sc, cfg.d_model)
         outs = _pipeline_apply(params, x_mbs, cfg)
-        is_last = (lax.axis_index("pp") == parallel.axis_size("pp") - 1)
+        is_last = (lax.axis_index("pp") == lax.axis_size("pp") - 1)
         outs = jnp.where(is_last, outs, 0.0).astype(jnp.float32)
         outs = lax.psum(outs, "pp").astype(cfg.dtype)
         h = _rmsnorm(outs, params["final_ln"], cfg.norm_eps)
@@ -788,7 +791,7 @@ def make_forward(mesh: Mesh, cfg: TransformerConfig, n_micro: int = 1,
                             head.astype(jnp.float32))
         return logits.reshape(Bl, Sc, head.shape[-1])
 
-    sharded = parallel.shard_map(
+    sharded = jax.shard_map(
         local_fwd, mesh=mesh,
         in_specs=(specs, P("dp", "sp")),
         out_specs=P("dp", "sp", None),

@@ -7,7 +7,8 @@ kernels with jnp fallbacks for non-TPU backends.
 """
 
 from .flash_attention import flash_attention, flash_attention_reference
+from .int8_matmul import fits as int8_matmul_fits
 from .int8_matmul import int8_matmul, int8_matmul_reference
 
 __all__ = ["flash_attention", "flash_attention_reference",
-           "int8_matmul", "int8_matmul_reference"]
+           "int8_matmul", "int8_matmul_fits", "int8_matmul_reference"]

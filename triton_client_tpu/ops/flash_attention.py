@@ -12,9 +12,12 @@ q-block plus the head's full K/V in VMEM and loops over k-blocks with a
 the cross-device ring in ``_ring_attention`` (same math, one chip).
 
 ``flash_attention`` pads S to the block size and masks the padding away, so
-any sequence length works. On non-TPU backends it falls back to the jnp
-reference implementation unless ``interpret=True`` (used by tests to run
-the kernel itself on CPU).
+any sequence length works. On a TPU backend the kernel is compiled by
+Mosaic and whatever the compiler refuses is raised to the caller — there
+is no fallback there. Off TPU, where the kernel cannot compile, callers get
+the jnp reference; ``interpret=True`` runs the kernel itself in the pallas
+interpreter (tests only — nothing under ``server/`` or ``models/`` passes
+it) and ``force=True`` means "the compiled kernel or an error" anywhere.
 """
 
 from __future__ import annotations
@@ -183,9 +186,11 @@ def flash_attention(
     block_k: int = 0, interpret: bool = False, force: bool = False):
     """Flash attention over [B, H, S, D] tensors; differentiable.
 
-    On TPU backends this runs the pallas kernel; elsewhere it falls back to
-    :func:`flash_attention_reference` unless ``interpret`` (run the kernel
-    in the pallas interpreter — slow, for tests) or ``force`` is set.
+    On a TPU backend (or with ``force``) this runs the compiled pallas
+    kernel, and a Mosaic refusal raises — no fallback.  Off TPU, with
+    neither flag, it returns :func:`flash_attention_reference` (the
+    kernel cannot compile there); ``interpret`` runs the kernel in the
+    pallas interpreter — slow, for tests.
 
     ``block_q``/``block_k`` of 0 pick measured-good defaults: 256/512 for
     long sequences (3-4x faster than XLA's fused attention at S>=2048 on

@@ -24,9 +24,13 @@ innermost (weight block resident, activations stream and re-quantize per
 visit — measured a loss on the BERT shapes, kept for other geometries;
 benchmarks/BERT_PROFILE.md §6).
 
-Like the flash kernel (``ops/flash_attention.py``) this falls back to the
-plain-jnp reference off-TPU; ``interpret=True`` runs the kernel itself on
-CPU for tests.
+Like the flash kernel (``ops/flash_attention.py``): on a TPU backend the
+kernel is compiled by Mosaic or the call raises — a shape the full-K
+design cannot take (:func:`fits`) is a ``ValueError``, never a silent
+switch to the reference, so the caller selects by shape up front
+(``models/transformer.py`` does).  Off TPU, where the kernel cannot
+compile, callers get the plain-jnp reference; ``interpret=True`` runs the
+kernel itself in the pallas interpreter (tests only).
 """
 
 from __future__ import annotations
@@ -36,9 +40,15 @@ import functools
 import jax
 import jax.numpy as jnp
 
-# VMEM ceiling per program is ~16 MB; beyond this K the full-row design
-# would not fit and the caller gets the XLA path instead.
+# Beyond this K the full-row design does not fit VMEM at any useful
+# block_m; ``fits`` says so and the caller takes the XLA path instead.
 _MAX_RESIDENT_K = 8192
+
+
+def fits(k: int, n: int) -> bool:
+    """Whether a [.., k] @ [k, n] matmul can take the kernel: both dims
+    lane-aligned and the contraction small enough to stay VMEM-resident."""
+    return k <= _MAX_RESIDENT_K and k % 128 == 0 and n % 128 == 0
 
 
 def int8_matmul_reference(x, w_q, w_scale):
@@ -74,10 +84,25 @@ def _kernel(x_ref, w_ref, ws_ref, o_ref):
     o_ref[:] = (acc.astype(jnp.float32) * xs * ws_ref[:]).astype(o_ref.dtype)
 
 
+def _vmem_limit_bytes(block_m: int, block_n: int, k: int,
+                      itemsize: int) -> int:
+    """Scoped-VMEM request for one program, from its shapes: the
+    double-buffered x/w/out blocks plus the in-kernel f32 working copies,
+    the int8 codes and the s32 accumulator, with a quarter of headroom.
+    Mosaic's default scoped limit (16 MiB on v5e) refuses a shape the
+    :func:`fits` gate admits — K=8192 at block_m 256 x block_n 512 asks
+    for 16.50M — so the request is explicit."""
+    blocks = 2 * (block_m * k * itemsize + k * block_n
+                  + block_m * block_n * itemsize)
+    working = block_m * k * (4 + 4 + 1) + 2 * block_m * block_n * 4
+    return max(16 << 20, min(96 << 20, (blocks + working) * 5 // 4))
+
+
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n",
                                               "m_inner", "interpret"))
 def _call(x2d, w_q, ws_row, block_m, block_n, m_inner, interpret):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     M, K = x2d.shape
     N = w_q.shape[1]
@@ -109,6 +134,9 @@ def _call(x2d, w_q, ws_row, block_m, block_n, m_inner, interpret):
             pl.BlockSpec((1, block_n), w_map),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), o_map),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit_bytes(
+                block_m, block_n, K, x2d.dtype.itemsize)),
         interpret=interpret,
     )(x2d, w_q, ws_row)
     return out[:M] if pad_m else out
@@ -119,10 +147,13 @@ def int8_matmul(x, w_q, w_scale, *, block_m: int = 0, block_n: int = 0,
                 force: bool = False):
     """Dynamically-quantized int8 matmul: [..., K] @ [K, N] -> [..., N].
 
-    On TPU backends runs the fused pallas kernel; elsewhere falls back to
-    :func:`int8_matmul_reference` unless ``interpret`` (pallas interpreter,
-    for tests) or ``force``.  Also falls back when the shape doesn't fit
-    the kernel's full-K-resident design (K > 8192 or K/N not lane-aligned).
+    On a TPU backend (or with ``force``) runs the compiled pallas kernel;
+    ``interpret`` runs it in the pallas interpreter (tests).  Whenever the
+    kernel is the selected path, a shape it cannot take (:func:`fits`:
+    K > 8192 or K/N not lane-aligned) raises ``ValueError`` and a Mosaic
+    refusal propagates — no silent switch to the reference.  Only off
+    TPU, with neither flag, does the call return
+    :func:`int8_matmul_reference` (the kernel cannot compile there).
 
     ``block_m``/``block_n`` of 0 pick measured defaults: the
     weight-resident schedule (bm=256, bn=N) when the whole weight fits
@@ -131,9 +162,13 @@ def int8_matmul(x, w_q, w_scale, *, block_m: int = 0, block_n: int = 0,
     """
     K = x.shape[-1]
     N = w_q.shape[1]
-    on_tpu = interpret or force or jax.default_backend() == "tpu"
-    if not on_tpu or K > _MAX_RESIDENT_K or K % 128 or N % 128:
+    if not (interpret or force or jax.default_backend() == "tpu"):
         return int8_matmul_reference(x, w_q, w_scale)
+    if not fits(K, N):
+        raise ValueError(
+            f"int8_matmul: [.., {K}] @ [{K}, {N}] does not fit the kernel "
+            f"(needs K <= {_MAX_RESIDENT_K} and K, N multiples of 128); "
+            "select by shape with ops.int8_matmul_fits()")
     import os
     blocks_env = os.environ.get("TRITON_TPU_INT8_BLOCKS", "")
     if blocks_env and block_m == 0 and block_n == 0:
@@ -183,6 +218,8 @@ def int8_matmul(x, w_q, w_scale, *, block_m: int = 0, block_n: int = 0,
     # clamp to M, then round up to a sublane multiple: a small unaligned M
     # (e.g. 50) must not produce a Mosaic block like (50, K) — _call's
     # pad_m already covers M < block_m, so rounding up is always safe
+    # (Mosaic takes the resulting 56-row block, int8 codes included:
+    # compiled and bit-exact on v5e)
     block_m = min(block_m, max(8, M))
     block_m = -(-block_m // 8) * 8
     ws_row = w_scale.reshape(1, N).astype(jnp.float32)

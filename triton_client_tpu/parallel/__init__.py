@@ -15,8 +15,8 @@ The flagship transformer (models/transformer.py) composes these into its
 5-axis (dp, pp, ep, sp, tp) training/forward step.
 """
 
-from .collectives import (axis_size, replicated_axes, ring_attention,
-                          shard_map, sync_replicated_grads)
+from .collectives import (replicated_axes, ring_attention,
+                          sync_replicated_grads)
 from .mesh import build_mesh, factorize_mesh
 from .multihost import initialize_multihost
 

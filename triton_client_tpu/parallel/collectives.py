@@ -16,30 +16,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions: the top-level API (with its
-    ``check_vma`` kwarg) landed after 0.4.x; older releases ship it as
-    ``jax.experimental.shard_map.shard_map`` with the same semantics
-    under the ``check_rep`` kwarg."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_sm
-
-    return legacy_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
-
-
-def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis inside shard_map.  ``lax.axis_size``
-    only exists on newer jax; on older releases ``psum(1, axis)`` folds to
-    the same static int."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def ring_attention(q, k, v, axis_name: str, causal: bool = True):
     """Causal ring attention over a sequence-parallel mesh axis.
 
@@ -49,7 +25,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = True):
     device — the TPU-native long-context mechanism (ICI ring instead of the
     reference's server-side sequence offload; SURVEY.md §5).
     """
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     B, Hl, Sc, Kd = q.shape
     scale = 1.0 / math.sqrt(Kd)
@@ -82,11 +58,8 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = True):
     o0 = jnp.zeros((B, Hl, Sc, Kd), jnp.float32)
     # constants entering the loop carry become axis-varying inside the body;
     # mark them so strict shard_map (check_vma=True) accepts the carry types
-    if hasattr(lax, "pcast"):
-        m0, l0, o0 = (lax.pcast(x, (axis_name,), to="varying")
-                      for x in (m0, l0, o0))
-    elif hasattr(lax, "pvary"):  # older jax
-        m0, l0, o0 = (lax.pvary(x, (axis_name,)) for x in (m0, l0, o0))
+    m0, l0, o0 = (lax.pcast(x, (axis_name,), to="varying")
+                  for x in (m0, l0, o0))
     _, _, _, l, o = lax.fori_loop(0, sp, body, (k, v, m0, l0, o0))
     out = o / jnp.maximum(l, 1e-30)[..., None]
     return out.astype(q.dtype)

@@ -16,10 +16,11 @@ not hand math — the TPU-native premise:
   experts), gets a live MFU from the FLOPs XLA actually scheduled.
 
 * :func:`classify_roofline` places a (FLOPs, bytes) pair against the
-  chip ridge point — ``peak_flops() / peak_bytes_per_s()`` — into a
-  ``compute_bound`` / ``memory_bound`` verdict with arithmetic
-  intensity and, when a measured compute window is supplied, the
-  achieved fraction of the *bound* resource's peak.
+  chip ridge point — bf16 peak FLOP/s over peak HBM bytes/s, both from
+  :data:`DEVICE_PEAKS` — into a ``compute_bound`` / ``memory_bound``
+  verdict with arithmetic intensity and, when a measured compute window
+  is supplied, the achieved fraction of the *bound* resource's peak.
+  A device the table does not list gets no verdict.
 
 * :class:`CostLedger` accumulates per-(model, tenant) device-time,
   FLOPs, generated tokens, and KV byte-seconds.  Attribution sites
@@ -38,39 +39,53 @@ undeclared-FLOPs models.
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Mapping, Optional, Tuple
 
 __all__ = [
     "CostLedger",
+    "DEVICE_PEAKS",
     "SignatureCost",
     "analysis_enabled",
     "analyze_jax_callable",
     "classify_roofline",
+    "device_peaks",
     "executable_cost",
     "merge_cost_snapshots",
-    "peak_bytes_per_s",
 ]
 
-#: v5e HBM bandwidth (~819 GB/s) — the default roofline denominator's
-#: memory leg, paired with device_stats.DEFAULT_PEAK_FLOPS for the
-#: compute leg.  Override with ``TRITON_TPU_PEAK_BYTES_PER_S`` the same
-#: way ``TRITON_TPU_PEAK_FLOPS`` overrides peak FLOPs.
-DEFAULT_PEAK_BYTES_PER_S = 819e9
+#: Published single-chip peaks keyed by ``jax.Device.device_kind`` — the
+#: ONE source of every MFU and roofline denominator in the repo.
+#: ``TPU v5 lite`` is how JAX names a v5e chip; figures from Google Cloud
+#: documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8, 819 GB/s
+#: HBM.  A device that is not listed gets NO figure (the gauge is absent,
+#: as ``hbm_stats`` does for backends without memory stats) — never a
+#: default borrowed from another chip.
+DEVICE_PEAKS: Dict[str, Mapping[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_per_s": 819e9},
+}
 
 
-def peak_bytes_per_s() -> float:
-    """Chip peak memory bandwidth for roofline ridge points:
-    ``TRITON_TPU_PEAK_BYTES_PER_S`` env override, else
-    :data:`DEFAULT_PEAK_BYTES_PER_S`."""
-    env = os.environ.get("TRITON_TPU_PEAK_BYTES_PER_S")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    return DEFAULT_PEAK_BYTES_PER_S
+@functools.lru_cache(maxsize=1)
+def _local_device_kind() -> str:
+    # cached: the batcher asks per executed batch, and a process's device
+    # does not change
+    import jax
+
+    return jax.local_devices()[0].device_kind
+
+
+def device_peaks(device_kind: Optional[str] = None
+                 ) -> Optional[Mapping[str, float]]:
+    """:data:`DEVICE_PEAKS` row for ``device_kind`` (default: this
+    process's first local device); None for a device the table does not
+    list — callers then report nothing rather than a wrong ratio."""
+    if device_kind is None:
+        device_kind = _local_device_kind()
+    return DEVICE_PEAKS.get(device_kind)
 
 
 def analysis_enabled() -> bool:
@@ -162,16 +177,30 @@ def executable_cost(compiled: Any) -> Optional[SignatureCost]:
 def analyze_jax_callable(fn: Any, *args: Any,
                          **kwargs: Any) -> Optional[SignatureCost]:
     """AOT-lower ``fn`` on concrete example arguments and extract its
-    cost.  ``fn`` may be a raw callable (wrapped in ``jax.jit`` for
-    lowering only — nothing executes) or an already-jitted function.
+    cost.  ``fn`` may be an already-jitted function or a raw callable
+    (lowered through a fresh ``jax.jit`` — nothing executes).  A raw
+    serving callable closes over its weights; jitting it as it stands
+    would bake them into the executable as constants — on the chip that
+    was a 2.4 GB program per ``bert_large`` signature, minutes to compile
+    and gigabytes in the compile cache, for a pass that only reads two
+    numbers off it.  So the callable is traced once and its jaxpr compiled
+    with the closed-over arrays passed as ARGUMENTS: same FLOPs and bytes
+    accessed, the weights counted where they live.
     None when jax/the backend can't oblige; never raises."""
     if not analysis_enabled():
         return None
     try:
         import jax
 
-        jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
-        compiled = jitted.lower(*args, **kwargs).compile()
+        if hasattr(fn, "lower"):
+            compiled = fn.lower(*args, **kwargs).compile()
+        else:
+            closed = jax.make_jaxpr(fn)(*args, **kwargs)
+            compiled = jax.jit(
+                lambda consts, *flat: jax.core.eval_jaxpr(
+                    closed.jaxpr, consts, *flat)
+            ).lower(closed.consts,
+                    *jax.tree_util.tree_leaves((args, kwargs))).compile()
     except Exception:  # noqa: BLE001
         return None
     return executable_cost(compiled)
@@ -184,20 +213,22 @@ def classify_roofline(flops: float, bytes_accessed: float,
     """Roofline verdict for a (FLOPs, bytes) workload point.
 
     ``arithmetic_intensity`` (FLOPs/byte) against the ridge point
-    ``peak_flops / peak_bytes_per_s``: at or above the ridge the chip's
-    compute ceiling binds (``compute_bound``), below it the memory
-    ceiling does (``memory_bound``).  With a measured ``compute_s``
-    window, ``pct_of_peak`` reports the achieved fraction (in percent)
-    of the *bound* resource's peak — how close the workload runs to the
-    roof it actually sits under.  None when either axis is unknown."""
+    ``pf / pb`` (default: the local device's bf16 peak FLOP/s over its
+    peak HBM bytes/s, :func:`device_peaks`): at or above the ridge the
+    chip's compute ceiling binds (``compute_bound``), below it the
+    memory ceiling does (``memory_bound``).  With a measured
+    ``compute_s`` window, ``pct_of_peak`` reports the achieved fraction
+    (in percent) of the *bound* resource's peak — how close the workload
+    runs to the roof it actually sits under.  None when either axis is
+    unknown, or the device has no :data:`DEVICE_PEAKS` row."""
     if flops <= 0.0 or bytes_accessed <= 0.0:
         return None
-    if pf is None:
-        from .device_stats import peak_flops
-
-        pf = peak_flops()
-    if pb is None:
-        pb = peak_bytes_per_s()
+    if pf is None or pb is None:
+        peaks = device_peaks()
+        if peaks is None:
+            return None
+        pf = peaks["bf16_flops"] if pf is None else pf
+        pb = peaks["hbm_bytes_per_s"] if pb is None else pb
     if pf <= 0.0 or pb <= 0.0:
         return None
     ai = flops / bytes_accessed

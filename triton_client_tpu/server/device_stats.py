@@ -48,7 +48,6 @@ synthetic time, never wall-clock sleeps.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 import warnings
@@ -56,14 +55,13 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from .costs import SignatureCost, classify_roofline
+from .costs import SignatureCost, classify_roofline, device_peaks
 
 __all__ = [
     "DeviceStatsCollector",
     "SloEngine",
     "SloObjective",
     "parse_slo_spec",
-    "peak_flops",
 ]
 
 #: Burn-rate windows (label -> seconds).  5m/1h is the classic fast-burn
@@ -77,24 +75,12 @@ SLO_WINDOWS: Dict[str, float] = {"5m": 300.0, "1h": 3600.0}
 DEFAULT_BURN_THRESHOLD = 14.4
 
 
-#: v5e bf16 single-chip peak — the repo's ONE default MFU denominator.
-#: ``models.language`` re-exports it as ``V5E_PEAK_FLOPS`` and its
-#: ``serving_mfu`` resolves through :func:`peak_flops`, so the live
-#: ``nv_tpu_live_mfu`` gauge and every offline MFU number share a
-#: denominator by construction.
-DEFAULT_PEAK_FLOPS = 394e12
-
-
-def peak_flops() -> float:
-    """Chip peak FLOP/s for MFU denominators: ``TRITON_TPU_PEAK_FLOPS``
-    env override, else :data:`DEFAULT_PEAK_FLOPS`."""
-    env = os.environ.get("TRITON_TPU_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    return DEFAULT_PEAK_FLOPS
+def _bf16_peak() -> Optional[float]:
+    """bf16 peak FLOP/s of the local device — the MFU denominator — or
+    None when :data:`costs.DEVICE_PEAKS` does not list it (CPU, an
+    unknown chip): the MFU gauge is then absent, never guessed."""
+    peaks = device_peaks()
+    return peaks["bf16_flops"] if peaks is not None else None
 
 
 class _ModelCompute:
@@ -405,10 +391,11 @@ class DeviceStatsCollector:
     def live_mfu(self, model: str, now: Optional[float] = None
                  ) -> Optional[float]:
         """Windowed MFU: FLOPs executed over elapsed compute time over
-        chip peak.  The numerator prefers XLA-measured flops-per-element
-        (cost analysis at first compile) over the hand-declared figure.
-        None for models with neither (or no window traffic) — an unknown
-        model must read as "unknown", not 0% utilization."""
+        the device's bf16 peak.  The numerator prefers XLA-measured
+        flops-per-element (cost analysis at first compile) over the
+        hand-declared figure.  None for models with neither (or no
+        window traffic), and on a device the peak table does not list —
+        unknown must read as "unknown", not 0% utilization."""
         now = time.monotonic() if now is None else now
         with self._lock:
             if not (self._flops_measured.get(model)
@@ -420,9 +407,10 @@ class DeviceStatsCollector:
             self._prune_locked(cm, now)
             busy = sum(e[1] for e in cm.events)
             flops = sum(e[2] for e in cm.events)
-        if busy <= 0:
+        peak = _bf16_peak()
+        if busy <= 0 or peak is None:
             return None
-        return flops / busy / peak_flops()
+        return flops / busy / peak
 
     def pad_waste(self, model: Optional[str] = None) -> Optional[float]:
         """Cumulative pad-waste fraction across ticks (one model, or every
@@ -466,6 +454,7 @@ class DeviceStatsCollector:
         """The ``nv_tpu_*`` sample rows, keyed by short family name — one
         source for both the Prometheus renderer and the JSON snapshot."""
         now = time.monotonic() if now is None else now
+        peak = _bf16_peak()
         with self._lock:
             models = sorted(self._compute)
             # duty + MFU in ONE pass over each model's event window, under
@@ -481,9 +470,10 @@ class DeviceStatsCollector:
                 for e in cm.events:
                     busy += e[1]
                     flops += e[2]
-                mfu = (flops / busy / peak_flops()
-                       if busy > 0 and (self._flops_measured.get(m)
-                                        or self._flops_pe.get(m)) else None)
+                mfu = (flops / busy / peak
+                       if busy > 0 and peak is not None
+                       and (self._flops_measured.get(m)
+                            or self._flops_pe.get(m)) else None)
                 duty_mfu[m] = (min(1.0, busy / span), mfu)
             compiles = {m: (c.compile_count, c.compile_ns_total, c.hits)
                         for m, c in self._compile.items()}

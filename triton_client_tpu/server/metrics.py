@@ -128,7 +128,7 @@ _DEVICE_FAMILIES: List[Tuple[str, str, str, str]] = [
     ("roofline_ai", "nv_tpu_roofline_arithmetic_intensity", "gauge",
      "XLA cost-analysis arithmetic intensity (FLOPs per byte accessed) "
      "per model and bucket — compare against the chip ridge point "
-     "(TRITON_TPU_PEAK_FLOPS / TRITON_TPU_PEAK_BYTES_PER_S)"),
+     "(bf16 peak FLOP/s over peak HBM bytes/s of the device kind)"),
     ("roofline_pct", "nv_tpu_roofline_pct_of_peak", "gauge",
      "Achieved percent of the bound resource's peak (peak FLOP/s when "
      "compute_bound, peak bytes/s when memory_bound) per model and "

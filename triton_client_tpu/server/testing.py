@@ -91,6 +91,9 @@ class ServerHarness:
         return f"{self.host}:{self.grpc_port}"
 
     def start(self) -> "ServerHarness":
+        from .compile_cache import enable_compile_cache
+
+        enable_compile_cache()  # before the warmup compiles in _serve
         self._present = True
         _server_present(+1)
         self._thread = threading.Thread(target=self._run, daemon=True, name="tc-tpu-server")

@@ -5,8 +5,8 @@ Historical bug class: ``/metrics`` rendered inline on the event loop and
 hops); ``ServerLog`` file appends called directly from async control-plane
 handlers while the request paths carefully hopped to the executor.  One
 blocking call on the loop stalls EVERY in-flight request for its duration
-— on a tunneled TPU link a single synchronous device read is a full RTT
-serializing all concurrent traffic behind it.
+— a single synchronous device read serializes all concurrent traffic
+behind the device queue it waits on.
 
 What fires, inside ``async def`` bodies only:
 

@@ -6,18 +6,26 @@ shapes, so the reference's ``simple_*_cudashm_*`` examples run with an import
 swap (a ``cuda_shared_memory`` alias module is provided for exactly that).
 
 TPU translation of the cudaIPC design (BASELINE.json north star; SURVEY.md
-§3.5/§7 hard parts (a)):
+§3.5/§7 hard parts (a)).  A chip belongs to ONE process, so which half of
+the design a region uses is fixed when it is created, by whether a server
+runs in this process (``broker().server_present``):
 
-* cudaMalloc                → a **region slot** in the process-local broker
-  holding the current immutable ``jax.Array`` (PjRt buffer).  jax arrays are
-  immutable, so "writing" a region rebinds the slot.
-* cudaIpcGetMemHandle       → ``get_raw_handle``: a JSON descriptor carrying
-  the slot uuid (in-process zero-copy import) and a POSIX host-shm staging
-  key (cross-process import; PjRt has no cudaIpcOpenMemHandle equivalent, so
-  a cross-process reader pays exactly one host↔device DMA).
-* cudaMemcpyAsync + stream  → ``jax.device_put`` (async dispatch; PjRt
-  transfer engine) / DLPack zero-copy ingest for device-resident producers.
-* cudaIpc leak assertions   → ``allocated_shared_memory_regions()``.
+* **co-located** (``ServerHarness`` in this process — the zero-copy
+  topology): cudaMalloc → a **region slot** in the process-local broker
+  holding the current immutable ``jax.Array`` (PjRt buffer; "writing" a
+  region rebinds the slot); cudaMemcpyAsync → ``jax.device_put`` / DLPack
+  zero-copy ingest.  The server shares the slot, tensors stay in HBM.
+* **out of process** (a client of a server that owns the chip — the
+  README quickstart, ``tpu-perf-analyzer --shared-memory=xla``, the
+  cudashm examples): the region IS its POSIX host-shm staging region plus
+  an 8-byte generation counter; create/set/get never import JAX, let
+  alone open a backend — exactly what the C++ client does
+  (``native/client/xla_shm_utils.h``).  PjRt has no cudaIpcOpenMemHandle
+  equivalent, so the server pays one host↔device DMA per changed region.
+* cudaIpcGetMemHandle → ``get_raw_handle``: a JSON descriptor carrying the
+  slot uuid (resolves only in the server's own process) and the staging
+  keys (resolve from any process).
+* cudaIpc leak assertions → ``allocated_shared_memory_regions()``.
 """
 
 from __future__ import annotations
@@ -91,10 +99,13 @@ class XlaSharedMemoryRegion:
         self._closed = False
         self._staging = None
         self._seq = None
-        self._slot = broker().create(self._uuid, byte_size, device_id)
-        # Host-shm staging region so an out-of-process server can import the
-        # handle.  Created eagerly (mmap is cheap); written only when no
-        # in-process server shares the slot (see set_shared_memory_region).
+        # topology is fixed here (module docstring): a device slot only
+        # when a server in THIS process can share it
+        self._slot = (broker().create(self._uuid, byte_size, device_id)
+                      if broker().server_present else None)
+        # Host-shm staging region: the whole region for an out-of-process
+        # client; created for a co-located one too (mmap is cheap, and the
+        # raw handle always names it) but never written there.
         self._staging_key = f"/xlashm_{self._uuid[:16]}"
         self._staging = _sysshm.create_shared_memory_region(
             self._triton_shm_name, self._staging_key, byte_size
@@ -127,9 +138,9 @@ class XlaSharedMemoryRegion:
 
     @property
     def array(self):
-        """Current device contents (jax.Array) or None."""
-        arr, _, _ = self._slot.get()
-        return arr
+        """Current device contents (jax.Array); None when nothing is bound
+        or the region is staging-only (out-of-process client)."""
+        return self._slot.get()[0] if self._slot is not None else None
 
     # -- lifecycle ---------------------------------------------------------
     def _close(self):
@@ -159,7 +170,14 @@ def create_shared_memory_region(
     cudaSetDevice + cudaMalloc + cudaIpcGetMemHandle)."""
     if byte_size <= 0:
         raise XlaSharedMemoryException("byte_size must be positive")
-    _device(device_id)  # validate device exists before allocating
+    if device_id < 0:
+        raise XlaSharedMemoryException(
+            f"unable to create shared memory region on device {device_id}")
+    if broker().server_present:
+        # co-located: the device is this process's own — validate it.  An
+        # out-of-process client must not open a backend to find out; the
+        # id travels in the handle to the server that owns the chip.
+        _device(device_id)
     region = XlaSharedMemoryRegion(triton_shm_name, byte_size, device_id)
     with _alloc_lock:
         _allocated[region._uuid] = region
@@ -203,9 +221,9 @@ def set_shared_memory_region(
     """Write numpy arrays into the region (reference __init__.py:173-239:
     cudaMemcpyAsync per value + stream sync).
 
-    One H2D ``jax.device_put`` binds the device slot; when no in-process
-    server shares the slot, the host staging region is written too so a
-    cross-process server can import the contents."""
+    Co-located: one H2D ``jax.device_put`` binds the device slot the
+    server shares.  Out of process: the bytes go to the host staging
+    region (generation counter bumped) and no backend is touched."""
     if not isinstance(input_values, (list, tuple)):
         raise XlaSharedMemoryException("input_values must be a list of numpy arrays")
     payloads = []
@@ -221,21 +239,32 @@ def set_shared_memory_region(
             "unable to set shared memory region: byte_size "
             f"{xla_shm_handle._byte_size} is too small for {offset + total} bytes"
         )
+    if xla_shm_handle._slot is None:
+        _write_staging(xla_shm_handle, payloads, offset=offset)
+    else:
+        _write_device(xla_shm_handle, payloads, offset)
+    from ..._telemetry import telemetry
+
+    telemetry().record_shm_transfer("xla", "write", total)
+
+
+def _write_device(handle: XlaSharedMemoryRegion, payloads, offset: int) -> None:
+    """Co-located write: one H2D ``device_put`` rebinds the slot."""
     import jax
 
-    dev = _device(xla_shm_handle._device_id)
+    dev = _device(handle._device_id)
     if len(payloads) == 1 and offset == 0:
         host = payloads[0]
         datatype = np_to_triton_dtype(host.dtype) or "UINT8"
         arr = jax.device_put(host, dev)
-        _bind(xla_shm_handle, arr, datatype, host.shape)
+        _bind(handle, arr, datatype, host.shape)
     else:
         # multiple values / offset: region becomes a flat byte buffer
         flat = np.concatenate(
             [p.reshape(-1).view(np.uint8) for p in payloads]
         ) if payloads else np.zeros((0,), np.uint8)
-        cur, _, _ = xla_shm_handle._slot.get()
-        size = xla_shm_handle._byte_size
+        cur, _, _ = handle._slot.get()
+        size = handle._byte_size
         buf = np.zeros((size,), np.uint8)
         if cur is not None:
             # Preserve whatever the region already holds (reference cudashm
@@ -246,12 +275,7 @@ def set_shared_memory_region(
             buf[: min(cur_bytes.size, size)] = cur_bytes[: min(cur_bytes.size, size)]
         buf[offset : offset + flat.size] = flat
         arr = jax.device_put(buf, dev)
-        _bind(xla_shm_handle, arr, "UINT8", (size,))
-    if not broker().server_present:
-        _write_staging(xla_shm_handle, payloads, offset=offset)
-    from ..._telemetry import telemetry
-
-    telemetry().record_shm_transfer("xla", "write", total)
+        _bind(handle, arr, "UINT8", (size,))
 
 
 def set_shared_memory_region_from_dlpack(
@@ -260,10 +284,29 @@ def set_shared_memory_region_from_dlpack(
     """Zero-copy ingest of DLPack-capable tensors (reference
     __init__.py:328-388 — device-pointer based, the model for this module).
 
-    jax arrays bind directly (no copy); other producers (torch CPU, numpy)
-    come in through ``jax.dlpack``/``device_put`` with one transfer."""
+    Co-located: jax arrays bind directly (no copy); other producers (torch
+    CPU, numpy) come in through ``jax.dlpack``/``device_put`` with one
+    transfer.  Out of process: each tensor is copied to host staging."""
     if not isinstance(input_values, (list, tuple)):
         input_values = [input_values]
+    if xla_shm_handle._slot is None:
+        hosts = []
+        for v in input_values:
+            if not hasattr(v, "__dlpack__"):
+                raise XlaSharedMemoryException(
+                    f"tensor of type {type(v).__name__} does not support "
+                    "DLPack")
+            if not _contiguous_ok(v):
+                raise XlaSharedMemoryException(
+                    "the tensor must be contiguous in memory")
+            # __array__ first: a device-resident producer (a jax.Array the
+            # caller already holds) copies out through it, which
+            # np.from_dlpack cannot do across devices
+            hosts.append(np.ascontiguousarray(
+                np.asarray(v) if hasattr(v, "__array__")
+                else np.from_dlpack(v)))
+        set_shared_memory_region(xla_shm_handle, hosts)
+        return
     import jax
 
     dev = _device(xla_shm_handle._device_id)
@@ -296,8 +339,6 @@ def set_shared_memory_region_from_dlpack(
         arr = arrays[0]
         datatype = np_to_triton_dtype(np.dtype(str(arr.dtype))) or "UINT8"
         _bind(xla_shm_handle, arr, datatype, arr.shape)
-        if not broker().server_present:
-            _write_staging(xla_shm_handle, [np.ascontiguousarray(np.asarray(arr))])
     else:
         hosts = [np.ascontiguousarray(np.asarray(a)) for a in arrays]
         set_shared_memory_region(xla_shm_handle, hosts)
@@ -322,10 +363,10 @@ def get_contents_as_numpy(
 ) -> np.ndarray:
     """Device → host read-back (reference __init__.py:242-325: D2H
     cudaMemcpy then numpy reinterpret; BYTES deserialized host-side)."""
-    arr, bound_dt, _ = xla_shm_handle._slot.get()
+    arr = xla_shm_handle.array
     if arr is None:
-        # region never written on-device (e.g. server in another process
-        # wrote the staging region): fall back to host staging contents
+        # staging-only region (the server is another process and wrote its
+        # outputs here), or a slot nothing was bound to yet
         return _sysshm.get_contents_as_numpy(
             xla_shm_handle._staging, datatype, list(shape), offset=offset
         )
@@ -355,16 +396,21 @@ def as_shared_memory_tensor(
     """DLPack-view export (reference __init__.py:391-399).
 
     For a device-bound region the live ``jax.Array`` is itself the DLPack
-    producer — frameworks consume TPU HBM with no host hop."""
-    arr, _, _ = xla_shm_handle._slot.get()
+    producer — frameworks consume TPU HBM with no host hop.  A
+    staging-only region exports a numpy view of the host staging bytes
+    (numpy arrays are DLPack producers too)."""
+    dt = triton_to_np_dtype(datatype)
+    if dt is None:
+        raise XlaSharedMemoryException(f"unsupported datatype {datatype}")
+    if xla_shm_handle._slot is None and dt is not np.object_:
+        return _sysshm.get_contents_as_numpy(
+            xla_shm_handle._staging, dt, list(shape))
+    arr = xla_shm_handle.array
     if arr is None:
         raise XlaSharedMemoryException(
             f"shared memory region '{xla_shm_handle._triton_shm_name}' has no "
             "contents to export"
         )
-    dt = triton_to_np_dtype(datatype)
-    if dt is None:
-        raise XlaSharedMemoryException(f"unsupported datatype {datatype}")
     import jax.numpy as jnp
 
     host_dt = jnp.dtype(dt) if dt is not np.object_ else None
