@@ -15,6 +15,7 @@ import time
 import pytest
 
 from triton_client_tpu.server.profiler import (DEFAULT_PROFILE_HZ,
+                                               PROBE_INTERVAL_S,
                                                PROFILE_HZ_ENV, HostProfiler,
                                                classify_thread, dump_threads,
                                                fold_stack,
@@ -289,6 +290,108 @@ class TestGcAccounting:
         snap = p.snapshot()
         assert snap["gc"]["2"]["collections"] >= 1
         assert snap["gc"]["2"]["pause_us_total"] > 0.0
+
+
+# -- pauses as events --------------------------------------------------------
+
+def _plant_gc(p, seconds, generation=2):
+    """The collector's hook, called as CPython calls it."""
+    gc.disable()  # a real collection in between would take the start stamp
+    try:
+        p._on_gc("start", {"generation": generation})
+        time.sleep(seconds)
+        p._on_gc("stop", {"generation": generation})
+    finally:
+        gc.enable()
+
+
+class TestPauses:
+    def test_the_probe_sees_every_stall_of_20_ms(self):
+        assert PROBE_INTERVAL_S == 0.02
+
+    def test_long_pauses_are_kept_and_every_pause_is_charged(self):
+        p = HostProfiler(hz=0)
+        charged = []
+        p.on_pause = charged.append
+        t0 = time.monotonic_ns()
+        _plant_gc(p, 0.006)
+        _plant_gc(p, 0.0, generation=0)
+        t1 = time.monotonic_ns()
+        assert len(charged) == 2 and charged[0] >= 6e6 > charged[1] > 0
+        (kept,) = p.pauses_between(t0, t1)   # 5 ms or more: an event
+        assert kept["kind"] == "gc2"
+        assert t0 <= kept["start_ns"] < kept["end_ns"] <= t1
+        assert kept["end_ns"] - kept["start_ns"] == charged[0]
+        assert p.pauses_between(t1, t1 + 10**9) == []
+        # the totals count both, kept or not
+        gc_snap = p.snapshot()["gc"]
+        assert gc_snap["2"]["collections"] == 1
+        assert gc_snap["0"]["collections"] == 1
+        assert gc_snap["2"]["pause_us_total"] == charged[0] / 1e3
+
+    def test_one_store_feeds_the_rows_the_series_and_the_recorder(self):
+        loop = asyncio.new_event_loop()
+        t = threading.Thread(target=loop.run_forever, daemon=True)
+        t.start()
+        p = HostProfiler(hz=0)
+        charged = []
+        p.on_pause = charged.append
+        try:
+            p.install_loop_probe(loop, name="lp", interval_s=0.02)
+            time.sleep(0.05)  # the probe is running, and on time
+            t0 = time.monotonic_ns()
+            loop.call_soon_threadsafe(time.sleep, 0.08)
+            deadline = time.monotonic() + 5.0
+            while not charged and time.monotonic() < deadline:
+                time.sleep(0.005)
+            pauses = p.pauses_between(t0, time.monotonic_ns())
+        finally:
+            p._stop.set()
+            loop.call_soon_threadsafe(loop.stop)
+            t.join(timeout=5)
+            loop.close()
+        (pause,) = pauses
+        assert pause["kind"] == "loop:lp"
+        late_ns = pause["end_ns"] - pause["start_ns"]
+        assert 50e6 <= late_ns <= 200e6 and charged == [late_ns]
+        assert p.loop_lag()["lp"]["max_us"] == late_ns / 1e3
+        (point,) = p.snapshot()["loop_lag"]["lp"]["series"]
+        assert point == {"ts_mono": pause["end_ns"] / 1e9,
+                         "lag_us": late_ns / 1e3}
+        assert p.metric_rows()["loop_lag"] == [({"loop": "lp"},
+                                                late_ns / 1e3)]
+
+    def test_a_late_probe_is_charged_net_of_the_collections_before_it(self):
+        """A collection stalls the loop too: the firing it made late is an
+        event of its own, but its milliseconds are charged once."""
+        loop = asyncio.new_event_loop()
+        t = threading.Thread(target=loop.run_forever, daemon=True)
+        t.start()
+        p = HostProfiler(hz=0)
+        charged = []
+        p.on_pause = charged.append
+        try:
+            p.install_loop_probe(loop, name="lp", interval_s=0.02)
+            time.sleep(0.05)
+            t0 = time.monotonic_ns()
+            loop.call_soon_threadsafe(_plant_gc, p, 0.08)
+            deadline = time.monotonic() + 5.0
+            while time.monotonic() < deadline and len(
+                    p.pauses_between(t0, time.monotonic_ns())) < 2:
+                time.sleep(0.005)
+            time.sleep(0.05)  # a firing or two more, on time again
+            pauses = p.pauses_between(t0, time.monotonic_ns())
+        finally:
+            p._stop.set()
+            loop.call_soon_threadsafe(loop.stop)
+            t.join(timeout=5)
+            loop.close()
+        assert sorted(x["kind"] for x in pauses) == ["gc2", "loop:lp"]
+        late = next(x for x in pauses if x["kind"] == "loop:lp")
+        assert late["end_ns"] - late["start_ns"] >= 50e6
+        # the collection, and of the late firing at most what the loop
+        # took to come round after it
+        assert charged[0] >= 80e6 and sum(charged) <= charged[0] + 10e6
 
 
 # -- output surfaces ---------------------------------------------------------

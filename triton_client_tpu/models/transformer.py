@@ -391,6 +391,18 @@ def _int8_quant(h, axes):
     return q, s
 
 
+# ``jax.named_scope`` below is metadata only: the scopes name the ops on the
+# profiler's device plane (and in HLO dumps) after the part of the step they
+# belong to, and leave the compiled programs and their cache keys alone.
+
+
+@jax.named_scope("weight_cast")
+def _cast(w, dtype):
+    """The weights are held in f32 and cast in every forward."""
+    return w.astype(dtype)
+
+
+@jax.named_scope("norm")
 def _rmsnorm(x, scale, eps):
     x32 = x.astype(jnp.float32)
     r = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
@@ -458,8 +470,8 @@ def _flash_min_s() -> int:
     return int(os.environ.get("TRITON_TPU_FLASH_MIN_S", "1024"))
 
 
-def _attn_apply(blk, x, cfg: TransformerConfig):
-    h = _rmsnorm(x, blk["ln1"], cfg.norm_eps)
+@jax.named_scope("qkv_proj")
+def _qkv_proj(blk, h):
     if "wq_scale" in blk:
         # int8 MXU path: activations quantized per token, weights already
         # int8 per output channel; the einsum runs int8×int8 with int32
@@ -474,14 +486,15 @@ def _attn_apply(blk, x, cfg: TransformerConfig):
             return (out.astype(jnp.float32)
                     * hs[:, None, :, :] * ws[:, :, None, :]).astype(h.dtype)
 
-        q, k, v = proj("wq"), proj("wk"), proj("wv")
-    else:
-        q = jnp.einsum("bsd,dhk->bhsk", h, blk["wq"].astype(h.dtype))
-        k = jnp.einsum("bsd,dhk->bhsk", h, blk["wk"].astype(h.dtype))
-        v = jnp.einsum("bsd,dhk->bhsk", h, blk["wv"].astype(h.dtype))
-    Sc = x.shape[1]
-    positions = lax.axis_index("sp") * Sc + jnp.arange(Sc)
-    q, k = _rope(q, k, positions, cfg.rope_theta)
+        return proj("wq"), proj("wk"), proj("wv")
+    q = jnp.einsum("bsd,dhk->bhsk", h, _cast(blk["wq"], h.dtype))
+    k = jnp.einsum("bsd,dhk->bhsk", h, _cast(blk["wk"], h.dtype))
+    v = jnp.einsum("bsd,dhk->bhsk", h, _cast(blk["wv"], h.dtype))
+    return q, k, v
+
+
+@jax.named_scope("scores_softmax")
+def _scores_softmax(q, k, v, cfg: TransformerConfig):
     if (lax.axis_size("sp") == 1 and _flash_enabled()
             and q.shape[2] >= _flash_min_s()):
         # full LONG sequence on-device: the pallas flash kernel (ops/)
@@ -490,9 +503,12 @@ def _attn_apply(blk, x, cfg: TransformerConfig):
         # sequences stay on XLA's fused attention (see _flash_min_s)
         from ..ops import flash_attention
 
-        o = flash_attention(q, k, v, causal=cfg.causal)
-    else:
-        o = _ring_attention(q, k, v, cfg)
+        return flash_attention(q, k, v, causal=cfg.causal)
+    return _ring_attention(q, k, v, cfg)
+
+
+@jax.named_scope("out_proj")
+def _out_proj(blk, o):
     if "wo_scale" in blk:
         # contraction is (h, k): quantize per (b, s) over the local heads —
         # each tp rank rescales its own partial product BEFORE the psum
@@ -502,11 +518,21 @@ def _attn_apply(blk, x, cfg: TransformerConfig):
         out = (out.astype(jnp.float32)
                * osc[:, 0, :, :] * blk["wo_scale"]).astype(o.dtype)
     else:
-        out = jnp.einsum("bhsk,hkd->bsd", o, blk["wo"].astype(o.dtype))
-    out = lax.psum(out, "tp")
-    return x + out
+        out = jnp.einsum("bhsk,hkd->bsd", o, _cast(blk["wo"], o.dtype))
+    return lax.psum(out, "tp")
 
 
+@jax.named_scope("attention")
+def _attn_apply(blk, x, cfg: TransformerConfig):
+    h = _rmsnorm(x, blk["ln1"], cfg.norm_eps)
+    q, k, v = _qkv_proj(blk, h)
+    Sc = x.shape[1]
+    positions = lax.axis_index("sp") * Sc + jnp.arange(Sc)
+    q, k = _rope(q, k, positions, cfg.rope_theta)
+    return x + _out_proj(blk, _scores_softmax(q, k, v, cfg))
+
+
+@jax.named_scope("ffn")
 def _ffn_apply(blk, x, cfg: TransformerConfig):
     h = _rmsnorm(x, blk["ln2"], cfg.norm_eps)
     if cfg.moe:
@@ -522,7 +548,7 @@ def _ffn_apply(blk, x, cfg: TransformerConfig):
         def _mw(name):
             # expert weights dequantized on the fly when int8 (weight-only
             # for MoE: routing keeps the dense int8-MXU path out of reach)
-            w = blk[name].astype(h.dtype)
+            w = _cast(blk[name], h.dtype)
             s = blk.get(name + "_scale")
             return w * s.astype(h.dtype) if s is not None else w
 
@@ -563,9 +589,9 @@ def _ffn_apply(blk, x, cfg: TransformerConfig):
                    * blk["w2_scale"]).astype(h.dtype)
         out = lax.psum(out, "tp")
     else:
-        he = jnp.einsum("bsd,df->bsf", h, blk["w1"].astype(h.dtype))
+        he = jnp.einsum("bsd,df->bsf", h, _cast(blk["w1"], h.dtype))
         he = jax.nn.silu(he)
-        out = jnp.einsum("bsf,fd->bsd", he, blk["w2"].astype(h.dtype))
+        out = jnp.einsum("bsf,fd->bsd", he, _cast(blk["w2"], h.dtype))
         out = lax.psum(out, "tp")
     return x + out
 
@@ -777,18 +803,20 @@ def make_forward(mesh: Mesh, cfg: TransformerConfig, n_micro: int = 1,
     def local_fwd(params, tokens):
         Bl, Sc = tokens.shape
         mb = Bl // n_micro
-        x = jnp.take(params["embed"].astype(cfg.dtype), tokens, axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(_cast(params["embed"], cfg.dtype), tokens, axis=0)
         x_mbs = x.reshape(n_micro, mb, Sc, cfg.d_model)
         outs = _pipeline_apply(params, x_mbs, cfg)
         is_last = (lax.axis_index("pp") == lax.axis_size("pp") - 1)
         outs = jnp.where(is_last, outs, 0.0).astype(jnp.float32)
         outs = lax.psum(outs, "pp").astype(cfg.dtype)
-        h = _rmsnorm(outs, params["final_ln"], cfg.norm_eps)
-        head = params["head"]
-        if head_cols is not None:
-            head = head[:, :head_cols]
-        logits = jnp.einsum("nbsd,dv->nbsv", h.astype(jnp.float32),
-                            head.astype(jnp.float32))
+        with jax.named_scope("head"):
+            h = _rmsnorm(outs, params["final_ln"], cfg.norm_eps)
+            head = params["head"]
+            if head_cols is not None:
+                head = head[:, :head_cols]
+            logits = jnp.einsum("nbsd,dv->nbsv", h.astype(jnp.float32),
+                                head.astype(jnp.float32))
         return logits.reshape(Bl, Sc, head.shape[-1])
 
     sharded = jax.shard_map(

@@ -19,6 +19,7 @@ thread-pool executor so the event loop keeps serving while XLA executes
 from __future__ import annotations
 
 import asyncio
+import collections
 import contextvars
 import threading
 import time
@@ -36,6 +37,7 @@ from .device_stats import DeviceStatsCollector, SloEngine, SloObjective
 from .flight_recorder import FlightRecorder
 from .log import ServerLog, log_off_loop
 from .memory import MemoryGovernor
+from .profiler import annotation
 from .qos import DEFAULT_TENANT, QosManager, TieredQueue
 from .trace import RequestTracer, TRACE_DEFAULTS
 from .types import (
@@ -418,20 +420,25 @@ class _DynamicBatcher:
         # produces) sampled before any concat/pad work
         queue_depth = self._queue.qsize()
         exec_stats: Dict[str, Any] = {}
-        for item in pending:
+        member_queue_ns = 0
+        for item, count in zip(pending, counts):
             ts, trace = item[3], item[4]
+            # this request's OWN wait from enqueue until its batch formed
+            # (``queue`` below charges the first member's to every row)
+            member_queue_ns += (t_asm0 - ts) * count
             if trace is not None:
-                # this request's wait from enqueue until its batch formed
                 trace.add_span("QUEUE", ts, t_asm0)
         try:
             merged = {}
-            for n in names:
-                parts = [p[0][n] for p in pending]
-                arr = np.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
-                if padded > total:
-                    pad_widths = [(0, padded - total)] + [(0, 0)] * (arr.ndim - 1)
-                    arr = np.pad(arr, pad_widths)
-                merged[n] = arr
+            with annotation("batcher.assemble", bucket=padded, rows=total,
+                            queue_depth=queue_depth):
+                for n in names:
+                    parts = [p[0][n] for p in pending]
+                    arr = np.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
+                    if padded > total:
+                        pad_widths = [(0, padded - total)] + [(0, 0)] * (arr.ndim - 1)
+                        arr = np.pad(arr, pad_widths)
+                    merged[n] = arr
             queue_ns = time.monotonic_ns() - pending[0][3]
             t0 = time.monotonic_ns()
             for trace in traces:
@@ -445,8 +452,10 @@ class _DynamicBatcher:
                 real_batch=total,
                 traces=traces, exec_stats=exec_stats)
             compute_ns = time.monotonic_ns() - t0
-            self._model.stats.record(total, queue_ns, compute_ns, ok=True)
-            self._model.stats.record_batch(total)
+            self._model.stats.record(
+                total, queue_ns, compute_ns, ok=True,
+                member_queue_ns=member_queue_ns, assembly_ns=t0 - t_asm0,
+                padded=padded, step=exec_stats, formed=True)
             ds = self._core.device_stats
             if ds.enabled:
                 # one tick record per batched execution: the bucket view
@@ -850,6 +859,7 @@ class InferenceCore:
         }
         self.tracer = RequestTracer(self.trace_settings)
         self.log = ServerLog(self.log_settings)
+        self.tracer.log = self.log
         self._batchers: Dict[str, _DynamicBatcher] = {}
         self._inline_profiles: Dict[str, _InlineProfile] = {}
         self.response_cache = _ResponseCache()
@@ -919,6 +929,12 @@ class InferenceCore:
         from .profiler import HostProfiler
 
         self.profiler = HostProfiler()
+        # pauses as events: the flight recorder lays the kept ones beside
+        # a pinned request's REQUEST span, and every one is charged to
+        # the ``pause`` entry of the models it held requests of
+        self.flight_recorder.pauses_between = self.profiler.pauses_between
+        self._pause_charges: collections.deque = collections.deque()
+        self.profiler.on_pause = self._charge_pause
         # automatic postmortems (server/incident.py): trigger-driven
         # bundle directories (profile window + thread dump + every
         # subsystem snapshot).  The flight recorder feeds its SLO pins
@@ -1260,6 +1276,11 @@ class InferenceCore:
         finally:
             model.stats.dec_pending()
             self.memory.release(model.name, request.tenant, held)
+        # the frontend charges the ``request`` entry (handler entry ->
+        # response built) with the rows ``ModelStats.record`` counted
+        shape = request.inputs[0].shape if request.inputs else ()
+        resp.stats = model.stats
+        resp.rows = int(shape[0]) if len(shape) else 1
         if request.client_request_id:
             # echo the propagated correlation id so the client can join its
             # telemetry with the server trace (HTTP also echoes the header)
@@ -1431,7 +1452,8 @@ class InferenceCore:
                     trace.cost = cost
                     if trace.flight is not None:
                         trace.flight.cost = cost
-            model.stats.record(_batch_count(inputs) or 1, queue_ns, compute_ns, ok=True)
+            model.stats.record(_batch_count(inputs) or 1, queue_ns, compute_ns,
+                               ok=True, step=exec_stats)
         if cache_key is not None:
             self.response_cache.put(cache_key, dict(outputs),
                                     ttl_s=_model_cache_ttl(model))
@@ -2020,8 +2042,15 @@ class InferenceCore:
         way every compute nanosecond is charged exactly once."""
         loop = asyncio.get_running_loop()
         ds = self.device_stats
+        # rows the execution runs with (pad rows included), read off the
+        # shape: ``np.asarray`` on a device-resident input would sync it
+        shape = getattr(next(iter(inputs.values()), None), "shape", None)
+        padded_n = int(shape[0]) if shape else 1
+        rows = real_batch or padded_n
+        t_submit = 0  # stamped only where the execution hops to a worker
 
         def _exec():
+            t_x0 = time.monotonic_ns()
             want_ds = ds.enabled
             # device-loop models (the decode worker) gate slot admission
             # on projected KV bytes — hand them the governor BEFORE the
@@ -2041,12 +2070,19 @@ class InferenceCore:
                 attach_chaos = getattr(model, "attach_chaos", None)
                 if attach_chaos is not None:
                     attach_chaos(self.chaos)
-            t_c0 = time.monotonic_ns() if (traces or want_ds) else 0
-            outputs = model.execute(inputs, params)
-            t_c1 = time.monotonic_ns() if (traces or want_ds) else 0
-            if traces:
-                for t in traces:
-                    t.add_span("COMPUTE", t_c0, t_c1)
+            with annotation("step.dispatch", model=model.name,
+                            bucket=padded_n, rows=rows):
+                t_c0 = time.monotonic_ns()
+                outputs = model.execute(inputs, params)
+                t_c1 = time.monotonic_ns()
+            if exec_stats is not None:
+                # the step as the host lives it (ModelStats.record folds
+                # these into executor_wait / dispatch / device_wait)
+                exec_stats["executor_wait_ns"] = \
+                    t_x0 - t_submit if t_submit else 0
+                exec_stats["dispatch_ns"] = t_c1 - t_c0
+            for t in traces:
+                t.add_span("COMPUTE", t_c0, t_c1)
             if want_ds:
                 # signature-analytic compile tracking: jax.jit compiles
                 # once per input-shape signature (the invariant JaxModel
@@ -2079,7 +2115,6 @@ class InferenceCore:
                 # this signature actually runs.  None (CPU stand-ins with
                 # no analysis, untraceable fns) stays None — absent,
                 # never fabricated.
-                padded_n = _batch_count(inputs) or 1
                 cost = None
                 if sig is not None and not ds.signature_known(
                         model.name, sig):
@@ -2108,17 +2143,20 @@ class InferenceCore:
                                   flops=cost.flops if cost else 0.0)
             if keep_device is None:
                 return outputs
-            drained = [n for n, v in outputs.items()
-                       if n not in keep_device
-                       and hasattr(v, "copy_to_host_async")]
-            for n in drained:
-                outputs[n].copy_to_host_async()
-            resolved = {n: (v if n in keep_device else np.asarray(v))
-                        for n, v in outputs.items()}
-            if traces:
+            with annotation("step.device_wait", model=model.name,
+                            bucket=padded_n, rows=rows):
+                drained = [n for n, v in outputs.items()
+                           if n not in keep_device
+                           and hasattr(v, "copy_to_host_async")]
+                for n in drained:
+                    outputs[n].copy_to_host_async()
+                resolved = {n: (v if n in keep_device else np.asarray(v))
+                            for n, v in outputs.items()}
                 t_d1 = time.monotonic_ns()
-                for t in traces:
-                    t.add_span("D2H_TRANSFER", t_c1, t_d1)
+            if exec_stats is not None:
+                exec_stats["device_wait_ns"] = t_d1 - t_c1
+            for t in traces:
+                t.add_span("D2H_TRANSFER", t_c1, t_d1)
             if drained:
                 if want_ds:
                     ds.record_transfer(
@@ -2156,6 +2194,7 @@ class InferenceCore:
                     prof.observe(sig, time.perf_counter() - t0)
 
         if prof is None:
+            t_submit = time.monotonic_ns()
             return await loop.run_in_executor(None, _exec)
 
         def _exec_timed():
@@ -2165,6 +2204,7 @@ class InferenceCore:
             finally:
                 prof.observe(sig, time.perf_counter() - t0)
 
+        t_submit = time.monotonic_ns()
         return await loop.run_in_executor(None, _exec_timed)
 
     async def _run_ensemble(self, model: EnsembleModel, inputs, params,
@@ -2425,7 +2465,31 @@ class InferenceCore:
             "extensions": list(self.EXTENSIONS),
         }
 
+    def _charge_pause(self, ns: int) -> None:
+        """``HostProfiler.on_pause``: charge a pause that just ended to
+        every model with a request pending.  It may run inside the
+        collector's hook, on a thread that holds a ``ModelStats.lock``, so
+        it queues the charges and settles only those whose lock is free
+        (``statistics()`` settles the rest), and does not wait for the
+        registry either: a pause during a model load is charged to none."""
+        for m in self.registry.all_version_models(blocking=False):
+            if m.stats.pending_count > 0:
+                self._pause_charges.append((m.stats, ns))
+        self._settle_pause_charges(blocking=False)
+
+    def _settle_pause_charges(self, blocking: bool) -> None:
+        queue = self._pause_charges
+        while True:
+            try:
+                stats, ns = queue.popleft()
+            except IndexError:
+                return
+            if not stats.charge_pause(ns, blocking):
+                queue.appendleft((stats, ns))
+                return
+
     def statistics(self, name: Optional[str], version: str = "") -> List[dict]:
+        self._settle_pause_charges(blocking=True)
         if name and version:
             models = [self.registry.get(name, version)]
         elif name:
@@ -2453,6 +2517,7 @@ class InferenceCore:
                             "compute_input": {"count": s.infer_count, "ns": 0},
                             "compute_infer": {"count": s.infer_count, "ns": s.infer_ns},
                             "compute_output": {"count": s.infer_count, "ns": 0},
+                            **s.extension_entries(),
                         },
                         "batch_stats": [],
                     }

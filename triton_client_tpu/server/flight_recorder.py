@@ -110,7 +110,7 @@ class FlightRecord:
                  "queue_us", "compute_us", "total_us", "outcome",
                  "capture_reason", "spans", "chaos", "tenant", "tier",
                  "tick", "shed_reason", "cost", "fault", "recovered",
-                 "cache_hit_tokens", "prefix_hash")
+                 "cache_hit_tokens", "prefix_hash", "pauses")
 
     def __init__(self, seq: int, model: str, version: str,
                  request_id: str = "", protocol: str = "",
@@ -166,6 +166,10 @@ class FlightRecord:
         # join key between the flight ring and the cache's block store
         self.cache_hit_tokens = 0
         self.prefix_hash: Optional[str] = None
+        # process-wide pauses (server/profiler.py: collections, late
+        # event-loop probes) that overlap this request's REQUEST span —
+        # filled when the watchdog pins the record, so a stall has a name
+        self.pauses: Optional[List[dict]] = None
 
     def to_dict(self, include_spans: bool = False) -> Dict[str, Any]:
         out: Dict[str, Any] = {
@@ -197,6 +201,7 @@ class FlightRecord:
         }
         if include_spans:
             out["spans"] = self.spans or []
+            out["pauses"] = self.pauses or []
         return out
 
 
@@ -242,6 +247,9 @@ class FlightRecorder:
         # watchdog-storm detector — the escalation from "pin this
         # request" to "bundle the whole process"
         self.incidents = None
+        # ``HostProfiler.pauses_between``, set by the core: the kept
+        # pauses overlapping a pinned request's REQUEST span
+        self.pauses_between = None
 
     def configure(self, capacity: Optional[int] = None,
                   outlier_capacity: Optional[int] = None,
@@ -383,6 +391,9 @@ class FlightRecorder:
                  "parent": s.parent}
                 for s in trace.spans
             ]
+            if self.pauses_between is not None and root is not None:
+                record.pauses = self.pauses_between(
+                    root.start_ns, root.start_ns + total_ns)
         # buffer appends share the counter lock: complete() runs on
         # executor threads while snapshot()/metrics iterate on the event
         # loop, and an unlocked deque append mid-iteration raises

@@ -405,8 +405,10 @@ class InferenceServicer:
             await context.abort(code, str(e))
         if update:  # get_trace_settings sends an empty map — a read, not an
             # update; it must not reset the sampling counters or count budget
-            self._core.trace_settings.update(update)
-            self._core.tracer.settings_updated()
+            try:
+                self._core.tracer.apply(update)
+            except InferError as e:  # the profiler did not start (503)
+                await context.abort(_grpc_code(e), str(e))
         resp = pb.TraceSettingResponse()
         for k, vals in self._core.trace_settings.items():
             resp.settings[k].value.extend(vals)
@@ -559,6 +561,7 @@ class InferenceServicer:
                 # grpc.aio serializes+writes after the handler returns; this
                 # span covers the handoff work still visible from here
                 trace.add_span("NETWORK_WRITE", t_ser1, time.monotonic_ns())
+            resp.count_request(t_recv)
         except BaseException as e:
             # encode failures after the core reported success must still
             # land in the flight record as failures (same contract as the

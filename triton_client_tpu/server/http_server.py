@@ -389,8 +389,7 @@ async def _set_trace(core, request):
             update[k] = v if isinstance(v, list) else [str(v)]
     validate_trace_update(update)  # 501 for TENSORS, 400 for junk — pre-apply
     if update:  # an empty body is a read, not an update — counters keep phase
-        core.trace_settings.update(update)
-        core.tracer.settings_updated()
+        core.tracer.apply(update)
     return web.json_response(core.trace_settings)
 
 
@@ -747,6 +746,7 @@ async def _infer(core, request: web.Request) -> web.Response:
             # compression + response assembly up to the transport handoff
             # (aiohttp writes the socket after the handler returns)
             trace.add_span("NETWORK_WRITE", t_ser1, time.monotonic_ns())
+        resp.count_request(t_recv)
     except BaseException as e:
         # a serialize/compress failure happens after the core reported
         # success — the flight record must still land as a failure

@@ -157,6 +157,21 @@ class ModelStats:
     # avg formed batch = batch_size_total / batch_execution_count
     batch_size_total: int = 0
     batch_execution_count: int = 0
+    # layer-boundary counters (the statistics extension, docs/ARCHITECTURE.md):
+    # cumulative (count, ns) pairs charged per row like ``infer_ns``, so a
+    # request's means add up along its path.  Recorded where the work
+    # happens, whether or not the request carries a TraceContext.
+    # The five per-execution entries count what ``infer_count`` counts.
+    request_count: int = 0      # frontend handler entry -> response built
+    request_ns: int = 0
+    queue_member_ns: int = 0    # each member's OWN enqueue -> assembly
+    assembly_ns: int = 0        # concat + pad-to-bucket
+    executor_wait_ns: int = 0   # run_in_executor called -> _exec starts
+    dispatch_ns: int = 0        # model.execute called -> returned
+    device_wait_ns: int = 0     # execute returned -> outputs on the host
+    bucket_rows: int = 0        # rows executed, pad rows included
+    pause_count: int = 0        # collector / late-loop pauses that held
+    pause_ns: int = 0           # requests of this model
     lock: threading.Lock = field(default_factory=threading.Lock)
 
     def inc_pending(self) -> None:
@@ -167,12 +182,18 @@ class ModelStats:
         with self.lock:
             self.pending_count -= 1
 
-    def record_batch(self, batch: int) -> None:
-        with self.lock:
-            self.batch_size_total += batch
-            self.batch_execution_count += 1
-
-    def record(self, batch: int, queue_ns: int, compute_ns: int, ok: bool) -> None:
+    def record(self, batch: int, queue_ns: int, compute_ns: int, ok: bool, *,
+               member_queue_ns: Optional[int] = None, assembly_ns: int = 0,
+               padded: Optional[int] = None, step=None,
+               formed: bool = False) -> None:
+        """One execution of ``batch`` rows.  ``queue_ns``/``compute_ns`` are
+        charged to every row (the v2 entries).  The extension entries:
+        ``member_queue_ns`` is the rows' own waits already summed (default:
+        ``queue_ns`` a row), ``assembly_ns`` the batch's concat + pad,
+        ``padded`` the rows the execution ran with (default: ``batch``),
+        ``step`` the dict ``_run_model`` filled with ``executor_wait_ns`` /
+        ``dispatch_ns`` / ``device_wait_ns``; ``formed`` marks a batch the
+        dynamic batcher formed."""
         with self.lock:
             if ok:
                 self.inference_count += batch
@@ -184,9 +205,57 @@ class ModelStats:
                 self.queue_ns += queue_ns * batch
                 self.infer_count += batch
                 self.infer_ns += compute_ns * batch
+                self.queue_member_ns += (queue_ns * batch if member_queue_ns
+                                         is None else member_queue_ns)
+                self.assembly_ns += assembly_ns * batch
+                self.bucket_rows += batch if padded is None else padded
+                if step:
+                    self.executor_wait_ns += \
+                        step.get("executor_wait_ns", 0) * batch
+                    self.dispatch_ns += step.get("dispatch_ns", 0) * batch
+                    self.device_wait_ns += \
+                        step.get("device_wait_ns", 0) * batch
+                if formed:
+                    self.batch_size_total += batch
+                    self.batch_execution_count += 1
             else:
                 self.fail_count += batch
                 self.fail_ns += (queue_ns + compute_ns) * batch
+
+    def record_request(self, rows: int, ns: int) -> None:
+        """A frontend answered a request of ``rows`` rows ``ns`` after its
+        handler was entered (successes only, like ``success``)."""
+        with self.lock:
+            self.request_count += rows
+            self.request_ns += ns * rows
+
+    def charge_pause(self, ns: int, blocking: bool = True) -> bool:
+        """Charge one process-wide pause of ``ns``.  ``blocking=False`` is
+        for the collector's hook, which may run on a thread that already
+        holds ``lock``: False means not charged, try again later."""
+        if not self.lock.acquire(blocking):
+            return False
+        try:
+            self.pause_count += 1
+            self.pause_ns += ns
+        finally:
+            self.lock.release()
+        return True
+
+    def extension_entries(self) -> Dict[str, Dict[str, int]]:
+        """The extension's ``inference_stats`` entries (caller holds
+        ``lock``), in path order."""
+        n = self.infer_count
+        return {
+            "request": {"count": self.request_count, "ns": self.request_ns},
+            "queue_member": {"count": n, "ns": self.queue_member_ns},
+            "batch_assembly": {"count": n, "ns": self.assembly_ns},
+            "executor_wait": {"count": n, "ns": self.executor_wait_ns},
+            "dispatch": {"count": n, "ns": self.dispatch_ns},
+            "device_wait": {"count": n, "ns": self.device_wait_ns},
+            "bucket_rows": {"count": self.bucket_rows, "ns": 0},
+            "pause": {"count": self.pause_count, "ns": self.pause_ns},
+        }
 
 
 class Model(abc.ABC):

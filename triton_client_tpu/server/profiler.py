@@ -25,6 +25,15 @@ observers:
   totals, because a gen-2 collection mid-decode-tick is precisely the
   kind of host stall the roadmap's tick-scheduling work must rule out.
 
+The last two see **pauses**: every collection, and every probe firing
+that ran ``PAUSE_LATE_NS`` late, is a pause ``(kind, start_ns, end_ns)``
+on ``time.monotonic_ns()`` — the request spans' clock.  Each is handed to
+``on_pause`` (the core charges it to the models that had requests
+pending); those of ``PAUSE_KEEP_NS`` or more are kept as events in one
+bounded deque, which the flight recorder lays beside a slow request's
+REQUEST span (``pauses_between``) and from which the rolling
+``nv_host_loop_lag_us`` maximum and ``snapshot()``'s series are computed.
+
 All three surface through ``metric_rows()`` into the single-declaration
 ``nv_host_*`` metric families, through ``snapshot()`` for JSON debug and
 incident bundles, and through ``collapsed()`` as flamegraph-ready
@@ -38,6 +47,7 @@ overflow folds into a synthetic ``~overflow`` frame rather than growing.
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import os
 import sys
@@ -58,9 +68,28 @@ DEFAULT_MAX_STACKS = 2048
 DEFAULT_WINDOW_S = 60.0
 # frames kept per sample; deeper stacks truncate at the leaf end
 MAX_STACK_DEPTH = 64
-# loop-lag probe cadence and per-loop sample retention
-PROBE_INTERVAL_S = 0.25
-_PROBE_KEEP = 512
+# loop-lag probe cadence: every stall of the loop of 20 ms or more is seen,
+# its length to within 20 ms (fifty timer callbacks a second on a loop that
+# handles thousands of events)
+PROBE_INTERVAL_S = 0.02
+# a probe firing this late is a pause; a pause this long is kept as an event
+PAUSE_LATE_NS = 10_000_000
+PAUSE_KEEP_NS = 5_000_000
+_PAUSE_KEEP = 512
+_GC_KINDS = ("gc0", "gc1", "gc2")
+_NO_SPAN = contextlib.nullcontext()
+
+
+def annotation(name: str, **kwargs):
+    """A span on the JAX profiler's clock (``jax.profiler.TraceAnnotation``)
+    for a SYNCHRONOUS section: a TraceMe belongs to its thread, so one held
+    across an ``await`` would overlap its neighbours on the loop's line.
+    With no profiler session it is an inactive TraceMe — one context
+    manager.  A process that has not imported JAX has no session, and is
+    not made to import it for this."""
+    cls = getattr(getattr(sys.modules.get("jax"), "profiler", None),
+                  "TraceAnnotation", None)
+    return cls(name, **kwargs) if cls is not None else _NO_SPAN
 
 
 def profile_hz_from_env(default: float = DEFAULT_PROFILE_HZ) -> float:
@@ -168,19 +197,30 @@ class HostProfiler:
         # threading.enumerate() per sample would dominate sampler cost)
         self._names: Dict[int, str] = {}
         self._names_key: frozenset = frozenset()
+        # -- pauses ----------------------------------------------------
+        # the one event store: (kind, start_ns, end_ns) on monotonic_ns,
+        # kind "gc<generation>" or "loop:<probe name>", PAUSE_KEEP_NS or
+        # longer.  Appended lock-free (deque.append is atomic) by the
+        # collector's hook and the probes; readers copy it with list().
+        self._pauses: deque = deque(maxlen=_PAUSE_KEEP)
+        # called with every pause's length in ns, kept or not, on the
+        # thread the pause ended on — the collector's hook included, so
+        # it must not block on a lock that thread may hold
+        self.on_pause = None
         # -- loop-lag probes -------------------------------------------
-        # loop name -> {"last_us", "max_us", "samples": [(mono, us)...]}
+        # loop name -> {"last_us", "gc_ns"}; written by its probe alone
         self._loops: Dict[str, Dict[str, Any]] = {}
         # -- GC accounting ---------------------------------------------
-        self._gc_start_ns: Optional[int] = None
-        self._gc_pause_ns: Counter = Counter()        # generation -> ns
-        self._gc_collections: Counter = Counter()     # generation -> n
         # _on_gc runs re-entrantly on WHATEVER thread triggered the
         # collection — including one already holding self._lock (an
         # allocation inside metric_rows/snapshot can start a GC).  It
-        # therefore never takes the lock: completed pauses queue here
-        # (deque.append is atomic) and readers drain under the lock.
-        self._gc_events: deque = deque()              # (generation, ns)
+        # therefore never takes the lock: it is the only writer of these
+        # totals (one collection runs at a time), readers read the ints.
+        self._gc_start_ns: Optional[int] = None
+        self._gc_span = None                          # host.gc, gen 2 only
+        self._gc_pause_ns = [0, 0, 0]                 # by generation
+        self._gc_collections = [0, 0, 0]
+        self._gc_total_ns = 0
         self._gc_registered = False
 
     # -- lifecycle ---------------------------------------------------------
@@ -337,25 +377,25 @@ class HostProfiler:
                 # second frontend on the SAME loop (http + metrics app
                 # share one): one probe per loop is enough
                 return
-            state = {"last_us": 0.0, "max_us": 0.0, "samples": []}
+            state = {"last_us": 0.0, "gc_ns": self._gc_total_ns}
             self._loops[name] = state
+        kind = f"loop:{name}"
 
         def _tick(expected: float) -> None:
             if self._stop.is_set():
                 return
             now = loop.time()
-            lag_us = max(0.0, (now - expected) * 1e6)
-            mono = time.monotonic()
-            with self._lock:
-                state["last_us"] = lag_us
-                samples = state["samples"]
-                samples.append((mono, lag_us))
-                if len(samples) > _PROBE_KEEP:
-                    del samples[: len(samples) - _PROBE_KEEP]
-                cutoff = mono - self.window_s
-                state["max_us"] = max(
-                    (us for ts, us in samples if ts >= cutoff),
-                    default=lag_us)
+            end_ns = time.monotonic_ns()
+            lag_ns = max(0, int((now - expected) * 1e9))
+            state["last_us"] = lag_ns / 1e3
+            # collections since the last firing were charged by the
+            # collector's hook: a firing they made late is charged net of
+            # them, so no millisecond is charged twice
+            gc_ns = self._gc_total_ns
+            collected, state["gc_ns"] = gc_ns - state["gc_ns"], gc_ns
+            if lag_ns >= PAUSE_LATE_NS:
+                self._pauses.append((kind, end_ns - lag_ns, end_ns))
+                self._charge(lag_ns - collected)
             loop.call_later(interval_s, _tick, now + interval_s)
 
         loop.call_soon_threadsafe(
@@ -368,23 +408,40 @@ class HostProfiler:
         # CPython runs one collection at a time under the GIL, so a
         # single start stamp is race-free.  Lock-free on purpose: the
         # callback fires on the thread that tripped the collection,
-        # which may already hold self._lock (see _gc_events).
+        # which may already hold self._lock (see __init__).
+        gen = min(2, int(info.get("generation", 0)))
         if phase == "start":
-            self._gc_start_ns = time.perf_counter_ns()
+            if gen == 2:
+                # the 40-60 ms ones, on the profiler's clock; the young
+                # generations fire too often to pay an object each
+                self._gc_span = annotation("host.gc")
+                self._gc_span.__enter__()
+            self._gc_start_ns = time.monotonic_ns()
         elif phase == "stop" and self._gc_start_ns is not None:
-            dt = time.perf_counter_ns() - self._gc_start_ns
-            self._gc_start_ns = None
-            self._gc_events.append((int(info.get("generation", 0)), dt))
-
-    def _drain_gc_events(self) -> None:
-        # caller holds self._lock; a GC fired mid-drain only appends
-        while True:
-            try:
-                gen, dt = self._gc_events.popleft()
-            except IndexError:
-                break
+            end_ns = time.monotonic_ns()
+            start_ns, self._gc_start_ns = self._gc_start_ns, None
+            span, self._gc_span = self._gc_span, None
+            if span is not None:
+                span.__exit__(None, None, None)
+            dt = end_ns - start_ns
             self._gc_pause_ns[gen] += dt
             self._gc_collections[gen] += 1
+            self._gc_total_ns += dt
+            if dt >= PAUSE_KEEP_NS:
+                self._pauses.append((_GC_KINDS[gen], start_ns, end_ns))
+            self._charge(dt)
+
+    def _charge(self, ns: int) -> None:
+        sink = self.on_pause
+        if sink is not None and ns > 0:
+            sink(ns)
+
+    def pauses_between(self, start_ns: int, end_ns: int) -> List[Dict[str, Any]]:
+        """The kept pauses that overlap ``[start_ns, end_ns]`` on
+        ``time.monotonic_ns()``, oldest first."""
+        return [{"kind": k, "start_ns": s, "end_ns": e}
+                for k, s, e in list(self._pauses)
+                if s < end_ns and e > start_ns]
 
     # -- output surfaces ---------------------------------------------------
 
@@ -416,21 +473,37 @@ class HostProfiler:
         items.sort(key=lambda t: -t[2])
         return items[:n]
 
-    def loop_lag(self) -> Dict[str, Dict[str, float]]:
+    def _loop_series(self) -> Dict[str, List[Tuple[int, float]]]:
+        """Per probed loop, its kept pauses inside the rolling window as
+        ``(end_ns, lag_us)``, oldest first."""
+        cutoff = time.monotonic_ns() - int(self.window_s * 1e9)
+        out: Dict[str, List[Tuple[int, float]]] = {}
+        for kind, start, end in list(self._pauses):
+            if end >= cutoff and kind.startswith("loop:"):
+                out.setdefault(kind[5:], []).append((end, (end - start) / 1e3))
+        return out
+
+    def loop_lag(self, series=None) -> Dict[str, Dict[str, float]]:
+        """Per probed loop, the last firing's lag and the rolling maximum:
+        the longest kept pause of the loop inside ``window_s``, or the
+        last lag where that is longer."""
+        if series is None:
+            series = self._loop_series()
         with self._lock:
-            return {name: {"last_us": st["last_us"],
-                           "max_us": st["max_us"]}
-                    for name, st in self._loops.items()}
+            loops = {name: st["last_us"] for name, st in self._loops.items()}
+        return {name: {"last_us": last,
+                       "max_us": max([last] + [us for _, us in
+                                               series.get(name, [])])}
+                for name, last in loops.items()}
 
     def metric_rows(self) -> Dict[str, List[Tuple[Dict[str, str], float]]]:
         """Rows for the single-declaration ``nv_host_*`` families in
         ``metrics.collect_families`` (keys are family short-names)."""
+        lag = [({"loop": name}, st["max_us"])
+               for name, st in sorted(self.loop_lag().items())]
+        pauses = [({"generation": str(gen)}, self._gc_pause_ns[gen] / 1e3)
+                  for gen in range(3) if self._gc_collections[gen]]
         with self._lock:
-            self._drain_gc_events()
-            lag = [({"loop": name}, st["max_us"])
-                   for name, st in sorted(self._loops.items())]
-            pauses = [({"generation": str(gen)}, ns / 1e3)
-                      for gen, ns in sorted(self._gc_pause_ns.items())]
             samples = [({"role": role}, float(n))
                        for role, n in sorted(self._samples_by_role.items())]
         return {"loop_lag": lag, "gc_pause": pauses, "samples": samples}
@@ -438,8 +511,9 @@ class HostProfiler:
     def snapshot(self) -> Dict[str, Any]:
         """JSON shape for ``/v2/debug/profile?format=json`` and incident
         bundles."""
+        series = self._loop_series()
+        lags = self.loop_lag(series)
         with self._lock:
-            self._drain_gc_events()
             merged = self._prev_epoch + self._epoch
             top = sorted(((r, s, c) for (r, s), c in merged.items()),
                          key=lambda t: -t[2])[:50]
@@ -455,12 +529,12 @@ class HostProfiler:
                     name: {"last_us": st["last_us"],
                            "max_us": st["max_us"],
                            "series": [
-                               {"ts_mono": ts, "lag_us": us}
-                               for ts, us in st["samples"][-64:]]}
-                    for name, st in self._loops.items()},
+                               {"ts_mono": end / 1e9, "lag_us": us}
+                               for end, us in series.get(name, [])[-64:]]}
+                    for name, st in lags.items()},
                 "gc": {
                     str(gen): {
                         "pause_us_total": self._gc_pause_ns[gen] / 1e3,
                         "collections": self._gc_collections[gen]}
-                    for gen in sorted(self._gc_pause_ns)},
+                    for gen in range(3) if self._gc_collections[gen]},
             }

@@ -289,12 +289,19 @@ class ModelRegistry:
         with self._lock:
             return list(self._models.values())
 
-    def all_version_models(self) -> List[Model]:
+    def all_version_models(self, blocking: bool = True) -> List[Model]:
         """Every served version instance (warmup, statistics, metrics —
-        surfaces that report or touch each version separately)."""
-        with self._lock:
+        surfaces that report or touch each version separately).
+        ``blocking=False`` is for a caller that must not wait (the
+        collector's hook runs on whichever thread allocated): none where
+        another thread holds the lock, as for the length of a load."""
+        if not self._lock.acquire(blocking):
+            return []
+        try:
             return [m for vs in self._version_sets.values()
                     for m in vs.values()]
+        finally:
+            self._lock.release()
 
     def version_models(self, name: str) -> List[Model]:
         """Every served version of one name, ascending."""

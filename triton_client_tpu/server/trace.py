@@ -457,6 +457,9 @@ class RequestTracer:
         # optional OtlpExporter (set by InferenceCore when --otlp-endpoint
         # is configured): every emitted record is also submitted there
         self.otlp = None
+        # the server's log (set by InferenceCore): a profiler that fails
+        # to stop is reported there
+        self.log = None
 
     # -- settings lifecycle ------------------------------------------------
     def settings_updated(self) -> None:
@@ -496,26 +499,54 @@ class RequestTracer:
                 eff[k] = list(v)
         return eff
 
+    def apply(self, update: Dict[str, List[str]]) -> None:
+        """Apply a validated GLOBAL settings update and open a fresh
+        sampling window.  Where the update turns ``PROFILE`` on and the
+        profiler cannot start, every key is put back as it was and the
+        ``InferError`` (503) goes to the caller."""
+        before = {k: self._settings.get(k) for k in update}
+        self._settings.update(update)
+        try:
+            self.settings_updated()
+        except InferError:
+            for k, v in before.items():
+                if v is None:
+                    self._settings.pop(k, None)
+                else:
+                    self._settings[k] = v
+            raise
+
     def _sync_profiler(self) -> None:
         want = "PROFILE" in (self._settings.get("trace_level") or [])
         if want and not self._profiling:
-            try:
-                import jax
+            import jax
 
+            try:
                 jax.profiler.start_trace(self._profile_dir())
-                self._profiling = True
-            except Exception:
-                # Profiler unavailable (or already active elsewhere): tracing
-                # of timestamps must keep working regardless.
-                self._profiling = False
+            except Exception as e:
+                # unavailable, or a session is already running elsewhere in
+                # the process: say so, rather than report a level that
+                # traces nothing
+                raise InferError(
+                    f"trace_level PROFILE: the JAX profiler did not start: "
+                    f"{type(e).__name__}: {e}", http_status=503) from e
+            self._profiling = True
         elif not want and self._profiling:
-            try:
-                import jax
+            self._stop_profiler()
 
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
-            self._profiling = False
+    def _stop_profiler(self) -> None:
+        import jax
+
+        self._profiling = False
+        try:
+            jax.profiler.stop_trace()
+        except Exception as e:
+            # the level is off either way; the trace file may be missing
+            if self.log is not None:
+                self.log.error(
+                    f"trace: jax.profiler.stop_trace failed, "
+                    f"{self._profile_dir()} may be incomplete: "
+                    f"{type(e).__name__}: {e}")
 
     def _profile_dir(self) -> str:
         return self._trace_file() + ".profile"
@@ -526,13 +557,7 @@ class RequestTracer:
             otlp.shutdown()
         self._out.close()
         if self._profiling:
-            try:
-                import jax
-
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
-            self._profiling = False
+            self._stop_profiler()
 
     # -- per-request sampling ----------------------------------------------
     def _trace_file(self, eff: Optional[Dict[str, List[str]]] = None) -> str:

@@ -137,6 +137,18 @@ class InferResponse:
     # Sampled TraceContext handed to a finalizing frontend (see
     # InferRequest.trace_handoff); never serialized onto the wire.
     trace: Any = None
+    # The ``ModelStats`` that counted this request and its rows (set by the
+    # core; never serialized): the frontend charges the ``request`` entry
+    # once it has built the wire response.
+    stats: Any = None
+    rows: int = 1
+
+    def count_request(self, t_recv_ns: int) -> None:
+        """The frontend entered its handler at ``t_recv_ns`` and has now
+        built the response and handed it to the transport."""
+        if self.stats is not None:
+            self.stats.record_request(
+                self.rows, time.monotonic_ns() - t_recv_ns)
 
 
 class InferError(Exception):

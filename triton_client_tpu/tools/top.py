@@ -830,7 +830,18 @@ def _outlier_brief(o: Optional[dict]) -> Optional[Dict[str, Any]]:
         "outcome": o.get("outcome"),
         "chaos": o.get("chaos"),
         "request_id": o.get("request_id", ""),
+        # the longest process-wide pause (collector, late event loop) that
+        # overlapped the request: the name of a stall, where it has one
+        "pause": _longest_pause(o.get("pauses")),
     }
+
+
+def _longest_pause(pauses) -> Optional[Dict[str, Any]]:
+    if not pauses:
+        return None
+    p = max(pauses, key=lambda p: p["end_ns"] - p["start_ns"])
+    return {"kind": p["kind"],
+            "ms": round((p["end_ns"] - p["start_ns"]) / 1e6, 1)}
 
 
 def aggregate_rows(per_url_rows: Dict[str, Dict[str, Dict[str, Any]]]
@@ -957,6 +968,8 @@ def _row_line(label: str, r: Dict[str, Any]) -> str:
             # injected weather, labeled so an operator staring at a
             # spike can tell the chaos harness from the real world
             brief += f" [chaos:{o['chaos']}]"
+        if o.get("pause"):
+            brief += f" [{o['pause']['kind']} {o['pause']['ms']:g}ms]"
         if o["outcome"] != "ok":
             brief += f" ({o['outcome'][:40]})"
     # the breach marker rides the burn column: "23.1!" = both windows
