@@ -461,13 +461,14 @@ def _int8_fused_mode() -> frozenset:
     return mode
 
 
-def _flash_min_s() -> int:
-    """Sequence-length gate for the pallas flash kernel.  Measured on-chip
-    (benchmarks/BERT_PROFILE.md): at S=384 the kernel is ~25% SLOWER than
-    XLA's fused attention (block overheads dominate short rows), while at
-    S=2048 it is ~2-4x faster and at S=8192 it is the only thing that
-    compiles.  Default crossover 1024; override TRITON_TPU_FLASH_MIN_S."""
-    return int(os.environ.get("TRITON_TPU_FLASH_MIN_S", "1024"))
+# A layer's f32 scores [B, H, S, S] up to this size XLA keeps on the chip
+# (v5e), and there the one-shard ring's fusions are the faster: bert_large's
+# forward at [1,384] (9 MiB) 4.76 ms against 4.92 through the kernel, at
+# [2,384] 6.53 against 6.61.  Past it they go through HBM three times a
+# layer, and the kernel wins: [4,384] (36 MiB) 9.31 against 9.37 ms,
+# [8,384] 15.57 against 16.08, [16,384] 27.84 against 38.94, [32,384] 54.44
+# against 76.16 (my chip runs, PR 27; PERF.md §6).
+_SCORES_ON_CHIP_BYTES = 32 << 20
 
 
 @jax.named_scope("qkv_proj")
@@ -495,12 +496,13 @@ def _qkv_proj(blk, h):
 
 @jax.named_scope("scores_softmax")
 def _scores_softmax(q, k, v, cfg: TransformerConfig):
+    B, H, S, _ = q.shape
     if (lax.axis_size("sp") == 1 and _flash_enabled()
-            and q.shape[2] >= _flash_min_s()):
-        # full LONG sequence on-device: the pallas flash kernel (ops/)
-        # replaces the cross-device ring — identical online-softmax math,
-        # VMEM-tiled (the TPU serving path for longctx_tpu); short
-        # sequences stay on XLA's fused attention (see _flash_min_s)
+            and 4 * B * H * S * S > _SCORES_ON_CHIP_BYTES):
+        # the whole sequence on one device, and a layer's scores too many
+        # to stay on it: the pallas kernel (ops/) never sends them to HBM —
+        # whole-row for short S, looped with the ring's online carry for
+        # long S (longctx_tpu)
         from ..ops import flash_attention
 
         return flash_attention(q, k, v, causal=cfg.causal)
