@@ -26,9 +26,9 @@ _BF16 = (jnp.bfloat16, 2e-2)
 
 
 @pytest.mark.parametrize(
-    "shape,causal,dtype,tol",
+    "shape,causal,dtype,tol,dv",
     [
-        *[pytest.param(shape, causal, *_F32,
+        *[pytest.param(shape, causal, *_F32, None,
                        id=f"{'x'.join(map(str, shape))}-f32-"
                           f"{'causal' if causal else 'bidir'}")
           for causal in (True, False)
@@ -39,30 +39,45 @@ _BF16 = (jnp.bfloat16, 2e-2)
               (1, 1, 8, 16),     # tiny: S smaller than any block
           ]],
         # the served short-row family, as the zoo's dtype sends it
-        pytest.param((1, 16, 384, 64), False, *_BF16,
+        pytest.param((1, 16, 384, 64), False, *_BF16, None,
                      id="bert_large-1x16x384x64-bf16-bidir"),
-        pytest.param((2, 16, 384, 64), False, *_BF16,
+        pytest.param((2, 16, 384, 64), False, *_BF16, None,
                      id="bert_large-2x16x384x64-bf16-bidir"),
-        pytest.param((1, 16, 128, 128), True, *_BF16,
+        pytest.param((1, 16, 128, 128), True, *_BF16, None,
                      id="llama-1x16x128x128-bf16-causal"),
-        pytest.param((1, 4, 200, 64), False, *_BF16,
+        pytest.param((1, 4, 200, 64), False, *_BF16, None,
                      id="odd-1x4x200x64-bf16-bidir"),
-        pytest.param((1, 4, 200, 64), True, *_BF16,
+        pytest.param((1, 4, 200, 64), True, *_BF16, None,
                      id="odd-1x4x200x64-bf16-causal"),
         # head blocks: 8 programs of 2 heads (a block holds _ROW_BLOCK_BYTES)
-        pytest.param((1, 16, 384, 128), False, *_F32,
+        pytest.param((1, 16, 384, 128), False, *_F32, None,
                      id="split-heads-1x16x384x128-f32-bidir"),
         # past _ROW_MAX_S: the looped form, S no multiple of its blocks
-        pytest.param((1, 2, 600, 64), True, *_F32,
+        pytest.param((1, 2, 600, 64), True, *_F32, None,
                      id="looped-1x2x600x64-f32-causal"),
-        pytest.param((1, 2, 1100, 32), True, *_BF16,
+        pytest.param((1, 2, 1100, 32), True, *_BF16, None,
                      id="looped-1x2x1100x32-bf16-causal"),
+        # values of another width than the keys (latent attention: q·k over
+        # 192, v 128 wide): the looped form at any length, output as v
+        pytest.param((1, 2, 1100, 24), True, *_F32, 16,
+                     id="dv-looped-1x2x1100x24v16-f32-causal"),
+        pytest.param((1, 2, 1100, 24), True, *_BF16, 16,
+                     id="dv-looped-1x2x1100x24v16-bf16-causal"),
+        pytest.param((2, 2, 200, 24), True, *_F32, 16,
+                     id="dv-short-2x2x200x24v16-f32-causal"),
+        pytest.param((1, 2, 384, 48), False, *_F32, 64,
+                     id="dv-wider-1x2x384x48v64-f32-bidir"),
+        pytest.param((1, 1, 1024, 192), True, *_BF16, 128,
+                     id="dv-mla-1x1x1024x192v128-bf16-causal"),
     ],
 )
-def test_kernel_matches_reference(shape, causal, dtype, tol):
+def test_kernel_matches_reference(shape, causal, dtype, tol, dv):
     q = _rand(shape, dtype, 1)
     k = _rand(shape, dtype, 2)
     v = _rand(shape, dtype, 3)
+    if dv is not None:
+        shape = shape[:3] + (dv,)
+        v = _rand(shape, dtype, 3)
     want = flash_attention_reference(q, k, v, causal=causal)
     got = flash_attention(q, k, v, causal=causal, interpret=True)
     assert got.dtype == dtype and got.shape == shape
@@ -84,11 +99,12 @@ def test_bf16_inputs_accumulate_in_fp32():
         rtol=2e-2, atol=2e-2)
 
 
-def test_custom_scale():
+@pytest.mark.parametrize("dv", [32, 16], ids=["dv=d", "dv-16"])
+def test_custom_scale(dv):
     shape = (1, 1, 64, 32)
     q = _rand(shape, jnp.float32, 7)
     k = _rand(shape, jnp.float32, 8)
-    v = _rand(shape, jnp.float32, 9)
+    v = _rand(shape[:3] + (dv,), jnp.float32, 9)
     want = flash_attention_reference(q, k, v, causal=True, sm_scale=0.5)
     got = flash_attention(q, k, v, causal=True, sm_scale=0.5, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -108,13 +124,15 @@ def test_cpu_fallback_is_reference():
                                rtol=1e-6, atol=1e-6)
 
 
-def test_gradients_match_reference():
+@pytest.mark.parametrize("dv", [16, 8, 24], ids=["dv=d", "dv-8", "dv-24"])
+def test_gradients_match_reference(dv):
     """custom_vjp: grads through the kernel equal grads through the
-    reference (the training path at sp=1)."""
+    reference (the training path at sp=1); with values of another width
+    the output and every cotangent follow v."""
     shape = (1, 2, 32, 16)
     q = _rand(shape, jnp.float32, 20)
     k = _rand(shape, jnp.float32, 21)
-    v = _rand(shape, jnp.float32, 22)
+    v = _rand(shape[:3] + (dv,), jnp.float32, 22)
 
     def loss_kernel(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=True,
@@ -125,7 +143,8 @@ def test_gradients_match_reference():
 
     g_kernel = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for gk, gr in zip(g_kernel, g_ref):
+    for gk, gr, x in zip(g_kernel, g_ref, (q, k, v)):
+        assert gk.shape == x.shape
         np.testing.assert_allclose(np.asarray(gk), np.asarray(gr),
                                    rtol=2e-4, atol=2e-4)
 
@@ -189,3 +208,63 @@ def test_scores_softmax_kernel_arm_matches_ring(monkeypatch, causal):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ring, np.float32),
                                rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("shape,dv,kernel", [
+    ((1, 16, 384, 64), 64, "_row_kernel"),     # bert_large's served row
+    ((32, 16, 384, 64), 64, "_row_kernel"),
+    ((1, 16, 4096, 64), 64, "_loop_kernel"),   # longctx_tpu's
+    ((1, 4, 384, 24), 16, "_loop_kernel"),     # short, but v is narrower
+    ((2, 64, 8192, 192), 128, "_loop_kernel"),  # latent attention's prefill
+], ids=["bert_large-1", "bert_large-32", "longctx", "short-dv", "mla"])
+def test_the_form_follows_the_shape(monkeypatch, shape, dv, kernel):
+    """``_flash_call`` hands a short row with one width to the whole-row
+    form and everything else to the looped one (shapes only; nothing runs)."""
+    import importlib
+
+    # the package exports the function under the module's name
+    fa = importlib.import_module("triton_client_tpu.ops.flash_attention")
+    taken = []
+    monkeypatch.setattr(fa, "_row_call",
+                        lambda *args: taken.append("_row_kernel"))
+    monkeypatch.setattr(fa, "_loop_call",
+                        lambda *args: taken.append("_loop_kernel"))
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape[:3] + (dv,), jnp.bfloat16)
+    fa._flash_call.__wrapped__(q, q, v, False, None, False)
+    assert taken == [kernel]
+
+
+def _pallas_operands(jaxpr, found):
+    """The operand shapes of every ``pallas_call`` under ``jaxpr``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append([v.aval.shape for v in eqn.invars])
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_operands(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("shape,dv,operands", [
+    # the whole-row form reads [B,H,D,S], the sequence on the lanes
+    ((1, 16, 384, 64), 64, [(1, 16, 64, 384)] * 3),
+    ((32, 16, 384, 64), 64, [(32, 16, 64, 384)] * 3),
+    # the looped form reads [B*H,S,D], v at its own width
+    ((1, 16, 4096, 64), 64, [(16, 4096, 64)] * 3),
+    ((1, 4, 384, 24), 16, [(4, 384, 24), (4, 384, 24), (4, 384, 16)]),
+    ((2, 64, 8192, 192), 128,
+     [(128, 8192, 192), (128, 8192, 192), (128, 8192, 128)]),
+], ids=["bert_large-1", "bert_large-32", "longctx", "short-dv", "mla"])
+def test_the_traced_call_is_of_the_form_its_shape_asks_for(shape, dv,
+                                                           operands):
+    """The real trace, nothing mocked: ``bert_large``'s served row still
+    reaches one whole-row kernel, a long row or a v of another width the
+    looped one."""
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape[:3] + (dv,), jnp.bfloat16)
+    traced = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=False, interpret=True))(q, q, v)
+    assert _pallas_operands(traced.jaxpr, []) == [operands]

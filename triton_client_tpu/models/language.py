@@ -123,7 +123,9 @@ def longctx_seq_len() -> int:
 
 
 def n_params(cfg: tr.TransformerConfig) -> int:
-    """Parameter count (dense FFN presets)."""
+    """Parameter count of a ``TransformerConfig`` with a dense FFN; wrong
+    for any expert model, whose count depends on what a chip holds and what
+    a token passes through (``latent_moe.layer_matmul_params``)."""
     per_layer = (
         4 * cfg.d_model * cfg.n_heads * cfg.head_dim  # wq wk wv wo
         + 2 * cfg.d_model                              # ln1 ln2
@@ -136,7 +138,9 @@ def n_params(cfg: tr.TransformerConfig) -> int:
 
 def forward_flops_per_token(cfg: tr.TransformerConfig, seq_len: int,
                             head_cols: int = None) -> float:
-    """≈2·params matmul FLOPs per token + attention score/value terms.
+    """≈2·params matmul FLOPs per token + attention score/value terms, for
+    a ``TransformerConfig`` with a dense FFN (the expert block counts its
+    own: ``latent_moe.flops_per_inference``).
 
     ``head_cols`` must match the forward's (tr.make_forward): a model that
     projects only N head columns (bert_large's span head: 2, not 30522)
@@ -305,6 +309,90 @@ def make_longctx_tpu() -> JaxModel:
         return {"LOGPROBS": jnp.pad(scores, ((0, 0), (0, 1)))}
 
     return JaxModel(cfg, fn, jit=False, analyzable=True)
+
+
+class _LazyLatentMoE:
+    """``_LazyTransformer``'s lazy first-request init for the latent-
+    attention / expert block (models/latent_moe.py): mesh from
+    ``tr.serve_mesh``, weights drawn in bfloat16 on the device leaf by leaf,
+    one jitted forward.  Nothing is imported or allocated before the first
+    call."""
+
+    def __init__(self, cfg, model_name: str):
+        self.cfg = cfg
+        self._model_name = model_name
+        self._fwd = None
+        self._params = None
+
+    def _ensure(self):
+        import jax
+
+        from . import latent_moe
+
+        if self._fwd is None:
+            mesh = tr.serve_mesh(self.cfg, model_name=self._model_name)
+            if mesh.size != 1:
+                raise ValueError(
+                    f"{self._model_name}: the expert layer computes the "
+                    "experts the configuration says it holds and has no "
+                    f"exchange between chips yet; the serve mesh has "
+                    f"{mesh.size} devices")
+            quant = tr.resolve_quant(self._model_name)
+            with jax.default_device(mesh.devices.flat[0]):
+                self._params = latent_moe.init_params(
+                    self.cfg, quantized=(quant == "int8"))
+            cfg = self.cfg
+            self._fwd = jax.jit(
+                lambda params, tokens: latent_moe.forward(params, tokens,
+                                                          cfg))
+
+    def __call__(self, tokens):
+        self._ensure()
+        return self._fwd(self._params, tokens)
+
+
+#: the output the expert block's step carries its routing counts in; the
+#: model's ``host_post`` takes it out of the answer for ``ModelStats``
+EXPERT_ROWS = "EXPERT_ROWS"
+
+
+def make_kimi_k2(cfg=None) -> JaxModel:
+    """Kimi-K2-Instruct's block on one chip's share of an EP32 prefill pool
+    (``latent_moe.KIMI_K2_EP32_SHARE``; a test passes a tiny ``cfg``):
+    INT32 INPUT_IDS [S] → FP32 LOGITS [vocabulary slice] of the next token.
+    One request is one prompt; a prefill pool answers it with one token's
+    logits (and a cache handed on, which this model does not keep)."""
+    if cfg is None:
+        from .latent_moe import KIMI_K2_EP32_SHARE as cfg
+    from .latent_moe import flops_per_inference
+
+    config = make_config(
+        "kimi_k2",
+        inputs=[("INPUT_IDS", "INT32", [cfg.seq_len])],
+        outputs=[("LOGITS", "FP32", [cfg.vocab_size])],
+        max_batch_size=2,
+        preferred_batch_sizes=[1, 2],
+        max_queue_delay_us=2000,
+        instance_kind="KIND_TPU",
+        parameters={"flops_per_inference": str(flops_per_inference(cfg))},
+    )
+    run = _LazyLatentMoE(cfg, "kimi_k2")
+
+    def fn(INPUT_IDS):
+        logits, rows = run(INPUT_IDS)
+        return {"LOGITS": logits, EXPERT_ROWS: rows}
+
+    def host_post(outputs, parameters):
+        # the counts come from the device with the answer; a batched step's
+        # parameters say how many of its rows are not padding
+        rows = outputs.pop(EXPERT_ROWS)
+        model.stats.queue_expert_rows(
+            rows, parameters.get("real_batch", rows.shape[0]), cfg.seq_len)
+        return outputs
+
+    model = JaxModel(config, fn, jit=False, host_post=host_post,
+                     analyzable=True)
+    return model
 
 
 # Mixture-of-experts scorer: serves the flagship stack's MoE FFN path

@@ -1,0 +1,643 @@
+"""Latent attention over a sparse expert layer: the DeepSeek-V3 block.
+
+The second block of the zoo, beside ``transformer.py``'s.  What it has that
+the shared stack has not:
+
+* **Latent attention (MLA).**  Queries and keys/values go through low-rank
+  paths with an inner norm each; a head's query and key are a 128-wide part
+  without position beside a 64-wide rotary part (YaRN frequencies), and the
+  key's rotary part is one vector a token, shared by every head; values are
+  128 wide, so q·k contracts over 192 and P·v writes 128
+  (``ops/flash_attention.py``'s looped form takes the two widths).
+* **SwiGLU** (three matrices) in every FFN.
+* **A stack that is not one scan**: the leading layers are dense, the rest
+  are expert layers (one ``lax.scan`` over those).
+* **An expert layer that is told which experts it holds.**  The router scores
+  all ``routed_experts_total`` experts (sigmoid, a selection bias, top-k,
+  renormalised weights times a factor); this chip holds experts
+  ``first_expert .. first_expert + n_routed_experts - 1`` and computes the
+  part of the result its own experts give, sparsely: only the (token, expert)
+  pairs that land on a held expert, by a grouped matmul over chunks of
+  sorted pairs (one chunk holds what even routing sends, and a quarter).  No pair is dropped: a step in which every
+  token picks held experts walks more chunks.  What the absent experts would
+  add is left out; no code stands in for the other chips or their exchange.
+* **Weights made and held in bfloat16**, leaf by leaf on the device, each
+  layer from ``fold_in(PRNGKey(weights_seed), layer)``: the bf16 values are
+  the checkpoint.
+
+The forward also returns, per batch row and expert layer, the rows routed to
+each held expert; the served model hands them to ``ModelStats``.
+``prefill`` returns the latent cache (``c_kv ‖ k_rope``, 576 values a token a
+layer) beside the logits and ``decode_step`` takes one token through it in
+the absorbed form (``W_kb`` folded into q, ``W_vb`` into the output).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from . import transformer as tr
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoEConfig:
+    """The source's keys under the source's names; ``n_routed_experts`` and
+    ``vocab_size`` are what this chip holds, ``routed_experts_total`` is the
+    router's published width."""
+
+    hidden_size: int
+    num_hidden_layers: int
+    first_k_dense_replace: int
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_shared_experts: int
+    n_routed_experts: int          # held here
+    routed_experts_total: int      # the router's outputs
+    first_expert: int
+    num_experts_per_tok: int
+    routed_scaling_factor: float
+    vocab_size: int                # rows of the vocabulary held here
+    rms_norm_eps: float
+    rope_theta: float
+    rope_factor: float
+    rope_original_max_position: int
+    rope_beta_fast: float
+    rope_beta_slow: float
+    rope_mscale: float
+    rope_mscale_all_dim: float
+    seq_len: int
+    weights_seed: int
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.num_hidden_layers - self.first_k_dense_replace
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    # what ``tr.serve_mesh`` asks of a configuration
+    @property
+    def n_heads(self) -> int:
+        return self.num_attention_heads
+
+    @property
+    def n_layers(self) -> int:
+        return self.num_hidden_layers
+
+    @property
+    def n_experts(self) -> int:
+        return self.n_routed_experts
+
+    @property
+    def moe(self) -> bool:
+        return True
+
+
+#: Kimi-K2-Instruct's ``config.json`` cut to one chip of a 32-chip expert-
+#: parallel prefill pool: 12 of 384 routed experts, an eighth of the
+#: vocabulary, the dense layer and four expert layers; every width as
+#: published (``chipbench/configs/kimi_k2.json`` states the cut).
+KIMI_K2_EP32_SHARE = LatentMoEConfig(
+    hidden_size=7168, num_hidden_layers=5, first_k_dense_replace=1,
+    num_attention_heads=64, q_lora_rank=1536, kv_lora_rank=512,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    intermediate_size=18432, moe_intermediate_size=2048, n_shared_experts=1,
+    n_routed_experts=12, routed_experts_total=384, first_expert=0,
+    num_experts_per_tok=8, routed_scaling_factor=2.827, vocab_size=20480,
+    rms_norm_eps=1e-6, rope_theta=50000.0, rope_factor=32.0,
+    rope_original_max_position=4096, rope_beta_fast=1.0, rope_beta_slow=1.0,
+    rope_mscale=1.0, rope_mscale_all_dim=1.0, seq_len=8192, weights_seed=28)
+
+
+# ---------------------------------------------------------------------------
+# Weights
+# ---------------------------------------------------------------------------
+
+_OUTER = 1 << 16  # the "layer" of embedding, final norm and head
+
+#: every matrix of a layer: (leaf, key index).  The index, not the order
+#: here, decides a leaf's key, so a new leaf never moves an old one's values.
+_LEAF_KEYS = {
+    "w_qa": 0, "w_qb_nope": 1, "w_qb_rope": 2, "w_kva": 3, "w_kb": 4,
+    "w_vb": 5, "w_o": 6, "w_gate": 7, "w_up": 8, "w_down": 9, "router": 10,
+    "router_bias": 11, "we_gate": 12, "we_up": 13, "we_down": 14,
+    "ws_gate": 15, "ws_up": 16, "ws_down": 17, "embed": 18, "head": 19,
+}
+
+#: the matrices ``TRITON_TPU_QUANT=int8`` stores as int8 (MLA and experts),
+#: with the axes each contracts over: one scale an output channel
+_INT8_CONTRACT = {
+    "w_qa": (0,), "w_qb_nope": (0,), "w_qb_rope": (0,), "w_kva": (0,),
+    "w_kb": (0,), "w_vb": (0,), "w_o": (0, 1),
+    "w_gate": (0,), "w_up": (0,), "w_down": (0,),
+    "we_gate": (1,), "we_up": (1,), "we_down": (1,),
+    "ws_gate": (0,), "ws_up": (0,), "ws_down": (0,),
+}
+
+
+def _leaf_shapes(cfg: LatentMoEConfig, dense: bool) -> Dict[str, Tuple]:
+    """``{leaf: (shape, scale of the normal draw)}`` of one layer; a routed
+    expert's leaves are per expert (the leading axis is added by the draw)."""
+    D, H = cfg.hidden_size, cfg.num_attention_heads
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    fan = lambda n: 1.0 / math.sqrt(n)  # noqa: E731
+    shapes = {
+        "w_qa": ((D, rq), fan(D)),
+        "w_qb_nope": ((rq, H, dn), fan(rq)),
+        "w_qb_rope": ((rq, H, dr), fan(rq)),
+        "w_kva": ((D, rkv + dr), fan(D)),
+        "w_kb": ((rkv, H, dn), fan(rkv)),
+        "w_vb": ((rkv, H, dv), fan(rkv)),
+        "w_o": ((H, dv, D), fan(H * dv)),
+    }
+    if dense:
+        F = cfg.intermediate_size
+        shapes.update({"w_gate": ((D, F), fan(D)), "w_up": ((D, F), fan(D)),
+                       "w_down": ((F, D), fan(F))})
+    else:
+        Fe = cfg.moe_intermediate_size
+        Fs = Fe * cfg.n_shared_experts
+        shapes.update({
+            "router": ((D, cfg.routed_experts_total), 0.02),
+            # normal x 0.01, so that the bias moves some choices
+            "router_bias": ((cfg.routed_experts_total,), 0.01),
+            "we_gate": ((D, Fe), fan(D)), "we_up": ((D, Fe), fan(D)),
+            "we_down": ((Fe, D), fan(Fe)),
+            "ws_gate": ((D, Fs), fan(D)), "ws_up": ((D, Fs), fan(D)),
+            "ws_down": ((Fs, D), fan(Fs)),
+        })
+    return shapes
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "scale"))
+def _draw(key, shape, scale):
+    """An f32 normal draw times ``scale``, rounded to bfloat16 once."""
+    return (jax.random.normal(key, shape, jnp.float32)
+            * scale).astype(jnp.bfloat16)
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "scale"))
+def _draw_experts(key, ids, shape, scale):
+    """An expert's weights follow its id, whichever chip holds it."""
+    return jax.vmap(lambda e: _draw(jax.random.fold_in(key, e), shape,
+                                    scale))(ids)
+
+
+def _layer_params(cfg: LatentMoEConfig, layer: int) -> Dict[str, jax.Array]:
+    """One layer's leaves in bfloat16 (the selection bias upcast to f32),
+    drawn on the default device leaf by leaf."""
+    dense = layer < cfg.first_k_dense_replace
+    root = jax.random.fold_in(jax.random.PRNGKey(cfg.weights_seed), layer)
+    held = cfg.first_expert + jnp.arange(cfg.n_routed_experts)
+    out = {"ln_attn": jnp.ones((cfg.hidden_size,), jnp.bfloat16),
+           "ln_q": jnp.ones((cfg.q_lora_rank,), jnp.bfloat16),
+           "ln_kv": jnp.ones((cfg.kv_lora_rank,), jnp.bfloat16),
+           "ln_ffn": jnp.ones((cfg.hidden_size,), jnp.bfloat16)}
+    for name, (shape, scale) in _leaf_shapes(cfg, dense).items():
+        key = jax.random.fold_in(root, _LEAF_KEYS[name])
+        if name.startswith("we_"):
+            out[name] = _draw_experts(key, held, shape, scale)
+        elif name == "router_bias":
+            # a bfloat16 value like every leaf, added to f32 scores
+            out[name] = _draw(key, shape, scale).astype(jnp.float32)
+        else:
+            out[name] = _draw(key, shape, scale)
+    return out
+
+
+def quantize_weights(layer: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """Weight-only int8 storage of a layer's MLA and expert matrices
+    (symmetric, one f32 scale an output channel, as
+    ``tr.quantize_layer_weights``); norms, router and bias stay as drawn."""
+    out = dict(layer)
+    for name, axes in _INT8_CONTRACT.items():
+        if name not in layer:
+            continue
+        w = layer[name].astype(jnp.float32)
+        amax = jnp.max(jnp.abs(w), axis=axes, keepdims=True)
+        scale = jnp.maximum(amax, 1e-12) / 127.0
+        out[name] = jnp.clip(jnp.round(w / scale), -127, 127).astype(jnp.int8)
+        out[name + "_scale"] = scale
+    return out
+
+
+def init_params(cfg: LatentMoEConfig, quantized: bool = False) -> Dict[str, Any]:
+    """``{"embed", "final_ln", "head", "dense": [layer...], "experts":
+    stacked layer}``: the expert layers' leaves are stacked on a leading
+    axis for the scan, one leaf at a time."""
+    prep = jax.jit(quantize_weights) if quantized else (lambda layer: layer)
+    n_dense = cfg.first_k_dense_replace
+    dense = [prep(_layer_params(cfg, i)) for i in range(n_dense)]
+    layers = [prep(_layer_params(cfg, i))
+              for i in range(n_dense, cfg.num_hidden_layers)]
+    experts = {}
+    for name in list(layers[0]):
+        experts[name] = jnp.stack([layer.pop(name) for layer in layers])
+    outer = jax.random.fold_in(jax.random.PRNGKey(cfg.weights_seed), _OUTER)
+    V, D = cfg.vocab_size, cfg.hidden_size
+    return {
+        "embed": _draw(jax.random.fold_in(outer, _LEAF_KEYS["embed"]),
+                       (V, D), 0.02),
+        "final_ln": jnp.ones((D,), jnp.bfloat16),
+        "head": _draw(jax.random.fold_in(outer, _LEAF_KEYS["head"]),
+                      (D, V), 0.02),
+        "dense": dense,
+        "experts": experts,
+    }
+
+
+def _w(blk, name):
+    """A matrix as it is held (bfloat16 when serving), dequantised on the
+    fly where it is stored as int8 (``decode._w``'s form)."""
+    w = blk[name]
+    scale = blk.get(name + "_scale")
+    if scale is None:
+        return w
+    return w.astype(jnp.bfloat16) * scale.astype(jnp.bfloat16)
+
+
+# ---------------------------------------------------------------------------
+# Rotary positions (YaRN)
+# ---------------------------------------------------------------------------
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def softmax_scale(cfg: LatentMoEConfig) -> float:
+    """``qk_head_dim ** -0.5`` times YaRN's ``mscale(factor,
+    mscale_all_dim) ** 2``."""
+    m = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+    return cfg.qk_head_dim ** -0.5 * m * m
+
+
+def yarn_inv_freq(cfg: LatentMoEConfig):
+    """DeepSeek-V3's ``DeepseekV3YarnRotaryEmbedding``: interpolated
+    frequencies below the correction range, the model's own above it, a
+    linear ramp between.  Returns ``(inv_freq [dim/2], cos/sin multiplier)``."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    orig = cfg.rope_original_max_position
+
+    def correction_dim(rotations):
+        return (dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    exponent = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
+    extra = 1.0 / base ** exponent
+    inter = extra / cfg.rope_factor
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    multiplier = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+                  / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    return inter * (1.0 - keep) + extra * keep, multiplier
+
+
+def _rotary(cfg: LatentMoEConfig, positions):
+    """``(cos, sin)`` as ``[len(positions), dim/2]`` f32."""
+    inv_freq, multiplier = yarn_inv_freq(cfg)
+    ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    return jnp.cos(ang) * multiplier, jnp.sin(ang) * multiplier
+
+
+def _rotate(x, cos, sin):
+    """Half-split pairs, the zoo's layout: ``x[..., :h]`` with ``x[..., h:]``;
+    ``cos``/``sin`` broadcast against ``x[..., :h]``."""
+    half = x.shape[-1] // 2
+    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# The block
+# ---------------------------------------------------------------------------
+
+def _swiglu(h, gate, up, down):
+    g = jnp.dot(h, gate, preferred_element_type=jnp.float32)
+    u = jnp.dot(h, up, preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(g) * u).astype(h.dtype)
+    return jnp.dot(a, down, preferred_element_type=jnp.float32)
+
+
+def _latents(blk, h, cfg: LatentMoEConfig, cos, sin):
+    """The two low-rank paths of a row of tokens ``h [..., D]``: the query's
+    two parts per head, the normed kv latent and the rotated shared key."""
+    with jax.named_scope("q_proj"):
+        c_q = tr._rmsnorm(jnp.dot(h, _w(blk, "w_qa")), blk["ln_q"],
+                          cfg.rms_norm_eps)
+        q_nope = jnp.einsum("...sr,rhk->...hsk", c_q, _w(blk, "w_qb_nope"))
+        q_rope = jnp.einsum("...sr,rhk->...hsk", c_q, _w(blk, "w_qb_rope"))
+    with jax.named_scope("kv_proj"):
+        kva = jnp.dot(h, _w(blk, "w_kva"))
+        c_kv = tr._rmsnorm(kva[..., :cfg.kv_lora_rank], blk["ln_kv"],
+                           cfg.rms_norm_eps)
+        k_rope = kva[..., cfg.kv_lora_rank:]
+    with jax.named_scope("rope"):
+        q_rope = _rotate(q_rope, cos, sin)
+        k_rope = _rotate(k_rope, cos, sin)
+    return q_nope, q_rope, c_kv, k_rope
+
+
+@jax.named_scope("mla")
+def _mla(blk, x, cfg: LatentMoEConfig, cos, sin):
+    """Prefill: ``x [B,S,D]`` -> ``(x + attention, (c_kv, k_rope))``."""
+    h = tr._rmsnorm(x, blk["ln_attn"], cfg.rms_norm_eps)
+    q_nope, q_rope, c_kv, k_rope = _latents(blk, h, cfg, cos, sin)
+    with jax.named_scope("kv_proj"):
+        k_nope = jnp.einsum("bsc,chk->bhsk", c_kv, _w(blk, "w_kb"))
+        v = jnp.einsum("bsc,chk->bhsk", c_kv, _w(blk, "w_vb"))
+    with jax.named_scope("rope"):
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, None], k_nope.shape[:3]
+                                      + k_rope.shape[-1:])], axis=-1)
+    with jax.named_scope("scores_softmax"):
+        from ..ops import flash_attention
+
+        o = flash_attention(q, k, v, causal=True,
+                            sm_scale=softmax_scale(cfg))
+    with jax.named_scope("out_proj"):
+        out = jnp.einsum("bhsk,hkd->bsd", o, _w(blk, "w_o"))
+    return x + out, (c_kv, k_rope)
+
+
+@jax.named_scope("dense_ffn")
+def _dense_ffn(blk, x, cfg: LatentMoEConfig):
+    h = tr._rmsnorm(x, blk["ln_ffn"], cfg.rms_norm_eps)
+    y = _swiglu(h, _w(blk, "w_gate"), _w(blk, "w_up"), _w(blk, "w_down"))
+    return x + y.astype(x.dtype)
+
+
+def route(blk, h, cfg: LatentMoEConfig):
+    """``h [T,D]`` -> the chosen experts ``idx [T,k]`` (of all
+    ``routed_experts_total``) and their weights ``[T,k]`` f32: sigmoid
+    scores, the top k of score + bias, weights from the scores alone,
+    renormalised and scaled."""
+    logits = jnp.dot(h, blk["router"], preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(scores + blk["router_bias"], cfg.num_experts_per_tok)
+    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = (picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+               * cfg.routed_scaling_factor)
+    return idx, weights
+
+
+_GMM_TILING = (256, 1024, 1024)
+
+
+def _grouped_matmul(lhs, rhs, sizes):
+    """``lhs [m,k]`` in groups of ``sizes`` rows against ``rhs [G,k,n]`` ->
+    ``[m,n]`` f32; rows past ``sum(sizes)`` hold nothing the caller may use.
+    The megablox kernel on a TPU where the rows fill its tiles (PERF.md §6,
+    PR 28 has the candidates' numbers), ``lax.ragged_dot`` elsewhere."""
+    if jax.default_backend() == "tpu" and lhs.shape[0] % _GMM_TILING[0] == 0:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        return gmm(lhs, rhs, sizes, preferred_element_type=jnp.float32,
+                   tiling=_GMM_TILING)
+    return lax.ragged_dot(lhs, rhs, sizes,
+                          preferred_element_type=jnp.float32)
+
+
+def held_experts(blk, h, idx, weights, cfg: LatentMoEConfig, batch: int = 1):
+    """The held experts' part of the layer for ``h [T,D]`` (``batch`` equal
+    runs of tokens): ``(y [T,D] f32, rows routed to each held expert by run
+    [batch,E])``.  Pairs on held experts are
+    sorted by expert and taken a chunk at a time: gather the
+    tokens' rows, one grouped SwiGLU over the experts the chunk spans,
+    weight, add into ``y`` (in the activations' dtype, summed in f32).  The
+    loop runs as many chunks as the routing
+    filled, so nothing is dropped and nothing of the worst case's size
+    stands allocated."""
+    T, D = h.shape
+    E, k = cfg.n_routed_experts, cfg.num_experts_per_tok
+    # pairs a pass takes: a quarter over what even routing sends the held
+    # experts (every row of a pass costs, filled or not), in whole tiles
+    even = -(-5 * T * k * E // (4 * cfg.routed_experts_total))
+    tile = _GMM_TILING[0] if even >= _GMM_TILING[0] else 8
+    C = -(-even // tile) * tile
+    with jax.named_scope("dispatch"):
+        local = idx - cfg.first_expert
+        held = (local >= 0) & (local < E)
+        group = jnp.where(held, local, E).reshape(-1)           # [T*k]
+        rows = jnp.sum(group.reshape(batch, -1, 1) == jnp.arange(E),
+                       axis=1, dtype=jnp.int32)                 # [batch,E]
+        counts = jnp.sum(rows, axis=0)
+        starts = jnp.cumsum(counts) - counts
+        n_held = jnp.sum(counts)
+        n_chunks = -(-T * k // C)
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        order = jnp.pad(order, (0, n_chunks * C - T * k))
+        pair_weight = weights.reshape(-1)
+
+    def chunk(i, y):
+        lo = i * C
+        with jax.named_scope("dispatch"):
+            pairs = lax.dynamic_slice(order, (lo,), (C,))
+            valid = lo + jnp.arange(C) < n_held
+            token = pairs // k
+            rows = jnp.take(h, token, axis=0)
+            sizes = jnp.clip(jnp.minimum(starts + counts, lo + C)
+                             - jnp.maximum(starts, lo), 0, C)
+        with jax.named_scope("experts"):
+            g = _grouped_matmul(rows, _w(blk, "we_gate"), sizes)
+            u = _grouped_matmul(rows, _w(blk, "we_up"), sizes)
+            a = (jax.nn.silu(g) * u).astype(h.dtype)
+            out = _grouped_matmul(a, _w(blk, "we_down"), sizes)
+        with jax.named_scope("combine"):
+            # y[t] += the chunk's rows of token t, as one matmul against
+            # the 0/1 matrix (token, row): a scatter-add of the same rows
+            # takes twice as long on the chip (PERF.md §6, PR 28)
+            out = jnp.where(valid[:, None],
+                            out * jnp.take(pair_weight, pairs)[:, None], 0.0)
+            rows_of = (token[None, :] == jnp.arange(T)[:, None])
+            return y + jnp.dot(rows_of.astype(h.dtype), out.astype(h.dtype),
+                               preferred_element_type=jnp.float32)
+
+    y = lax.fori_loop(0, -(-n_held // C), chunk,
+                      jnp.zeros((T, D), jnp.float32))
+    return y, rows
+
+
+@jax.named_scope("moe")
+def _moe_ffn(blk, x, cfg: LatentMoEConfig):
+    """``x [B,S,D]`` -> ``(x + held experts' part + shared expert,
+    rows routed to each held expert by batch row [B,E])``."""
+    B, S, D = x.shape
+    h = tr._rmsnorm(x, blk["ln_ffn"], cfg.rms_norm_eps).reshape(B * S, D)
+    with jax.named_scope("router"):
+        idx, weights = route(blk, h, cfg)
+    y, rows = held_experts(blk, h, idx, weights, cfg, batch=B)
+    with jax.named_scope("shared_expert"):
+        y = y + _swiglu(h, _w(blk, "ws_gate"), _w(blk, "ws_up"),
+                        _w(blk, "ws_down"))
+    with jax.named_scope("combine"):
+        return x + y.astype(x.dtype).reshape(B, S, D), rows
+
+
+def _embed(params, tokens, cfg: LatentMoEConfig):
+    with jax.named_scope("embed"):
+        ids = jnp.clip(tokens, 0, cfg.vocab_size - 1)
+        return jnp.take(params["embed"], ids, axis=0)
+
+
+def _head(params, x_last, cfg: LatentMoEConfig):
+    with jax.named_scope("head"):
+        h = tr._rmsnorm(x_last, params["final_ln"], cfg.rms_norm_eps)
+        return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
+
+
+def _run(params, tokens, cfg: LatentMoEConfig, want_cache: bool):
+    S = tokens.shape[1]
+    cos, sin = _rotary(cfg, jnp.arange(S))
+    x = _embed(params, tokens, cfg)
+    latents = []
+    for blk in params["dense"]:
+        x, latent = _mla(blk, x, cfg, cos, sin)
+        latents.append(latent)
+        x = _dense_ffn(blk, x, cfg)
+
+    def expert_layer(x, blk):
+        x, latent = _mla(blk, x, cfg, cos, sin)
+        x, rows = _moe_ffn(blk, x, cfg)
+        return x, (rows, latent if want_cache else None)
+
+    x, (rows, scanned) = lax.scan(expert_layer, x, params["experts"])
+    cache = None
+    if want_cache:
+        cache = tuple(
+            jnp.concatenate([jnp.stack([lat[i] for lat in latents]),
+                             scanned[i]]) if latents else scanned[i]
+            for i in range(2))
+    return _head(params, x[:, -1], cfg), rows.transpose(1, 0, 2), cache
+
+
+def prefill(params, tokens, cfg: LatentMoEConfig):
+    """``tokens [B,S]`` -> ``(logits [B,V] f32 of the last position, rows
+    routed to held experts [B, expert layers, E] int32, latent cache)``; the
+    cache is ``(c_kv [L,B,S,kv_lora_rank], k_rope [L,B,S,qk_rope_head_dim])``."""
+    return _run(params, tokens, cfg, True)
+
+
+def forward(params, tokens, cfg: LatentMoEConfig):
+    """The served step: ``prefill`` without the cache."""
+    return _run(params, tokens, cfg, False)[:2]
+
+
+# ---------------------------------------------------------------------------
+# One token through the latent cache, in the absorbed form
+# ---------------------------------------------------------------------------
+
+def _mla_decode(blk, x, c_kv_cache, k_rope_cache, pos, cfg, cos, sin):
+    """``x [B,D]`` at position ``pos`` against ``c_kv_cache [B,S,rkv]`` and
+    ``k_rope_cache [B,S,dr]`` (this token's latents written at ``pos``):
+    ``W_kb`` is folded into the query and ``W_vb`` into the output, so
+    attention runs over the latents and no per-head key or value exists."""
+    h = tr._rmsnorm(x, blk["ln_attn"], cfg.rms_norm_eps)[:, None]   # [B,1,D]
+    q_nope, q_rope, c_kv, k_rope = _latents(blk, h, cfg, cos, sin)
+    c_kv_cache = lax.dynamic_update_slice_in_dim(c_kv_cache, c_kv, pos, 1)
+    k_rope_cache = lax.dynamic_update_slice_in_dim(k_rope_cache, k_rope,
+                                                   pos, 1)
+    q_lat = jnp.einsum("bhsk,chk->bhsc", q_nope, _w(blk, "w_kb"))
+    scores = (jnp.einsum("bhsc,btc->bhst", q_lat, c_kv_cache,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhsk,btk->bhst", q_rope, k_rope_cache,
+                           preferred_element_type=jnp.float32))
+    scores = scores * softmax_scale(cfg)
+    seen = jnp.arange(c_kv_cache.shape[1]) <= pos
+    p = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
+    o_lat = jnp.einsum("bhst,btc->bhsc", p.astype(x.dtype), c_kv_cache)
+    o = jnp.einsum("bhsc,chk->bhsk", o_lat, _w(blk, "w_vb"))
+    out = jnp.einsum("bhsk,hkd->bsd", o, _w(blk, "w_o"))
+    return x + out[:, 0], c_kv_cache, k_rope_cache
+
+
+def decode_step(params, token, cache, pos, cfg: LatentMoEConfig):
+    """``token [B]`` at position ``pos`` -> ``(logits [B,V], cache)``; the
+    cache's sequence axis is as long as the caller allocated."""
+    c_kv_all, k_rope_all = cache
+    cos, sin = _rotary(cfg, jnp.reshape(pos, (1,)))
+    x = _embed(params, token, cfg)
+    n_dense = len(params["dense"])
+    new_ckv, new_kr = [], []
+    for i, blk in enumerate(params["dense"]):
+        x, ckv, kr = _mla_decode(blk, x, c_kv_all[i], k_rope_all[i], pos,
+                                 cfg, cos, sin)
+        new_ckv.append(ckv)
+        new_kr.append(kr)
+        x = _dense_ffn(blk, x[:, None], cfg)[:, 0]
+
+    def expert_layer(x, scanned):
+        blk, ckv, kr = scanned
+        x, ckv, kr = _mla_decode(blk, x, ckv, kr, pos, cfg, cos, sin)
+        x, _ = _moe_ffn(blk, x[:, None], cfg)
+        return x[:, 0], (ckv, kr)
+
+    x, (ckv, kr) = lax.scan(
+        expert_layer, x,
+        (params["experts"], c_kv_all[n_dense:], k_rope_all[n_dense:]))
+    cache = (jnp.concatenate([jnp.stack(new_ckv), ckv]),
+             jnp.concatenate([jnp.stack(new_kr), kr]))
+    return _head(params, x, cfg), cache
+
+
+# ---------------------------------------------------------------------------
+# What a forward needs
+# ---------------------------------------------------------------------------
+
+def layer_matmul_params(cfg: LatentMoEConfig) -> Dict[str, float]:
+    """Matrix elements a token passes through, by part of a layer."""
+    D, H = cfg.hidden_size, cfg.num_attention_heads
+    expert = 3 * D * cfg.moe_intermediate_size
+    return {
+        "mla": (D * cfg.q_lora_rank + cfg.q_lora_rank * H * cfg.qk_head_dim
+                + D * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+                + cfg.kv_lora_rank * H
+                * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+                + H * cfg.v_head_dim * D),
+        "dense_ffn": 3 * D * cfg.intermediate_size,
+        "router": D * cfg.routed_experts_total,
+        "shared_expert": expert * cfg.n_shared_experts,
+        # a token's expected pairs on held experts under even routing
+        "held_experts": expert * cfg.num_experts_per_tok
+        * cfg.n_routed_experts / cfg.routed_experts_total,
+    }
+
+
+def flops_per_inference(cfg: LatentMoEConfig) -> float:
+    """FLOPs one prompt of ``seq_len`` tokens needs: every matrix a token
+    passes through, the causal half of the scores and of P·v, the pairs on
+    held experts at even routing's expectation, the last position's head.
+    No padding, norms or rotary."""
+    S, H = cfg.seq_len, cfg.num_attention_heads
+    part = layer_matmul_params(cfg)
+    n_dense, n_moe = cfg.first_k_dense_replace, cfg.n_expert_layers
+    per_token = 2.0 * (
+        cfg.num_hidden_layers * part["mla"] + n_dense * part["dense_ffn"]
+        + n_moe * (part["router"] + part["shared_expert"]
+                   + part["held_experts"]))
+    # a query at position t sees t + 1 keys: S (S + 1) / 2 pairs a head
+    attention = (2.0 * cfg.num_hidden_layers * H
+                 * (cfg.qk_head_dim + cfg.v_head_dim) * S * (S + 1) / 2)
+    head = 2.0 * cfg.hidden_size * cfg.vocab_size
+    return S * per_token + attention + head

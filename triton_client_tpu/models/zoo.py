@@ -447,6 +447,7 @@ def register_all(registry: ModelRegistry) -> None:
     registry.register_model(language.make_ensemble_llama())
     registry.register_model(language.make_longctx_tpu())
     registry.register_model(language.make_moe_tpu())
+    registry.register_model(language.make_kimi_k2())
     from .decode import DecodeModel, make_llama_generate
 
     decode = DecodeModel()

@@ -40,7 +40,7 @@ _NEG_INF = -1e30
 def flash_attention_reference(q, k, v, *, causal: bool = True, sm_scale=None):
     """Plain-jnp attention with the same signature/semantics as the kernel.
 
-    q, k, v: [B, H, S, D]; returns [B, H, S, D] in q.dtype.
+    q, k: [B, H, S, D]; v: [B, H, S, Dv]; returns [B, H, S, Dv] in q.dtype.
     """
     B, H, S, D = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
@@ -60,7 +60,9 @@ def _loop_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, block_q,
                  block_k, seq_len, n_kblocks):
     """Long rows: one (batch·head, q-block) program. Refs carry a leading
     length-1 block dim; k/v refs hold the head's full (padded) sequence,
-    walked in ``block_k`` steps under the online-softmax carry."""
+    walked in ``block_k`` steps under the online-softmax carry.  q and k
+    share one width, v and the output another (latent attention's 192 and
+    128; the same everywhere else)."""
     qi = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     ki = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
     q_start = _pl().program_id(1) * block_q
@@ -99,10 +101,9 @@ def _loop_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, block_q,
         )
         return m_new, l_new, acc_new
 
-    D = q_ref.shape[-1]
     m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
-    a0 = jnp.zeros((block_q, D), jnp.float32)
+    a0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)  # follows v
     if causal:
         # skip k-blocks that lie entirely above the diagonal: the last key
         # this q-block may attend to is q_start + block_q - 1, so only
@@ -203,39 +204,41 @@ def _loop_call(q, k, v, causal, scale, interpret):
     from jax.experimental import pallas as pl
 
     B, H, S, D = q.shape
+    Dv = v.shape[-1]
     bq, bk = (256, 512) if S >= 1024 else (128, 128)
     pad = -S % bk
     if pad:
         zeros = [(0, 0), (0, 0), (0, pad), (0, 0)]
         q, k, v = (jnp.pad(x, zeros) for x in (q, k, v))
     Sp = S + pad
-    q, k, v = (x.reshape(B * H, Sp, D) for x in (q, k, v))
+    q, k, v = (x.reshape(B * H, Sp, x.shape[-1]) for x in (q, k, v))
     kernel = functools.partial(
         _loop_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         seq_len=S, n_kblocks=Sp // bk)
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((B * H, Sp, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * H, Sp, Dv), q.dtype),
         grid=(B * H, Sp // bq),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
             pl.BlockSpec((1, Sp, D), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, Sp, D), lambda bh, qi: (bh, 0, 0)),
+            pl.BlockSpec((1, Sp, Dv), lambda bh, qi: (bh, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
+        out_specs=pl.BlockSpec((1, bq, Dv), lambda bh, qi: (bh, qi, 0)),
         interpret=interpret,
     )(q, k, v)
-    out = out.reshape(B, H, Sp, D)
+    out = out.reshape(B, H, Sp, Dv)
     return out[:, :, :S, :] if pad else out
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "sm_scale", "interpret"))
 def _flash_call(q, k, v, causal, sm_scale, interpret):
     """The form follows the shape: a key row short enough for one f32 score
-    tile in VMEM takes the whole-row kernel, a longer one the looped."""
+    tile in VMEM takes the whole-row kernel, a longer one the looped, and so
+    does a value narrower or wider than the keys."""
     S, D = q.shape[2:]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
-    if _round_up(S, 128) <= _ROW_MAX_S:
+    if _round_up(S, 128) <= _ROW_MAX_S and v.shape[-1] == D:
         return _row_call(q, k, v, causal, scale, interpret)
     return _loop_call(q, k, v, causal, scale, interpret)
 
@@ -267,8 +270,9 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q, k, v, *, causal: bool = True, sm_scale=None,
                     interpret: bool = False, force: bool = False):
     """Attention over [B, H, S, D] tensors without the scores in HBM;
-    differentiable.  Block shapes and the kernel's form (whole-row or
-    looped) follow ``(S, D)``: see :func:`_flash_call`.
+    differentiable.  ``v`` may have another width than ``q`` and ``k``; the
+    output and the VJP follow it.  Block shapes and the kernel's form
+    (whole-row or looped) follow ``(S, D)``: see :func:`_flash_call`.
 
     On a TPU backend (or with ``force``) this runs the compiled pallas
     kernel, and a Mosaic refusal raises — no fallback.  Off TPU, with

@@ -2073,7 +2073,11 @@ class InferenceCore:
             with annotation("step.dispatch", model=model.name,
                             bucket=padded_n, rows=rows):
                 t_c0 = time.monotonic_ns()
-                outputs = model.execute(inputs, params)
+                # a padded step's parameters say how many rows are real, for
+                # a model whose host_post counts by row
+                outputs = model.execute(
+                    inputs, params if real_batch is None
+                    else {**params, "real_batch": real_batch})
                 t_c1 = time.monotonic_ns()
             if exec_stats is not None:
                 # the step as the host lives it (ModelStats.record folds
@@ -2502,6 +2506,7 @@ class InferenceCore:
         out = []
         for m in models:
             s = m.stats
+            s.settle_expert_rows()
             with s.lock:
                 out.append(
                     {

@@ -18,8 +18,9 @@ from __future__ import annotations
 import abc
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Deque, Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -172,7 +173,16 @@ class ModelStats:
     bucket_rows: int = 0        # rows executed, pad rows included
     pause_count: int = 0        # collector / late-loop pauses that held
     pause_ns: int = 0           # requests of this model
+    # an expert layer's routing, counted on the device and read back with
+    # the answer (models/latent_moe.py); 0 for a model without one
+    expert_rows: int = 0          # (token, expert) pairs on held experts
+    expert_tokens: int = 0        # tokens x expert layers run, no pad rows
+    expert_rows_busiest: int = 0  # per layer and execution, the fullest
+    #                               held expert's rows, summed
     lock: threading.Lock = field(default_factory=threading.Lock)
+    # steps whose counts are still on the device: (array [rows, layers,
+    # experts], real rows, tokens a row)
+    _expert_pending: Deque = field(default_factory=deque)
 
     def inc_pending(self) -> None:
         with self.lock:
@@ -242,6 +252,36 @@ class ModelStats:
             self.lock.release()
         return True
 
+    def queue_expert_rows(self, rows, real_rows: int,
+                          tokens_per_row: int) -> None:
+        """A model's ``host_post`` hands over its step's routing counts
+        (``int32 [batch, expert layers, held experts]``, still on the
+        device) with the rows the batcher did not pad on: start their copy
+        to the host and fold the earlier steps that have finished."""
+        if hasattr(rows, "copy_to_host_async"):
+            rows.copy_to_host_async()
+        self._expert_pending.append((rows, real_rows, tokens_per_row))
+        self.settle_expert_rows()
+
+    def settle_expert_rows(self) -> None:
+        """Fold the queued steps' counts into the three entries, oldest
+        first, up to the first whose step is still running on the device:
+        like ``inference_count``, the entries count finished steps, and
+        reading them never waits for the device."""
+        with self.lock:
+            while self._expert_pending:
+                rows, real_rows, tokens_per_row = self._expert_pending[0]
+                ready = getattr(rows, "is_ready", None)
+                if ready is not None and not ready():
+                    return
+                self._expert_pending.popleft()
+                counts = np.asarray(rows)[:real_rows]
+                self.expert_rows += int(counts.sum())
+                self.expert_tokens += \
+                    real_rows * tokens_per_row * counts.shape[1]
+                self.expert_rows_busiest += int(
+                    counts.sum(axis=0).max(axis=-1).sum())
+
     def extension_entries(self) -> Dict[str, Dict[str, int]]:
         """The extension's ``inference_stats`` entries (caller holds
         ``lock``), in path order."""
@@ -255,6 +295,10 @@ class ModelStats:
             "device_wait": {"count": n, "ns": self.device_wait_ns},
             "bucket_rows": {"count": self.bucket_rows, "ns": 0},
             "pause": {"count": self.pause_count, "ns": self.pause_ns},
+            "expert_rows": {"count": self.expert_rows, "ns": 0},
+            "expert_tokens": {"count": self.expert_tokens, "ns": 0},
+            "expert_rows_busiest": {"count": self.expert_rows_busiest,
+                                    "ns": 0},
         }
 
 
