@@ -3,13 +3,14 @@ dynamic-batcher parameter grouping, parallel ensemble DAG execution with real
 stats, and sequence-state idle eviction (VERDICT round-1 weak items 6/7)."""
 
 import asyncio
+import threading
 import time
 
 import numpy as np
 import pytest
 
 from triton_client_tpu.models import zoo
-from triton_client_tpu.server.core import InferenceCore
+from triton_client_tpu.server.core import InferenceCore, InferError
 from triton_client_tpu.server.model import (
     EnsembleModel,
     PyModel,
@@ -124,6 +125,286 @@ class TestBatcherParamGrouping:
                 r.outputs[0].data, np.full((5, 4), 2.0 * i, np.float32))
         assert sum(execute_batches) == 20
         assert max(execute_batches) <= 8, execute_batches
+
+
+class _GatedRig:
+    """A batched model whose every execution blocks on a gate of its own,
+    so a test makes the device's backlog by hand.  Request ``v`` carries
+    ``v`` in every row; ``executions`` holds the first column of what each
+    execution was handed, pad rows (0.0) included, in dispatch order."""
+
+    def __init__(self, buckets=(1, 2, 4, 8, 16, 32), max_bs=32,
+                 delay_us=100_000):
+        self.executions = []
+        self._gates = {}
+        self._all_open = False
+        self._lock = threading.Lock()
+        cfg = make_config(
+            "gated",
+            inputs=[("INPUT", "FP32", [4])],
+            outputs=[("OUTPUT", "FP32", [4])],
+            max_batch_size=max_bs,
+            preferred_batch_sizes=list(buckets),
+            max_queue_delay_us=delay_us,
+        )
+        registry = ModelRegistry()
+        registry.register_model(PyModel(cfg, self._fn))
+        self.core = InferenceCore(registry)
+
+    def _gate(self, k):
+        # caller holds the lock
+        gate = self._gates.setdefault(k, threading.Event())
+        if self._all_open:
+            gate.set()
+        return gate
+
+    def _fn(self, inputs, params):
+        with self._lock:
+            gate = self._gate(len(self.executions))
+            self.executions.append(inputs["INPUT"][:, 0].tolist())
+        assert gate.wait(30)
+        return {"OUTPUT": inputs["INPUT"]}
+
+    def open(self, k=None):
+        """Let execution ``k`` finish; every one, now and later, without."""
+        with self._lock:
+            if k is None:
+                self._all_open = True
+                for gate in self._gates.values():
+                    gate.set()
+            else:
+                self._gate(k).set()
+
+    @property
+    def batcher(self):
+        (b,) = self.core._batchers.values()
+        return b
+
+    def ahead(self):
+        return len(self.batcher._batch_tasks) if self.core._batchers else 0
+
+    def held(self, where):
+        """Values of the requests the pump holds in ``_pending``/``_carry``."""
+        return [float(item[0]["INPUT"][0, 0])
+                for item in getattr(self.batcher, where)]
+
+    def send(self, value, rows=1, priority=0, deadline_s=None):
+        req = _request("gated", np.full((rows, 4), float(value)))
+        req.priority = priority
+        if deadline_s is not None:
+            req.deadline_ns = time.monotonic_ns() + int(deadline_s * 1e9)
+        return asyncio.ensure_future(self.core.infer(req))
+
+    async def until(self, cond, what):
+        for _ in range(3000):
+            if cond():
+                return
+            await asyncio.sleep(0.002)
+        raise AssertionError(f"never came: {what}")
+
+    async def lead(self, n=1):
+        """``n`` single-row batches in flight (values 100, 101, ...), each
+        blocked on its gate.  With one ahead the second closes at bucket 1
+        when its window ends."""
+        tasks = []
+        for k in range(n):
+            tasks.append(self.send(100 + k))
+            await self.until(lambda: self.ahead() == k + 1
+                             and len(self.executions) == k + 1,
+                             f"lead batch {k} in flight")
+        return tasks
+
+    async def finish(self, tasks):
+        """Open every gate, gather the answers, stop the core."""
+        self.open()
+        got = await asyncio.gather(*tasks, return_exceptions=True)
+        await self.core.shutdown()
+        return got
+
+
+def _real(execution):
+    return [v for v in execution if v != 0.0]
+
+
+async def _carry_21_behind_one():
+    # 21 single rows behind one batch in flight: 16 run with no pad, the
+    # other 5 lead the next batch in arrival order
+    rig = _GatedRig()
+    tasks = await rig.lead(1)
+    tasks += [rig.send(v) for v in range(1, 22)]
+    await rig.until(lambda: len(rig.executions) == 2, "the batch of 16")
+    assert rig.executions[1] == [float(v) for v in range(1, 17)]
+    await rig.until(lambda: rig.held("_pending") == [17., 18., 19., 20., 21.],
+                    "the carried five lead the next batch")
+    assert rig.ahead() == 2 and len(rig.executions) == 2  # and it stays open
+    got = await rig.finish(tasks)
+    assert not any(isinstance(g, Exception) for g in got)
+    later = [v for e in rig.executions[2:] for v in _real(e)]
+    assert later == [17., 18., 19., 20., 21.]
+
+
+async def _idle_pads_at_the_windows_end():
+    # nothing in flight: three waiting rows pad to 4 and go, as before
+    rig = _GatedRig()
+    rig.open()
+    t0 = time.monotonic()
+    got = await asyncio.gather(*(rig.send(v) for v in (1, 2, 3)))
+    assert time.monotonic() - t0 >= 0.1  # the queue delay, from arrival
+    await rig.core.shutdown()
+    assert rig.executions == [[1., 2., 3., 0.]]
+    assert [float(r.outputs[0].data[0, 0]) for r in got] == [1., 2., 3.]
+
+
+async def _no_bucket_small_enough_still_pads():
+    # buckets start at 8: five rows under a backlog pad to 8, none carried
+    rig = _GatedRig(buckets=(8, 16, 32, 64), max_bs=64)
+    tasks = await rig.lead(1)
+    tasks += [rig.send(v) for v in range(1, 6)]
+    await rig.until(lambda: len(rig.executions) == 2, "the batch of five")
+    assert rig.executions[1] == [1., 2., 3., 4., 5., 0., 0., 0.]
+    assert not rig.batcher._carry and not rig.batcher._pending
+    await rig.finish(tasks)
+
+
+async def _three_row_requests_fewest_pad_rows():
+    # no prefix of 3-row requests lands on a bucket: 15 of 18 rows pad to
+    # 16 (one pad row; 18 would pad to 32), the sixth request is carried
+    rig = _GatedRig()
+    tasks = await rig.lead(1)
+    tasks += [rig.send(v, rows=3) for v in range(1, 7)]
+    await rig.until(lambda: len(rig.executions) == 2, "the batch of 15")
+    assert rig.executions[1] == [float(v) for v in range(1, 6)
+                                 for _ in range(3)] + [0.]
+    await rig.until(lambda: rig.held("_pending") == [6.], "the sixth held")
+    got = await rig.finish(tasks)
+    assert got[-1].outputs[0].data.shape == (3, 4)
+
+
+async def _top_bucket_request_passes_whole():
+    # a client-made [32] batch is the top bucket: it goes at once, whole,
+    # even behind two batches
+    rig = _GatedRig(delay_us=20_000)
+    tasks = await rig.lead(2)
+    tasks.append(rig.send(7, rows=32))
+    await rig.until(lambda: len(rig.executions) == 3, "the [32] request")
+    assert rig.executions[2] == [7.] * 32 and rig.ahead() == 3
+    await rig.finish(tasks)
+
+
+async def _two_ahead_stays_open():
+    # behind two batches an under-full batch waits and grows; with one
+    # ahead it closes at the bucket it fills and carries the rest
+    rig = _GatedRig(delay_us=20_000)
+    tasks = await rig.lead(2)
+    tasks += [rig.send(v) for v in (1, 2, 3)]
+    await asyncio.sleep(0.1)  # five queue delays
+    assert len(rig.executions) == 2 and rig.ahead() == 2
+    rig.open(0)
+    await rig.until(lambda: len(rig.executions) == 3, "the batch of two")
+    assert rig.executions[2] == [1., 2.]
+    await rig.until(lambda: rig.held("_pending") == [3.], "the third held")
+    rig.open(1)
+    await rig.until(lambda: len(rig.executions) == 4, "the third runs")
+    assert rig.executions[3] == [3.]
+    await rig.finish(tasks)
+
+
+async def _carried_request_expires_with_zero_compute():
+    rig = _GatedRig(delay_us=20_000)
+    tasks = await rig.lead(1)
+    tasks += [rig.send(v) for v in (1, 2, 3, 4)]
+    tasks.append(rig.send(5, deadline_s=0.25))
+    await rig.until(lambda: len(rig.executions) == 2, "the batch of four")
+    assert rig.executions[1] == [1., 2., 3., 4.]
+    await rig.until(lambda: rig.held("_pending") == [5.], "the fifth held")
+    await asyncio.sleep(0.3)
+    got = await rig.finish(tasks)
+    assert isinstance(got[-1], InferError) and got[-1].http_status == 504
+    assert not any(isinstance(g, Exception) for g in got[:-1])
+    assert all(5. not in e for e in rig.executions)
+    assert rig.core.deadline_exceeded_by_model == {"gated": 1}
+
+
+async def _carried_request_at_shutdown_gets_503():
+    rig = _GatedRig(delay_us=20_000)
+    tasks = await rig.lead(1)
+    tasks += [rig.send(v) for v in (1, 2, 3, 4, 5)]
+    await rig.until(lambda: len(rig.executions) == 2
+                    and rig.held("_pending") == [5.], "the fifth held")
+    down = asyncio.ensure_future(rig.core.shutdown(drain_s=0.05))
+    got = await asyncio.wait_for(asyncio.gather(
+        tasks[-1], return_exceptions=True), 10)
+    assert isinstance(got[0], InferError) and got[0].http_status == 503
+    assert len(rig.executions) == 2  # the batches in flight still run
+    rig.open()
+    await down
+    got = await asyncio.gather(*tasks[:-1])
+    assert len(got) == 5
+
+
+async def _tiers_keep_priority_across_a_carry():
+    # held behind two batches, arrivals stay in the tiered queue: tier 0
+    # leaves it first.  The carried tail keeps its place: a later tier-0
+    # arrival does not pass it, since nothing goes back through the queue
+    rig = _GatedRig(delay_us=20_000)
+    tasks = await rig.lead(2)
+    tasks.append(rig.send(1, priority=1))
+    await rig.until(lambda: rig.held("_pending") == [1.], "the first taken")
+    tasks += [rig.send(v, priority=1) for v in (2, 3)]
+    tasks += [rig.send(v, priority=0) for v in (4, 5)]
+    await rig.until(lambda: rig.batcher._queue.depths()[:2] == [2, 2],
+                    "four left in the queue")
+    rig.open(0)
+    await rig.until(lambda: len(rig.executions) == 3, "the batch of four")
+    assert rig.executions[2] == [1., 4., 5., 2.]
+    await rig.until(lambda: rig.held("_pending") == [3.], "the tail held")
+    tasks.append(rig.send(6, priority=0))
+    await rig.until(lambda: rig.batcher._queue.qsize() == 1, "6 queued")
+    rig.open(1)
+    await rig.until(lambda: len(rig.executions) == 4, "the next batch")
+    assert rig.executions[3] == [3., 6.]
+    await rig.finish(tasks)
+
+
+async def _batch_carry_counts():
+    rig = _GatedRig(delay_us=50_000)
+    tasks = await rig.lead(1)
+    tasks += [rig.send(v) for v in range(1, 22)]
+    await rig.until(lambda: len(rig.executions) == 2, "16 run, 5 carried")
+    rig.open(0)
+    await rig.until(lambda: len(rig.executions) == 3, "4 run, 1 carried")
+    assert rig.executions[2] == [17., 18., 19., 20.]
+    rig.open(1)
+    await rig.until(lambda: len(rig.executions) == 4, "the last one")
+    assert rig.executions[3] == [21.]
+    await rig.finish(tasks)
+    (row,) = rig.core.statistics("gated")
+    ext = row["inference_stats"]
+    assert ext["batch_carry"] == {"count": 2, "ns": 0}
+    assert ext["batch_carry_rows"] == {"count": 5 + 1, "ns": 0}
+    assert row["inference_count"] == 22 and row["execution_count"] == 4
+    assert ext["bucket_rows"]["count"] == 22  # not one pad row
+
+
+class TestBatcherBacklog:
+    """A batch is padded only while nothing of the model is in flight; with
+    a batch ahead it closes at the bucket it fills and carries the rest,
+    with two ahead it stays open (``_DynamicBatcher``'s docstring)."""
+
+    @pytest.mark.parametrize("scenario", [
+        _carry_21_behind_one,
+        _idle_pads_at_the_windows_end,
+        _no_bucket_small_enough_still_pads,
+        _three_row_requests_fewest_pad_rows,
+        _top_bucket_request_passes_whole,
+        _two_ahead_stays_open,
+        _carried_request_expires_with_zero_compute,
+        _carried_request_at_shutdown_gets_503,
+        _tiers_keep_priority_across_a_carry,
+        _batch_carry_counts,
+    ], ids=lambda f: f.__name__.lstrip("_"))
+    def test_batch_is_sized_against_the_backlog(self, scenario):
+        _run(scenario())
 
 
 class TestEnsembleDag:

@@ -19,6 +19,7 @@ thread-pool executor so the event loop keeps serving while XLA executes
 from __future__ import annotations
 
 import asyncio
+import bisect
 import collections
 import contextvars
 import threading
@@ -226,30 +227,60 @@ class _ResponseCache:
 
 
 class _DynamicBatcher:
-    """Queue + pad-to-bucket batcher for one model.
+    """Queue + bucket batcher for one model.
 
     Groups concurrent requests up to ``max_queue_delay_microseconds`` /
     preferred batch sizes (reference behavior contract: BASELINE config #4
-    "dynamic batching"), concatenates along the batch axis, pads the batch
-    dim to the smallest configured bucket ≥ actual so XLA sees a bounded set
-    of shapes, executes once, splits results.
+    "dynamic batching"), concatenates along the batch axis, executes once
+    at one of the configured buckets so XLA sees a bounded set of shapes,
+    splits results.
+
+    A batch is sized against the device's backlog, read from one
+    observable: the batches of this model in flight (``_batch_tasks``).
+
+    * **None in flight**: the batch collects for the queue delay from its
+      first member's arrival, is padded to the smallest bucket ≥ its rows
+      and goes at once.  That is the latency path of an idle server and
+      the reason padding exists.
+    * **One in flight**: at the window's end the batch closes at the
+      bucket it fills: the longest prefix of the collected requests whose
+      rows sum to a bucket exactly (else the prefix that leaves the fewest
+      pad rows), and the requests after it lead the next batch
+      (``_carry``).  A pad row would wait on the device behind the batch
+      ahead like a real one; carried, its place goes to a request that
+      arrives meanwhile (the reference's ``preferred_batch_size``:
+      dispatch a preferred size from what is queued, leave the rest).
+    * **Two or more in flight**: a batch short of the top bucket stays
+      open.  Dispatched it would wait on the device's FIFO for both; held
+      here it costs its members nothing and grows.  One running and one
+      behind it keep the chip fed, so the depth is the literal 2.
+
+    A batch forms once its in-flight permit is held, so whatever arrives
+    while every permit is taken joins the batch that is forming and not
+    the one after.  While two batches are ahead the forming batch leaves
+    requests in the queue (still ordered by tier, still preemptible)
+    until it can close with them.
 
     Queue items are ``(inputs, params, fut, enqueue_ns, trace,
     deadline_ns, (tenant, tier))``; an item whose deadline already passed
-    is dropped at dequeue and again at batch assembly — zero compute for a
-    request whose client gave up while it queued.
+    is dropped at dequeue (a carried one again when its batch forms) and
+    again at batch assembly — zero compute for a request whose client
+    gave up while it queued.
 
     The queue is the QoS layer's :class:`TieredQueue`: strict-priority (or
     weighted-fair) dequeue across tiers, FIFO within one, with the
     best-effort lane preemptible under admission pressure (see
-    ``InferenceCore._admit``).
+    ``InferenceCore._admit``).  A carried request never goes back through
+    it: it was dequeued in order and keeps its place.
     """
 
-    # Batches in flight concurrently: device dispatch is async, so letting
-    # several padded batches ride the (possibly high-RTT) device link at once
-    # converts per-batch latency into pipeline throughput.  This is the
-    # static default; the fleet controller's autoscaler moves the live
-    # value per model through ``set_instances`` (server/fleet.py).
+    # Batches in flight concurrently: device dispatch is async, so a batch
+    # queued on the device behind the running one starts the moment that
+    # one ends, while the host assembles the next and splits the last.
+    # Only batches that reach the top bucket go this deep (class
+    # docstring).  This is the static default; the fleet controller's
+    # autoscaler moves the live value per model through ``set_instances``
+    # (server/fleet.py).
     MAX_INFLIGHT = 4
 
     def __init__(self, core: "InferenceCore", model: Model):
@@ -277,6 +308,15 @@ class _DynamicBatcher:
         # running batches finish
         self._shrink_debt = 0
         self._batch_tasks: set = set()
+        # what the pump holds outside the queue: the batch that is forming,
+        # and the requests dequeued for it that lead the next one instead
+        # (a batch closed at a bucket leaves its tail here; so does a
+        # request that would overflow max_batch_size), oldest first
+        self._pending: list = []
+        self._carry: list = []
+        # set by an arrival and by a batch finishing: the two events that
+        # can change what the forming batch should do
+        self._wake = asyncio.Event()
         # registry generation of the bound model; InferenceCore._batcher
         # retires this batcher when the instance behind the name is swapped
         self.generation = 0
@@ -301,6 +341,11 @@ class _DynamicBatcher:
         if self._task is None or self._task.done():
             self._task = asyncio.get_running_loop().create_task(self._run())
 
+    def idle(self) -> bool:
+        """Nothing queued, forming, carried or in flight."""
+        return (self._queue.empty() and not self._batch_tasks
+                and not self._pending and not self._carry)
+
     async def submit(self, inputs: Dict[str, np.ndarray],
                      parameters: Dict[str, Any], trace=None,
                      deadline_ns: int = 0, tenant: str = "",
@@ -310,6 +355,7 @@ class _DynamicBatcher:
         await self._queue.put(
             (inputs, parameters, fut, time.monotonic_ns(), trace,
              deadline_ns, (tenant, tier)), tier=tier)
+        self._wake.set()
         return await fut
 
     def _drop_if_expired(self, item) -> bool:
@@ -326,69 +372,123 @@ class _DynamicBatcher:
                 "deadline while queued", http_status=504))
         return True
 
+    def _bucket_for(self, rows: int) -> Optional[int]:
+        """The smallest configured bucket that holds ``rows``."""
+        at = bisect.bisect_left(self._buckets, rows)
+        return self._buckets[at] if at < len(self._buckets) else None
+
+    def _bucket_prefix(self, pending: list) -> Tuple[int, int]:
+        """How many of ``pending``'s requests, from the front, run now while
+        a batch is ahead, and their rows: the prefix that leaves the fewest
+        pad rows, the longest of those (so the longest that fills a bucket
+        exactly where one does).  All of them (0 rows) where the first
+        request alone is past the top bucket."""
+        best, best_rows, best_pad, rows = len(pending), 0, None, 0
+        for k, item in enumerate(pending, 1):
+            rows += _batch_count(item[0])
+            bucket = self._bucket_for(rows)
+            if bucket is None:
+                break
+            if best_pad is None or bucket - rows <= best_pad:
+                best, best_rows, best_pad = k, rows, bucket - rows
+        return best, best_rows
+
+    async def _form(self) -> Tuple[list, int]:
+        """Collect the next batch (permit held) and close it by the class
+        docstring's rules.  Returns its requests and the rows carried over
+        to the next batch by a close at a bucket; no requests when every
+        one taken had expired and the queue is empty."""
+        pending, carry, queue = self._pending, self._carry, self._queue
+        # rows at which the batch is full and goes whatever is ahead
+        cap = min(self._buckets[-1], self._max_bs) \
+            if self._buckets else self._max_bs
+        total = 0
+        overflowed = False
+        while True:
+            self._wake.clear()
+            ahead = len(self._batch_tasks)
+            # carried requests first (they left the queue in order); from
+            # the queue everything, or under two batches nothing until it
+            # holds enough to fill the batch (a request has a row or more)
+            held_back = ahead >= 2 and \
+                total + len(carry) + queue.qsize() < cap
+            while total < cap and (
+                    carry or not (held_back or queue.empty())):
+                item = carry.pop(0) if carry else queue.get_nowait()
+                if self._drop_if_expired(item):
+                    continue  # expired at dequeue: zero compute
+                count = _batch_count(item[0])
+                if pending and total + count > self._max_bs:
+                    # merging would break the max_batch_size contract
+                    # (an untested shape the model was never warmed for);
+                    # the request seeds the next batch instead
+                    carry.insert(0, item)
+                    overflowed = True
+                    break
+                pending.append(item)
+                total += count
+            if overflowed or total >= cap:
+                break
+            if ahead >= 2:
+                await self._wake.wait()
+                continue
+            if not pending:
+                return [], 0
+            timeout = (pending[0][3] / 1e9 + self._max_delay_s
+                       - time.monotonic())
+            if timeout <= 0:
+                break
+            try:
+                async with asyncio.timeout(timeout):
+                    await self._wake.wait()
+            except TimeoutError:
+                pass
+        batch, carried = pending[:], 0
+        if ahead and self._buckets:
+            keep, rows = self._bucket_prefix(batch)
+            if keep < len(batch):
+                carried = total - rows
+                carry[:0] = batch[keep:]
+                del batch[keep:]
+        pending.clear()
+        return batch, carried
+
+    def _batch_done(self, task) -> None:
+        if self._shrink_debt > 0:
+            # a pending scale-in absorbs this permit instead of
+            # re-releasing it — concurrency tapers to the new target as
+            # batches finish
+            self._shrink_debt -= 1
+        else:
+            self._inflight.release()
+        self._batch_tasks.discard(task)
+        self._wake.set()
+
     async def _run(self) -> None:
-        pending: list = []
-        carry = None  # request pulled from the queue that overflowed a batch
         try:
             while True:
-                if carry is not None:
-                    first, carry = carry, None
-                else:
-                    first = await self._queue.get()
-                if self._drop_if_expired(first):
-                    continue  # expired at dequeue: zero compute
-                pending = [first]
-                total = _batch_count(first[0])
-                deadline = time.monotonic() + self._max_delay_s
-                while total < self._max_bs:
-                    if self._buckets and total >= self._buckets[-1]:
-                        break
-                    timeout = deadline - time.monotonic()
-                    if timeout <= 0:
-                        break
-                    try:
-                        item = await asyncio.wait_for(self._queue.get(), timeout)
-                    except asyncio.TimeoutError:
-                        break
-                    if self._drop_if_expired(item):
-                        continue
-                    count = _batch_count(item[0])
-                    if total + count > self._max_bs:
-                        # merging would break the max_batch_size contract
-                        # (an untested shape the model was never warmed for);
-                        # the request seeds the next batch instead
-                        carry = item
-                        break
-                    pending.append(item)
-                    total += count
+                if not self._carry:
+                    self._carry.append(await self._queue.get())
                 await self._inflight.acquire()
+                batch, carried = await self._form()
+                if not batch:
+                    self._inflight.release()  # whatever it took had expired
+                    continue
                 task = asyncio.get_running_loop().create_task(
-                    self._execute_batch(pending))
+                    self._execute_batch(batch, carried))
                 self._batch_tasks.add(task)
-
-                def _done(t, *, _self=self):
-                    if _self._shrink_debt > 0:
-                        # a pending scale-in absorbs this permit instead
-                        # of re-releasing it — concurrency tapers to the
-                        # new target as batches finish
-                        _self._shrink_debt -= 1
-                    else:
-                        _self._inflight.release()
-                    _self._batch_tasks.discard(t)
-
-                task.add_done_callback(_done)
-                pending = []
+                task.add_done_callback(self._batch_done)
         except asyncio.CancelledError:
             # shutdown mid-batch: fail whatever we were holding
-            if carry is not None:
-                pending.append(carry)
-            for item in pending:
+            for item in self._pending + self._carry:
                 fut = item[2]
                 if not fut.done():
                     fut.set_exception(InferError("server is shutting down", 503))
+            self._pending.clear()
+            self._carry.clear()
             raise
 
-    async def _execute_batch(self, pending) -> None:
+    async def _execute_batch(self, pending, carried: int = 0) -> None:
         # Requests with different parameters must not share an execution —
         # the model sees one parameters dict per execute (reference dynamic
         # batching merges only parameter-compatible requests).
@@ -396,10 +496,13 @@ class _DynamicBatcher:
         for item in pending:
             key = tuple(sorted((k, repr(v)) for k, v in item[1].items()))
             groups.setdefault(key, []).append(item)
+        # ``carried`` (rows a close at a bucket left for the next batch) is
+        # counted once, with the first group's execution
         await asyncio.gather(
-            *(self._execute_group(g) for g in groups.values()))
+            *(self._execute_group(g, 0 if k else carried)
+              for k, g in enumerate(groups.values())))
 
-    async def _execute_group(self, pending) -> None:
+    async def _execute_group(self, pending, carried: int = 0) -> None:
         # last deadline gate before compute: a member that expired between
         # dequeue and its batch forming must not ride the execution
         pending = [p for p in pending if not self._drop_if_expired(p)]
@@ -407,11 +510,7 @@ class _DynamicBatcher:
             return
         counts = [_batch_count(p[0]) for p in pending]
         total = sum(counts)
-        padded = total
-        for b in self._buckets:
-            if total <= b:
-                padded = b
-                break
+        padded = self._bucket_for(total) or total
         names = list(pending[0][0].keys())
         traces = [p[4] for p in pending if p[4] is not None]
         t_asm0 = time.monotonic_ns()
@@ -455,7 +554,8 @@ class _DynamicBatcher:
             self._model.stats.record(
                 total, queue_ns, compute_ns, ok=True,
                 member_queue_ns=member_queue_ns, assembly_ns=t0 - t_asm0,
-                padded=padded, step=exec_stats, formed=True)
+                padded=padded, step=exec_stats, formed=True,
+                carried=carried)
             ds = self._core.device_stats
             if ds.enabled:
                 # one tick record per batched execution: the bucket view
@@ -1980,7 +2080,7 @@ class InferenceCore:
             return True
         deadline = time.monotonic() + max(0.0, timeout_s)
         clean = True
-        while not b._queue.empty() or b._batch_tasks:
+        while not b.idle():
             if time.monotonic() >= deadline:
                 clean = False
                 break

@@ -171,6 +171,8 @@ class ModelStats:
     dispatch_ns: int = 0        # model.execute called -> returned
     device_wait_ns: int = 0     # execute returned -> outputs on the host
     bucket_rows: int = 0        # rows executed, pad rows included
+    batch_carry_count: int = 0  # executions the batcher closed at a bucket,
+    batch_carry_rows: int = 0   # and the rows it left for the next batch
     pause_count: int = 0        # collector / late-loop pauses that held
     pause_ns: int = 0           # requests of this model
     # an expert layer's routing, counted on the device and read back with
@@ -195,7 +197,7 @@ class ModelStats:
     def record(self, batch: int, queue_ns: int, compute_ns: int, ok: bool, *,
                member_queue_ns: Optional[int] = None, assembly_ns: int = 0,
                padded: Optional[int] = None, step=None,
-               formed: bool = False) -> None:
+               formed: bool = False, carried: int = 0) -> None:
         """One execution of ``batch`` rows.  ``queue_ns``/``compute_ns`` are
         charged to every row (the v2 entries).  The extension entries:
         ``member_queue_ns`` is the rows' own waits already summed (default:
@@ -203,7 +205,8 @@ class ModelStats:
         ``padded`` the rows the execution ran with (default: ``batch``),
         ``step`` the dict ``_run_model`` filled with ``executor_wait_ns`` /
         ``dispatch_ns`` / ``device_wait_ns``; ``formed`` marks a batch the
-        dynamic batcher formed."""
+        dynamic batcher formed, ``carried`` the rows it closed the batch
+        without, at a bucket, for the next one to lead with."""
         with self.lock:
             if ok:
                 self.inference_count += batch
@@ -228,6 +231,9 @@ class ModelStats:
                 if formed:
                     self.batch_size_total += batch
                     self.batch_execution_count += 1
+                if carried:
+                    self.batch_carry_count += 1
+                    self.batch_carry_rows += carried
             else:
                 self.fail_count += batch
                 self.fail_ns += (queue_ns + compute_ns) * batch
@@ -294,6 +300,8 @@ class ModelStats:
             "dispatch": {"count": n, "ns": self.dispatch_ns},
             "device_wait": {"count": n, "ns": self.device_wait_ns},
             "bucket_rows": {"count": self.bucket_rows, "ns": 0},
+            "batch_carry": {"count": self.batch_carry_count, "ns": 0},
+            "batch_carry_rows": {"count": self.batch_carry_rows, "ns": 0},
             "pause": {"count": self.pause_count, "ns": self.pause_ns},
             "expert_rows": {"count": self.expert_rows, "ns": 0},
             "expert_tokens": {"count": self.expert_tokens, "ns": 0},
