@@ -39,6 +39,7 @@ from triton_client_tpu.server.flight_recorder import (  # noqa: E402
     parse_snapshot_limit,
 )
 from triton_client_tpu.server.testing import ServerHarness  # noqa: E402
+from triton_client_tpu.server.types import StepRecord  # noqa: E402
 from triton_client_tpu.server.trace import (  # noqa: E402
     TRACE_DEFAULTS,
     RequestTracer,
@@ -665,7 +666,8 @@ class TestReviewRegressions:
         before = (core.device_stats.snapshot()["models"].get("batchy")
                   or {}).get("inferences", 0)
         x = np.ones((4, 4), np.float32)  # bucket-4 execution, 3 real rows
-        asyncio.run(core._run_model(model, {"X": x}, {}, real_batch=3))
+        asyncio.run(core._run_model(model, {"X": x}, {}, None, StepRecord(
+            "batchy", "1", None, "batch", rows=3, bucket=4)))
         after = core.device_stats.snapshot()["models"]["batchy"]
         assert after["inferences"] - before == 3  # pad slot is not an inference
 
@@ -674,7 +676,9 @@ class TestReviewRegressions:
         model = core.registry.get("custom_identity_int32")
         for n in (3, 5, 7):  # three distinct input-shape signatures
             x = np.zeros((1, n), np.int32)
-            asyncio.run(core._run_model(model, {"INPUT0": x}, {}))
+            asyncio.run(core._run_model(
+                model, {"INPUT0": x}, {}, None, StepRecord(
+                    model.name, "1", None, "direct", rows=1, bucket=1)))
         snap = core.device_stats.snapshot()["models"]["custom_identity_int32"]
         # a PyModel never touches XLA: no compile events, and every
         # execution's compute stays in the duty/MFU window

@@ -6,7 +6,8 @@ The counters are charged per row where the work happens, whether or not a
 request carries a TraceContext, from the timestamps the spans already take:
 ``request`` (frontends), ``queue_member`` / ``batch_assembly`` /
 ``bucket_rows`` (dynamic batcher; direct path), ``executor_wait`` /
-``dispatch`` / ``device_wait`` (``InferenceCore._run_model``), ``pause``
+``dispatch`` / ``device_wait`` (the step's ``StepRecord``, stamped by
+``InferenceCore._run_model``, booked by ``InferenceCore._book``), ``pause``
 (``HostProfiler`` through the core).
 """
 

@@ -554,6 +554,120 @@ class TestEnsembleDag:
             _run(core.infer(_request("bad_ens", np.ones(4))))
 
 
+class TestStepIsBookedOnce:
+    """One executed step is one ``StepRecord`` booked once by
+    ``InferenceCore._book``, and the books it writes agree with each other
+    about it: the statistics, the collector, the ledger, the traces."""
+
+    @staticmethod
+    def _core(fail=False):
+        registry = ModelRegistry()
+
+        def fn(inputs, params):
+            if fail:
+                raise RuntimeError("boom")
+            return {"OUTPUT": inputs["INPUT"] * 2.0}
+
+        io = dict(inputs=[("INPUT", "FP32", [4])],
+                  outputs=[("OUTPUT", "FP32", [4])])
+        registry.register_model(PyModel(
+            make_config("plain", max_batch_size=8, **io), fn))
+        registry.register_model(PyModel(
+            make_config("batched", max_batch_size=8,
+                        preferred_batch_sizes=[4, 8],
+                        max_queue_delay_us=50_000, **io), fn))
+        ens = make_config("ens", max_batch_size=8, platform="ensemble",
+                          backend="", **io)
+        step = ens.ensemble_scheduling.step.add()
+        step.model_name = "plain"
+        step.input_map["INPUT"] = "INPUT"
+        step.output_map["OUTPUT"] = "OUTPUT"
+        registry.register_model(EnsembleModel(ens))
+        core = InferenceCore(registry)
+        booked = []
+        book = core._book
+        core._book = lambda step: (booked.append(step), book(step))[1]
+        return core, booked
+
+    @staticmethod
+    def _books(core, name):
+        stats = core.registry.get(name).stats
+        snap = core.device_stats.snapshot()
+        compute = snap["models"].get(name, {})
+        return {
+            "bucket_rows": stats.bucket_rows,
+            "infer_count": stats.infer_count,
+            "fail_count": stats.fail_count,
+            "dispatch_ns": stats.dispatch_ns,
+            "inferences": compute.get("inferences", 0),
+            "compute_us": compute.get("compute_ms_total", 0.0) * 1e3,
+            "tick_padded": sum(b["padded_total"] for b in
+                               snap["ticks"].get(name, {}).values()),
+            "ledger_us": core.cost_ledger.totals(name)["device_us"],
+        }
+
+    @pytest.mark.parametrize("case", [
+        "direct_request", "batched_group_of_three_with_a_pad_row",
+        "ensemble_member_not_batchable", "execute_raises"])
+    def test_books_agree_about_one_step(self, case):
+        core, booked = self._core(fail=case == "execute_raises")
+        target, ran, sent = {
+            "direct_request": ("plain", "plain", [2]),
+            "batched_group_of_three_with_a_pad_row":
+                ("batched", "batched", [1, 1, 1]),
+            "ensemble_member_not_batchable": ("ens", "plain", [2]),
+            "execute_raises": ("plain", "plain", [2]),
+        }[case]
+        before = self._books(core, ran)
+
+        async def drive():
+            return await asyncio.gather(
+                *(core.infer(_request(target, np.ones((rows, 4))))
+                  for rows in sent), return_exceptions=True)
+
+        answers = _run(drive())
+        after = self._books(core, ran)
+        delta = {k: after[k] - before[k] for k in after}
+        (step,) = booked  # once, success or failure
+        rows = sum(sent)
+        assert (step.model, step.rows, len(step.members)) == \
+            (ran, rows, len(sent))
+        assert step.path == {"batched": "batch", "ens": "member"}.get(
+            target, "direct")
+        if case == "execute_raises":
+            assert all(isinstance(a, InferError) for a in answers)
+            assert not step.ok and delta == {
+                **dict.fromkeys(delta, 0), "fail_count": rows}
+            return
+        assert step.ok and not any(
+            isinstance(a, Exception) for a in answers)
+        assert step.bucket == (4 if step.formed else rows)
+        # the three stores of rows and of the compute window agree
+        assert delta["bucket_rows"] == step.bucket
+        assert delta["tick_padded"] == (step.bucket if step.formed else 0)
+        assert delta["infer_count"] == delta["inferences"] == rows
+        assert delta["dispatch_ns"] == step.window_ns * rows
+        assert delta["compute_us"] == pytest.approx(
+            step.window_ns / 1e3, abs=1.0)
+        # the members' shares sum to the window the collector recorded
+        assert delta["ledger_us"] == pytest.approx(
+            step.window_ns / 1e3, abs=1e-3)
+        for m in step.members:
+            if step.path == "member":
+                assert m.trace is None  # the ensemble's envelope is traced
+                continue
+            spans = {}
+            for span in m.trace.spans:
+                spans.setdefault(span.name, []).append(
+                    (span.start_ns, span.end_ns))
+            assert spans["COMPUTE"] == [(step.t_called, step.t_returned)]
+            assert spans["QUEUE"] == [(m.enqueue_ns, step.t_assembly)]
+            assert ("BATCH_ASSEMBLY" in spans) == step.formed
+            assert m.trace.cost["device_us"] == pytest.approx(
+                step.window_ns * m.rows / rows / 1e3, abs=0.1)
+            assert (m.trace.tick is not None) == step.formed
+
+
 class TestSequenceEviction:
     def test_idle_sequences_evicted(self):
         model = zoo.SequenceModel()

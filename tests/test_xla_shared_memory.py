@@ -316,7 +316,8 @@ import sys
 import numpy as np
 import triton_client_tpu.grpc as grpcclient
 import triton_client_tpu.utils.cuda_shared_memory as cudashm
-from triton_client_tpu.perf_analyzer import _make_data, _resolve_model, run_level
+from triton_client_tpu.perf_analyzer import (
+    _build_inputs, _make_data, _resolve_model, run_level)
 
 grpc_url = sys.argv[1]
 # examples/simple_grpc_cudashm_client.py, as its own process
@@ -351,6 +352,9 @@ assert not cudashm.allocated_shared_memory_regions()
 # tpu-perf-analyzer -m dense_tpu --shared-memory=xla, the README quickstart
 pa_inputs, pa_outputs, max_batch = _resolve_model(client, "grpc", "dense_tpu", "")
 arrays = _make_data(pa_inputs, {}, 1, max_batch, np.random.default_rng(0))
+# the shape's first request compiles in the server: over the wire, before
+# the level, whose 1.5 s a busy machine can spend compiling (throughput 0)
+client.infer("dense_tpu", _build_inputs(grpcclient, arrays, "none"))
 client.close()
 res = run_level("grpc", grpc_url, "dense_tpu", "", 2, arrays, pa_outputs,
                 "xla", 1 << 16, 1.0, warmup_s=0.5)

@@ -1021,6 +1021,21 @@ def make_prefill_tail(cfg: tr.TransformerConfig, s_max: int):
     return tail
 
 
+def _worker_socket(decode: "DecodeModel", alias: str, name: str):
+    """``__getattr__`` of the two ``Model`` adapters below: the core's
+    ``attach_*`` sockets (``InferenceCore._wire``) are the shared decode
+    worker's, where the ticks, slot admission, cost attribution and faults
+    of every name it serves happen.  The fault manager also learns the
+    adapter's model name as an alias, so a quarantine (and a probe's
+    release) covers the sequence and the generate surface together."""
+    if not name.startswith("attach_"):
+        raise AttributeError(name)
+    attach = getattr(decode, name)
+    if name == "attach_device_faults":
+        return lambda mgr: attach(mgr, alias)
+    return attach
+
+
 class DecodeModel:
     """``llama_decode``: sequence-stateful greedy decoding over a shared
     SLOT cache with continuous batching.
@@ -1123,20 +1138,10 @@ class DecodeModel:
             def unload(inner):
                 outer._shutdown()
 
-            def attach_device_stats(inner, ds):
-                outer.attach_device_stats(ds)
+            device_loop = True
 
-            def attach_memory_governor(inner, gov):
-                outer.attach_memory_governor(gov)
-
-            def attach_cost_ledger(inner, ledger):
-                outer.attach_cost_ledger(ledger)
-
-            def attach_device_faults(inner, mgr):
-                outer.attach_device_faults(mgr, inner.config.name)
-
-            def attach_chaos(inner, injector):
-                outer.attach_chaos(injector)
+            def __getattr__(inner, name):
+                return _worker_socket(outer, inner.config.name, name)
 
         self._model = _Impl(cfg)
         # device/scheduler observability sink (attach_device_stats): the
@@ -3304,29 +3309,10 @@ class GenerateModel:
             def execute_decoupled(inner, inputs, parameters):
                 return outer._generate(inputs, parameters)
 
-            def attach_device_stats(inner, ds):
-                # the generation path's ticks happen in the SHARED decode
-                # worker — route the collector there
-                outer._decode.attach_device_stats(ds)
+            device_loop = True
 
-            def attach_memory_governor(inner, gov):
-                # slot admission happens in the shared decode model —
-                # the HBM gate must see generation traffic too
-                outer._decode.attach_memory_governor(gov)
-
-            def attach_cost_ledger(inner, ledger):
-                # tick attribution happens in the SHARED decode worker —
-                # route the ledger there so generation traffic is charged
-                outer._decode.attach_cost_ledger(ledger)
-
-            def attach_device_faults(inner, mgr):
-                # faults strike the SHARED decode worker: register this
-                # model name as an alias so a quarantine (and a probe
-                # release) covers the generate surface too
-                outer._decode.attach_device_faults(mgr, inner.config.name)
-
-            def attach_chaos(inner, injector):
-                outer._decode.attach_chaos(injector)
+            def __getattr__(inner, name):
+                return _worker_socket(outer._decode, inner.config.name, name)
 
         self.model = _Impl(cfg)
 

@@ -48,6 +48,8 @@ from .types import (
     InputTensor,
     OutputTensor,
     RequestedOutput,
+    StepMember,
+    StepRecord,
 )
 
 
@@ -511,114 +513,33 @@ class _DynamicBatcher:
         counts = [_batch_count(p[0]) for p in pending]
         total = sum(counts)
         padded = self._bucket_for(total) or total
-        names = list(pending[0][0].keys())
-        traces = [p[4] for p in pending if p[4] is not None]
-        t_asm0 = time.monotonic_ns()
-        # tick profile: queue depth at assembly (requests left waiting
-        # while this tick forms — the backlog the chosen bucket geometry
-        # produces) sampled before any concat/pad work
-        queue_depth = self._queue.qsize()
-        exec_stats: Dict[str, Any] = {}
-        member_queue_ns = 0
-        for item, count in zip(pending, counts):
-            ts, trace = item[3], item[4]
-            # this request's OWN wait from enqueue until its batch formed
-            # (``queue`` below charges the first member's to every row)
-            member_queue_ns += (t_asm0 - ts) * count
-            if trace is not None:
-                trace.add_span("QUEUE", ts, t_asm0)
+        model = self._model
+        # queue depth: the backlog the chosen bucket geometry leaves
+        # waiting while this batch forms, sampled before any concat/pad
+        step = StepRecord(
+            model.name, model.served_version, model.stats, "batch",
+            rows=total, bucket=padded,
+            members=[StepMember(count, p[6][0], p[4], p[3])
+                     for p, count in zip(pending, counts)],
+            carried=carried, t_assembly=time.monotonic_ns(),
+            queue_depth=self._queue.qsize())
         try:
             merged = {}
             with annotation("batcher.assemble", bucket=padded, rows=total,
-                            queue_depth=queue_depth):
-                for n in names:
+                            queue_depth=step.queue_depth):
+                for n in pending[0][0]:
                     parts = [p[0][n] for p in pending]
                     arr = np.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
                     if padded > total:
                         pad_widths = [(0, padded - total)] + [(0, 0)] * (arr.ndim - 1)
                         arr = np.pad(arr, pad_widths)
                     merged[n] = arr
-            queue_ns = time.monotonic_ns() - pending[0][3]
-            t0 = time.monotonic_ns()
-            for trace in traces:
-                # concat + pad-to-bucket: the cost of riding a shared batch
-                trace.add_span("BATCH_ASSEMBLY", t_asm0, t0)
+            step.t_assembled = time.monotonic_ns()
             # keep_device=set(): every output resolves D2H on the executor
             # thread, not the event loop — a blocking np.asarray here would
             # stall every other request for the full device round trip.
             outputs = await self._core._run_model(
-                self._model, merged, pending[0][1], keep_device=set(),
-                real_batch=total,
-                traces=traces, exec_stats=exec_stats)
-            compute_ns = time.monotonic_ns() - t0
-            self._model.stats.record(
-                total, queue_ns, compute_ns, ok=True,
-                member_queue_ns=member_queue_ns, assembly_ns=t0 - t_asm0,
-                padded=padded, step=exec_stats, formed=True,
-                carried=carried)
-            ds = self._core.device_stats
-            if ds.enabled:
-                # one tick record per batched execution: the bucket view
-                # (nv_tpu_tick_* / pad-waste series, triton-top buckets)
-                # is aggregated from exactly these
-                ds.record_tick(
-                    self._model.name, bucket=padded, batch=total,
-                    padded=padded, queue_depth=queue_depth,
-                    assembly_ns=t0 - t_asm0,
-                    compute_ns=exec_stats.get("compute_ns", compute_ns),
-                    requests=len(pending),
-                    syncs=exec_stats.get("d2h_syncs", 0),
-                    flops=exec_stats.get("flops", 0.0),
-                    bytes_accessed=exec_stats.get("bytes_accessed", 0.0))
-                tick = {
-                    "bucket": padded, "batch": total,
-                    "pad_fraction": (round((padded - total) / padded, 4)
-                                     if padded else 0.0),
-                    "queue_depth": queue_depth,
-                    "assembly_us": round((t0 - t_asm0) / 1e3, 1),
-                    "requests": len(pending),
-                }
-                for item in pending:
-                    tr = item[4]
-                    if tr is not None:
-                        # the tick shape rides the trace record and the
-                        # flight record, so a pinned outlier shows which
-                        # bucket/occupancy it paid for
-                        tr.tick = tick
-                        if tr.flight is not None:
-                            tr.flight.tick = tick
-            ledger = self._core.cost_ledger
-            if ledger.enabled and total > 0:
-                # per-request slot-share attribution: each member owns
-                # count/total of the batch's compute window and of the
-                # signature's measured FLOPs.  The shares sum to exactly
-                # the window the tick recorded — conservation to the
-                # duty-cycle compute window is by construction.
-                exec_ns = exec_stats.get("compute_ns", compute_ns)
-                exec_flops = exec_stats.get("flops", 0.0)
-                verdict = None
-                roofline = classify_roofline(
-                    exec_flops, exec_stats.get("bytes_accessed", 0.0))
-                if roofline is not None:
-                    verdict = roofline["verdict"]
-                for item, count in zip(pending, counts):
-                    tenant = item[6][0]
-                    share = count / total
-                    dev_us = exec_ns * share / 1e3
-                    flops_share = exec_flops * share
-                    ledger.charge(self._model.name, tenant,
-                                  device_us=dev_us, flops=flops_share)
-                    tr = item[4]
-                    if tr is not None:
-                        cost = {"tenant": tenant,
-                                "device_us": round(dev_us, 1)}
-                        if flops_share:
-                            cost["flops"] = flops_share
-                        if verdict is not None:
-                            cost["roofline"] = verdict
-                        tr.cost = cost
-                        if tr.flight is not None:
-                            tr.flight.cost = cost
+                model, merged, pending[0][1], set(), step)
             offset = 0
             for item, count in zip(pending, counts):
                 fut = item[2]
@@ -629,7 +550,10 @@ class _DynamicBatcher:
                 if not fut.done():
                     fut.set_result(part)
         except Exception as e:
-            self._model.stats.record(total, 0, 0, ok=False)
+            if not step.t_assembled:
+                # the assembly itself failed (members' shapes disagree):
+                # nothing ran, so _run_model booked nothing
+                self._core._book(step)
             for item in pending:
                 fut = item[2]
                 if not fut.done():
@@ -917,9 +841,12 @@ class DeviceFaultManager:
                 "device_aborted": aborted, "device_quarantine": quarantine}
 
 
-def _batch_count(inputs: Dict[str, np.ndarray]) -> int:
+def _batch_count(inputs: Dict[str, Any]) -> int:
+    """Rows of a request or a step, off the first input's shape (read as
+    an attribute: ``np.asarray`` on a device-resident input would sync it)."""
     for v in inputs.values():
-        return int(np.asarray(v).shape[0]) if np.asarray(v).ndim > 0 else 1
+        shape = np.shape(v)
+        return int(shape[0]) if shape else 1
     return 1
 
 
@@ -1468,7 +1395,7 @@ class InferenceCore:
                 if cached is not None:
                     # cache hits still count in statistics/metrics (Triton
                     # behavior) — zero compute, real queue time
-                    model.stats.record(
+                    model.stats.record_answered(
                         _batch_count(cached) or 1,
                         time.monotonic_ns() - request.arrival_ns, 0, ok=True)
                     if trace is not None:
@@ -1487,18 +1414,17 @@ class InferenceCore:
                     model, inputs, params,
                     tenant=request.tenant, tier=request.tier)
             except Exception:
-                model.stats.record(_batch_count(inputs) or 1, queue_ns, 0, ok=False)
+                model.stats.record_answered(_batch_count(inputs) or 1, queue_ns, 0, ok=False)
                 raise
             compute_ns = time.monotonic_ns() - t0
             if trace is not None:
                 trace.ts("COMPUTE_END", t0 + compute_ns)
                 trace.add_span("COMPUTE", t0, t0 + compute_ns)
-            model.stats.record(
+            model.stats.record_answered(
                 _batch_count(inputs) or 1, queue_ns, compute_ns, ok=True)
         elif self._use_batcher(model, request):
-            # Batched execution: the batcher records this request's QUEUE /
-            # BATCH_ASSEMBLY spans and the shared batch's COMPUTE window
-            # (every traced member of a batch carries the same COMPUTE span).
+            # the batch's step record carries this request as a member:
+            # _book writes its QUEUE / BATCH_ASSEMBLY / COMPUTE spans
             outputs = await self._batcher(model).submit(
                 inputs, params, trace=trace,
                 deadline_ns=request.deadline_ns,
@@ -1513,47 +1439,23 @@ class InferenceCore:
                 if o.shm is not None
                 and self.xla_shm.is_slot_backed(o.shm.region_name)
             }
-            t0 = time.monotonic_ns()
-            queue_ns = t0 - request.arrival_ns
-            if trace is not None:
-                trace.ts("COMPUTE_START", t0)
-                trace.add_span("QUEUE", request.arrival_ns, t0)
-            device_loop = getattr(model, "attach_device_stats", None)
-            if device_loop is not None and request.tenant:
-                # device-loop models (the decode worker) attribute cost
-                # per fused tick; the tenant rides the parameters copy so
-                # the worker can label this request's slot
+            if model.device_loop and request.tenant:
+                # the decode worker charges per fused tick: the tenant rides
+                # the parameters copy to label this request's slot
                 params["_cost_tenant"] = request.tenant
-            exec_stats: Dict[str, Any] = {}
+            step = self._lone_step(model, inputs, "direct", request.tenant,
+                                   trace, request.arrival_ns)
+            if trace is not None:
+                trace.ts("COMPUTE_START", step.t_assembled)
             try:
                 outputs = await self._run_model(
-                    model, inputs, params, keep_device=keep_device,
-                    traces=(trace,) if trace is not None else (),
-                    exec_stats=exec_stats, cost_tenant=request.tenant)
+                    model, inputs, params, keep_device, step)
             except InferError:
-                model.stats.record(_batch_count(inputs) or 1, queue_ns, 0, ok=False)
                 raise
             except Exception as e:
-                model.stats.record(_batch_count(inputs) or 1, queue_ns, 0, ok=False)
                 raise InferError(f"inference failed: {e}", http_status=500)
-            compute_ns = time.monotonic_ns() - t0
             if trace is not None:
-                trace.ts("COMPUTE_END", t0 + compute_ns)
-                if (self.cost_ledger.enabled and self.device_stats.enabled
-                        and device_loop is None):
-                    # mirror of the ledger charge _run_model just made —
-                    # the compact cost stamp riding the trace and flight
-                    # records (slot-share = whole window on this path)
-                    cost = {"tenant": request.tenant,
-                            "device_us": round(exec_stats.get(
-                                "compute_ns", compute_ns) / 1e3, 1)}
-                    if exec_stats.get("flops"):
-                        cost["flops"] = exec_stats["flops"]
-                    trace.cost = cost
-                    if trace.flight is not None:
-                        trace.flight.cost = cost
-            model.stats.record(_batch_count(inputs) or 1, queue_ns, compute_ns,
-                               ok=True, step=exec_stats)
+                trace.ts("COMPUTE_END", step.t_done)
         if cache_key is not None:
             self.response_cache.put(cache_key, dict(outputs),
                                     ttl_s=_model_cache_ttl(model))
@@ -1705,37 +1607,13 @@ class InferenceCore:
         queue: asyncio.Queue = asyncio.Queue()
         _SENTINEL = object()
         consumer_gone = threading.Event()
-        # decoupled models never pass through _run_model's stats hook;
-        # hand device-loop models (llama_generate -> the decode worker)
-        # the collector here so generation ticks are observable too
-        attach = getattr(model, "attach_device_stats", None)
-        if attach is not None:
-            attach(self.device_stats)
-        # hand device-loop models the memory governor too: generation
-        # slot admission gates on projected KV bytes vs HBM headroom
-        attach_gov = getattr(model, "attach_memory_governor", None)
-        if attach_gov is not None:
-            attach_gov(self.memory)
-        # cost attribution: the decode worker charges per-tick slot-shares
-        # to the ledger; the tenant rides the (copied) parameters dict and
-        # the worker reports the stream's accumulated device-time back
-        # through the same dict (read below for the final response)
-        attach_ledger = getattr(model, "attach_cost_ledger", None)
-        if attach_ledger is not None:
-            attach_ledger(self.cost_ledger)
-            if request.tenant:
-                params["_cost_tenant"] = request.tenant
-        # device-fault containment: the decode worker reports dispatch
-        # faults/recoveries into the manager (which quarantines) and, when
-        # a chaos injector is armed, consults it at dispatch boundaries
-        # for seeded device_error drills
-        attach_faults = getattr(model, "attach_device_faults", None)
-        if attach_faults is not None:
-            attach_faults(self.device_faults)
-        if self.chaos is not None:
-            attach_chaos = getattr(model, "attach_chaos", None)
-            if attach_chaos is not None:
-                attach_chaos(self.chaos)
+        # a decoupled model never passes through _run_model: a device loop
+        # (llama_generate -> the decode worker) gets its services here.  The
+        # tenant rides the (copied) parameters dict, and the worker reports
+        # the stream's device-time back through it (read below)
+        self._wire(model)
+        if model.device_loop and request.tenant:
+            params["_cost_tenant"] = request.tenant
         # current-trace contextvar set AROUND the whole stream (and reset
         # in the finally): shm staging transfers, request-scoped server-log
         # lines, and the decode worker's lifecycle spans all key off
@@ -1779,7 +1657,7 @@ class InferenceCore:
                     if item is _SENTINEL:
                         break
                     if isinstance(item, Exception):
-                        model.stats.record(1, 0, time.monotonic_ns() - t0, ok=False)
+                        model.stats.record_answered(1, 0, time.monotonic_ns() - t0, ok=False)
                         raise item if isinstance(item, InferError) else InferError(str(item), 500)
                     count += 1
                     resp = self._build_response(model, request, item)
@@ -1795,14 +1673,14 @@ class InferenceCore:
             except GeneratorExit:
                 # consumer closed the stream early (stop sequence, disconnect):
                 # the request was served — it must not vanish from statistics
-                model.stats.record(1, 0, time.monotonic_ns() - t0, ok=True)
+                model.stats.record_answered(1, 0, time.monotonic_ns() - t0, ok=True)
                 raise
             finally:
                 # reached on aclose()/GeneratorExit too: tell the producer the
                 # consumer is gone so the model generator stops at its next token
                 consumer_gone.set()
             await producer
-            model.stats.record(1, 0, time.monotonic_ns() - t0, ok=True)
+            model.stats.record_answered(1, 0, time.monotonic_ns() - t0, ok=True)
         finally:
             if token is not None:
                 reset_current_trace(token)
@@ -1856,8 +1734,11 @@ class InferenceCore:
         n = 0
         for _name, count, inputs in warmup_samples(model):
             for _ in range(count):
-                await self._run_model(model, dict(inputs), {},
-                                      keep_device=set())
+                rows = _batch_count(inputs) or 1
+                await self._run_model(
+                    model, dict(inputs), {}, set(), StepRecord(
+                        model.name, model.served_version, None, "warmup",
+                        rows, rows))
                 n += 1
         return n
 
@@ -2097,15 +1978,43 @@ class InferenceCore:
             return grp.kind == pb.ModelInstanceGroup.Kind.Value("KIND_CPU")
         return False
 
+    @staticmethod
+    def _lone_step(model: Model, inputs, path: str, tenant: str, trace=None,
+                   enqueue_ns: int = 0) -> StepRecord:
+        """The record of a step that is one request's alone: a batch of one
+        member, nothing to assemble, run now."""
+        rows = _batch_count(inputs) or 1
+        now = time.monotonic_ns()
+        return StepRecord(
+            model.name, model.served_version, model.stats, path, rows, rows,
+            members=(StepMember(rows, tenant, trace, enqueue_ns or now),),
+            t_assembly=now, t_assembled=now)
+
+    def _wire(self, model: Model) -> None:
+        """Hand ``model`` each of the core's services it has a socket for
+        (``attach_<service>``: an idempotent attribute stamp).  A device
+        loop (``Model.device_loop``: the decode worker) has all five: it
+        records its fused ticks, gates slot admission on HBM headroom,
+        charges the ledger per tick, reports failed dispatches and consults
+        the chaos injector, so it holds them before its first request."""
+        for socket, service in (
+                ("device_stats", self.device_stats),
+                ("memory_governor", self.memory),
+                ("cost_ledger", self.cost_ledger),
+                ("device_faults", self.device_faults),
+                ("chaos", self.chaos)):
+            attach = getattr(model, "attach_" + socket, None)
+            if attach is not None and service is not None:
+                attach(service)
+
     async def _run_model(
         self, model: Model, inputs, params,
-        keep_device: Optional[Set[str]] = None,
-        traces=(),
-        exec_stats: Optional[Dict[str, Any]] = None,
-        real_batch: Optional[int] = None,
-        cost_tenant: Optional[str] = None,
+        keep_device: Optional[Set[str]], step: StepRecord,
     ) -> Dict[str, Any]:
-        """Execute on a thread-pool worker so the event loop keeps serving.
+        """Execute one step on a thread-pool worker so the event loop keeps
+        serving, stamp ``step`` with what happened and when, and book it
+        (``_book``): once, whether it succeeds or raises.  The caller builds
+        the record and books nothing itself.
 
         ``keep_device`` names the outputs left device-resident (the zero-copy
         path for xla-shm-bound outputs; ``None`` keeps everything on device —
@@ -2118,158 +2027,11 @@ class InferenceCore:
 
         Exception: sub-millisecond host-placed models with pure wire IO run
         INLINE once their shape signature is warm (see ``_InlineProfile``) —
-        for those the executor round trip dominates the compute.
-
-        ``traces``: TraceContexts of sampled requests riding this execution
-        (one for the direct path, every traced member for a batch) — each
-        gets a COMPUTE span for the execute window and, when host
-        resolution happens, a D2H_TRANSFER span for the readback drain.
-
-        ``exec_stats``: optional dict the execution fills with
-        ``compute_ns`` / ``d2h_syncs`` — the batcher passes one so its
-        tick records carry per-tick sync counts without re-deriving them.
-
-        ``real_batch``: the REAL element count when ``inputs`` has been
-        padded to a bucket (the dynamic batcher passes its pre-pad total)
-        — pad slots are waste (``nv_tpu_pad_waste_ratio``), so they must
-        not count as inferences or MFU FLOPs.
-
-        ``cost_tenant``: when set (the direct path and ensemble members),
-        the whole compute window is charged to this tenant in the cost
-        ledger.  The dynamic batcher passes None and splits the window
-        into per-request slot-shares itself; device-loop models (the
-        decode worker) attribute per tick and are skipped here — either
-        way every compute nanosecond is charged exactly once."""
+        for those the executor round trip dominates the compute."""
         loop = asyncio.get_running_loop()
         ds = self.device_stats
-        # rows the execution runs with (pad rows included), read off the
-        # shape: ``np.asarray`` on a device-resident input would sync it
-        shape = getattr(next(iter(inputs.values()), None), "shape", None)
-        padded_n = int(shape[0]) if shape else 1
-        rows = real_batch or padded_n
-        t_submit = 0  # stamped only where the execution hops to a worker
-
-        def _exec():
-            t_x0 = time.monotonic_ns()
-            want_ds = ds.enabled
-            # device-loop models (the decode worker) gate slot admission
-            # on projected KV bytes — hand them the governor BEFORE the
-            # execute so the first request is already gated (idempotent
-            # attribute stamp, like attach_device_stats below)
-            attach_gov = getattr(model, "attach_memory_governor", None)
-            if attach_gov is not None:
-                attach_gov(self.memory)
-            # device-fault containment wiring rides the same idempotent
-            # stamp: the decode worker must be able to report a failed
-            # dispatch (and consult the chaos injector) from the very
-            # first sequence-protocol request
-            attach_faults = getattr(model, "attach_device_faults", None)
-            if attach_faults is not None:
-                attach_faults(self.device_faults)
-            if self.chaos is not None:
-                attach_chaos = getattr(model, "attach_chaos", None)
-                if attach_chaos is not None:
-                    attach_chaos(self.chaos)
-            with annotation("step.dispatch", model=model.name,
-                            bucket=padded_n, rows=rows):
-                t_c0 = time.monotonic_ns()
-                # a padded step's parameters say how many rows are real, for
-                # a model whose host_post counts by row
-                outputs = model.execute(
-                    inputs, params if real_batch is None
-                    else {**params, "real_batch": real_batch})
-                t_c1 = time.monotonic_ns()
-            if exec_stats is not None:
-                # the step as the host lives it (ModelStats.record folds
-                # these into executor_wait / dispatch / device_wait)
-                exec_stats["executor_wait_ns"] = \
-                    t_x0 - t_submit if t_submit else 0
-                exec_stats["dispatch_ns"] = t_c1 - t_c0
-            for t in traces:
-                t.add_span("COMPUTE", t_c0, t_c1)
-            if want_ds:
-                # signature-analytic compile tracking: jax.jit compiles
-                # once per input-shape signature (the invariant JaxModel
-                # builds on), so a signature's first execution is the
-                # jit-cache miss whose wall time paid XLA compilation.
-                # Only XLA-backed models earn signatures — a python-backend
-                # model never compiles, and fabricating misses would both
-                # invent nv_tpu_compile events and drop its real compute
-                # from the duty/MFU window
-                sig = None
-                if isinstance(model, JaxModel):
-                    sig = tuple(sorted(
-                        ((n, getattr(v, "shape", None),
-                          getattr(v, "dtype", None))
-                         for n, v in inputs.items()), key=lambda s: s[0]))
-                ds.declare_model(model.name, model.flops_per_element())
-                # models that run their own device loop (the decode
-                # worker's fused ticks) record tick rows directly; hand
-                # them the collector (idempotent attribute stamp)
-                attach = getattr(model, "attach_device_stats", None)
-                if attach is not None:
-                    attach(ds)
-                attach_ledger = getattr(model, "attach_cost_ledger", None)
-                if attach_ledger is not None:
-                    attach_ledger(self.cost_ledger)
-                # XLA cost analysis, once per new signature: the execute
-                # above warmed the jit cache, so the AOT lower+compile
-                # here reuses the compilation where the backend caches it
-                # and the extracted FLOPs/bytes are those of the program
-                # this signature actually runs.  None (CPU stand-ins with
-                # no analysis, untraceable fns) stays None — absent,
-                # never fabricated.
-                cost = None
-                if sig is not None and not ds.signature_known(
-                        model.name, sig):
-                    cost = model.analyze_cost(inputs, params)
-                ds.record_execute(model.name,
-                                  real_batch or padded_n,
-                                  t_c1 - t_c0, signature=sig,
-                                  cost=cost, padded_batch=padded_n)
-                if cost is None and sig is not None:
-                    cost = ds.signature_cost(model.name, sig)
-                if exec_stats is not None:
-                    exec_stats["compute_ns"] = t_c1 - t_c0
-                    if cost is not None:
-                        exec_stats["flops"] = cost.flops
-                        exec_stats["bytes_accessed"] = cost.bytes_accessed
-                ledger = self.cost_ledger
-                if cost_tenant is not None and ledger.enabled \
-                        and attach is None:
-                    # direct-path / ensemble-member attribution: one
-                    # request owns the whole window.  Device-loop models
-                    # (attach is not None) attribute per fused tick in
-                    # their own worker — charging here too would double-
-                    # count and break the conservation contract.
-                    ledger.charge(model.name, cost_tenant,
-                                  device_us=(t_c1 - t_c0) / 1e3,
-                                  flops=cost.flops if cost else 0.0)
-            if keep_device is None:
-                return outputs
-            with annotation("step.device_wait", model=model.name,
-                            bucket=padded_n, rows=rows):
-                drained = [n for n, v in outputs.items()
-                           if n not in keep_device
-                           and hasattr(v, "copy_to_host_async")]
-                for n in drained:
-                    outputs[n].copy_to_host_async()
-                resolved = {n: (v if n in keep_device else np.asarray(v))
-                            for n, v in outputs.items()}
-                t_d1 = time.monotonic_ns()
-            if exec_stats is not None:
-                exec_stats["device_wait_ns"] = t_d1 - t_c1
-            for t in traces:
-                t.add_span("D2H_TRANSFER", t_c1, t_d1)
-            if drained:
-                if want_ds:
-                    ds.record_transfer(
-                        "d2h", sum(resolved[n].nbytes for n in drained),
-                        count=len(drained))
-                if exec_stats is not None:
-                    exec_stats["d2h_syncs"] = len(drained)
-            return resolved
-
+        self._wire(model)
+        step.device_loop = model.device_loop
         prof = None
         if keep_device is not None and not keep_device \
                 and self._host_placed(model):
@@ -2281,35 +2043,180 @@ class InferenceCore:
                 # execution (a potential XLA compile) never runs inline
                 prof = _InlineProfile(generation=gen)
                 self._inline_profiles[prof_key] = prof
-            # dtype objects are hashable/comparable by equality — building
-            # str(dtype) here cost ~100 us/request of pure overhead on the
-            # profiled hot path (benchmarks/HOTPATH_PROFILE.md); sort by
-            # name only (the other elements never tie-break)
-            sig = tuple(sorted(
-                ((n, getattr(v, "shape", None), getattr(v, "dtype", None))
-                 for n, v in inputs.items()), key=lambda t: t[0]))
-            if prof.allows(sig):
-                t0 = time.perf_counter()
-                try:
-                    return _exec()
-                finally:
-                    # observed even on raise: a model failing slowly must
-                    # still demote off the event loop
-                    prof.observe(sig, time.perf_counter() - t0)
+        # dtype objects are hashable/comparable by equality — building
+        # str(dtype) here cost ~100 us/request of pure overhead on the
+        # profiled hot path (benchmarks/HOTPATH_PROFILE.md); sort by name
+        # only (the other elements never tie-break)
+        sig = tuple(sorted(
+            ((n, getattr(v, "shape", None), getattr(v, "dtype", None))
+             for n, v in inputs.items()), key=lambda t: t[0]))
+        if ds.enabled:
+            ds.declare_model(model.name, model.flops_per_element())
+            if isinstance(model, JaxModel):
+                # signature-analytic compile tracking: jax.jit compiles
+                # once per input-shape signature (the invariant JaxModel
+                # builds on), so a signature's first execution is the
+                # jit-cache miss whose wall time paid XLA compilation.
+                # Only XLA-backed models earn one — a python-backend model
+                # never compiles, and fabricating misses would both invent
+                # nv_tpu_compile events and drop its real compute from the
+                # duty/MFU window
+                step.signature = sig
 
-        if prof is None:
-            t_submit = time.monotonic_ns()
-            return await loop.run_in_executor(None, _exec)
+        def _exec():
+            step.t_exec = time.monotonic_ns()
+            with annotation("step.dispatch", model=model.name,
+                            bucket=step.bucket, rows=step.rows):
+                step.t_called = time.monotonic_ns()
+                # a padded step's parameters say how many rows are real, for
+                # a model whose host_post counts by row
+                outputs = model.execute(
+                    inputs, params if step.rows == step.bucket
+                    else {**params, "real_batch": step.rows})
+                step.t_returned = time.monotonic_ns()
+            if step.signature is not None and not ds.signature_known(
+                    model.name, step.signature):
+                # XLA cost analysis, once per new signature: the execute
+                # above warmed the jit cache, so the AOT lower+compile
+                # here reuses the compilation where the backend caches it
+                # and the extracted FLOPs/bytes are those of the program
+                # this signature actually runs.  None (CPU stand-ins with
+                # no analysis, untraceable fns) stays None — absent,
+                # never fabricated.
+                step.cost = model.analyze_cost(inputs, params)
+            if keep_device is None:
+                return outputs
+            with annotation("step.device_wait", model=model.name,
+                            bucket=step.bucket, rows=step.rows):
+                drained = [n for n, v in outputs.items()
+                           if n not in keep_device
+                           and hasattr(v, "copy_to_host_async")]
+                for n in drained:
+                    outputs[n].copy_to_host_async()
+                resolved = {n: (v if n in keep_device else np.asarray(v))
+                            for n, v in outputs.items()}
+                step.t_on_host = time.monotonic_ns()
+            step.d2h_count = len(drained)
+            step.d2h_bytes = sum(resolved[n].nbytes for n in drained)
+            return resolved
 
         def _exec_timed():
             t0 = time.perf_counter()
             try:
                 return _exec()
             finally:
+                # observed even on raise: a model failing slowly must
+                # still demote off the event loop
                 prof.observe(sig, time.perf_counter() - t0)
 
-        t_submit = time.monotonic_ns()
-        return await loop.run_in_executor(None, _exec_timed)
+        try:
+            if prof is not None and prof.allows(sig):
+                outputs = _exec_timed()
+            else:
+                step.t_submit = time.monotonic_ns()
+                outputs = await loop.run_in_executor(
+                    None, _exec if prof is None else _exec_timed)
+            step.ok = True
+            return outputs
+        finally:
+            step.t_done = time.monotonic_ns()
+            self._book(step)
+
+    def _book(self, step: StepRecord) -> None:
+        """Write one step into every book that keeps one: the members'
+        traces (QUEUE, BATCH_ASSEMBLY, COMPUTE and D2H_TRANSFER spans, the
+        ``tick`` and ``cost`` stamps, mirrored on their flight records),
+        the model's statistics, the collector (compute window and compile
+        event, read-back, the batcher's tick) and the cost ledger.  No
+        other code writes an execution of ``_run_model``'s into any of
+        them; a book that needs a new fact gets it from the record."""
+        ran = step.t_returned > 0
+        for m in step.members:
+            trace = m.trace
+            if trace is None:
+                continue
+            trace.add_span("QUEUE", m.enqueue_ns, step.t_assembly)
+            if step.formed and step.t_assembled:
+                # concat + pad-to-bucket: the cost of riding a shared batch
+                trace.add_span("BATCH_ASSEMBLY", step.t_assembly,
+                               step.t_assembled)
+            if ran:
+                # every traced member of a batch carries the same window
+                trace.add_span("COMPUTE", step.t_called, step.t_returned)
+            if step.t_on_host:
+                trace.add_span("D2H_TRANSFER", step.t_returned,
+                               step.t_on_host)
+        if step.stats is not None:
+            step.stats.record(step)
+        if not ran:
+            return
+        ds, cost, window_ns = self.device_stats, step.cost, step.window_ns
+        if ds.enabled:
+            # ``now`` is the record's own point: the duty-cycle window's
+            # events keep the times they had when the worker booked them.
+            # Pad rows are waste (nv_tpu_pad_waste_ratio): they count as
+            # neither inferences nor MFU FLOPs
+            ds.record_execute(step.model, step.rows, window_ns,
+                              signature=step.signature,
+                              now=step.t_returned / 1e9, cost=cost,
+                              padded_batch=step.bucket)
+            if cost is None and step.signature is not None:
+                cost = ds.signature_cost(step.model, step.signature)
+            if step.d2h_count:
+                ds.record_transfer("d2h", step.d2h_bytes,
+                                   count=step.d2h_count)
+        flops, nbytes = (cost.flops, cost.bytes_accessed) \
+            if cost is not None else (0.0, 0.0)
+        tick = None
+        if step.formed and step.ok and ds.enabled:
+            # one tick record per batched execution: the bucket view
+            # (nv_tpu_tick_* / pad-waste series, triton-top buckets)
+            # is aggregated from exactly these
+            ds.record_tick(
+                step.model, bucket=step.bucket, batch=step.rows,
+                padded=step.bucket, queue_depth=step.queue_depth,
+                assembly_ns=step.assembly_ns, compute_ns=window_ns,
+                requests=len(step.members), syncs=step.d2h_count,
+                flops=flops, bytes_accessed=nbytes)
+            # the tick shape rides the trace record and the flight record,
+            # so a pinned outlier shows which bucket/occupancy it paid for
+            tick = {
+                "bucket": step.bucket, "batch": step.rows,
+                "pad_fraction": (
+                    round((step.bucket - step.rows) / step.bucket, 4)
+                    if step.bucket else 0.0),
+                "queue_depth": step.queue_depth,
+                "assembly_us": round(step.assembly_ns / 1e3, 1),
+                "requests": len(step.members),
+            }
+        # slot-share attribution: each member owns rows/step.rows of the
+        # compute window and of the signature's measured FLOPs (a lone
+        # request: all of it), so the shares sum to exactly the window the
+        # collector recorded — conservation by construction.  A device
+        # loop attributes per fused tick in its own worker: charging here
+        # too would count its time twice.
+        ledger = self.cost_ledger
+        charged = ledger.enabled and not step.device_loop and step.rows > 0
+        roofline = classify_roofline(flops, nbytes) if charged else None
+        for m in step.members:
+            stamp = None
+            if charged:
+                share = m.rows / step.rows
+                dev_us = window_ns * share / 1e3
+                ledger.charge(step.model, m.tenant, device_us=dev_us,
+                              flops=flops * share)
+                stamp = {"tenant": m.tenant, "device_us": round(dev_us, 1)}
+                if flops:
+                    stamp["flops"] = flops * share
+                if roofline is not None:
+                    stamp["roofline"] = roofline["verdict"]
+            if m.trace is None:
+                continue
+            for holder in filter(None, (m.trace, m.trace.flight)):
+                if tick is not None:
+                    holder.tick = tick
+                if stamp is not None:
+                    holder.cost = stamp
 
     async def _run_ensemble(self, model: EnsembleModel, inputs, params,
                             tenant: str = "", tier: int = 0) -> Dict[str, Any]:
@@ -2399,19 +2306,10 @@ class InferenceCore:
             # (a best-effort ensemble must not jump the member's queue)
             return await self._batcher(member).submit(
                 step_inputs, member_params, tenant=tenant, tier=tier)
-        t0 = time.monotonic_ns()
-        try:
-            outs = await self._run_model(member, step_inputs, params,
-                                         cost_tenant=tenant)
-        except Exception:
-            member.stats.record(
-                _batch_count(step_inputs) or 1, 0,
-                time.monotonic_ns() - t0, ok=False)
-            raise
-        member.stats.record(
-            _batch_count(step_inputs) or 1, 0, time.monotonic_ns() - t0, ok=True
-        )
-        return outs
+        # keep_device=None: intermediates stay on the device between steps
+        return await self._run_model(
+            member, step_inputs, params, None,
+            self._lone_step(member, step_inputs, "member", tenant))
 
     # ------------------------------------------------------------------
     def _resolve_inputs(self, model: Model, request: InferRequest) -> Dict[str, Any]:

@@ -194,49 +194,57 @@ class ModelStats:
         with self.lock:
             self.pending_count -= 1
 
-    def record(self, batch: int, queue_ns: int, compute_ns: int, ok: bool, *,
-               member_queue_ns: Optional[int] = None, assembly_ns: int = 0,
-               padded: Optional[int] = None, step=None,
-               formed: bool = False, carried: int = 0) -> None:
-        """One execution of ``batch`` rows.  ``queue_ns``/``compute_ns`` are
-        charged to every row (the v2 entries).  The extension entries:
-        ``member_queue_ns`` is the rows' own waits already summed (default:
-        ``queue_ns`` a row), ``assembly_ns`` the batch's concat + pad,
-        ``padded`` the rows the execution ran with (default: ``batch``),
-        ``step`` the dict ``_run_model`` filled with ``executor_wait_ns`` /
-        ``dispatch_ns`` / ``device_wait_ns``; ``formed`` marks a batch the
-        dynamic batcher formed, ``carried`` the rows it closed the batch
-        without, at a bucket, for the next one to lead with."""
+    def record(self, step) -> None:
+        """One executed step (``types.StepRecord``; called by
+        ``InferenceCore._book`` alone).  The v2 entries charge the step's
+        ``queue_ns`` and ``compute_ns`` to every row; the extension entries
+        are the record's other durations, a row each."""
+        rows = step.rows
+        with self.lock:
+            if not step.ok:
+                self.fail_count += rows
+                self.fail_ns += step.fail_ns * rows
+                return
+            self._served(rows, step.queue_ns, step.compute_ns,
+                         step.member_queue_ns, step.bucket)
+            self.assembly_ns += step.assembly_ns * rows
+            self.executor_wait_ns += step.executor_wait_ns * rows
+            self.dispatch_ns += step.window_ns * rows
+            self.device_wait_ns += step.device_wait_ns * rows
+            if step.formed:
+                self.batch_size_total += rows
+                self.batch_execution_count += 1
+            if step.carried:
+                self.batch_carry_count += 1
+                self.batch_carry_rows += step.carried
+
+    def record_answered(self, rows: int, queue_ns: int, compute_ns: int,
+                        ok: bool) -> None:
+        """Rows answered with no step of their own to book: a cache hit, an
+        ensemble's envelope (its members book their steps), a decoupled
+        stream (the decode worker books its ticks)."""
         with self.lock:
             if ok:
-                self.inference_count += batch
-                self.execution_count += 1
-                self.last_inference_ms = int(time.time() * 1000)
-                self.success_count += batch
-                self.success_ns += (queue_ns + compute_ns) * batch
-                self.queue_count += batch
-                self.queue_ns += queue_ns * batch
-                self.infer_count += batch
-                self.infer_ns += compute_ns * batch
-                self.queue_member_ns += (queue_ns * batch if member_queue_ns
-                                         is None else member_queue_ns)
-                self.assembly_ns += assembly_ns * batch
-                self.bucket_rows += batch if padded is None else padded
-                if step:
-                    self.executor_wait_ns += \
-                        step.get("executor_wait_ns", 0) * batch
-                    self.dispatch_ns += step.get("dispatch_ns", 0) * batch
-                    self.device_wait_ns += \
-                        step.get("device_wait_ns", 0) * batch
-                if formed:
-                    self.batch_size_total += batch
-                    self.batch_execution_count += 1
-                if carried:
-                    self.batch_carry_count += 1
-                    self.batch_carry_rows += carried
+                self._served(rows, queue_ns, compute_ns, queue_ns * rows,
+                             rows)
             else:
-                self.fail_count += batch
-                self.fail_ns += (queue_ns + compute_ns) * batch
+                self.fail_count += rows
+                self.fail_ns += (queue_ns + compute_ns) * rows
+
+    def _served(self, rows: int, queue_ns: int, compute_ns: int,
+                member_queue_ns: int, bucket: int) -> None:
+        # caller holds ``lock``
+        self.inference_count += rows
+        self.execution_count += 1
+        self.last_inference_ms = int(time.time() * 1000)
+        self.success_count += rows
+        self.success_ns += (queue_ns + compute_ns) * rows
+        self.queue_count += rows
+        self.queue_ns += queue_ns * rows
+        self.infer_count += rows
+        self.infer_ns += compute_ns * rows
+        self.queue_member_ns += member_queue_ns
+        self.bucket_rows += bucket
 
     def record_request(self, rows: int, ns: int) -> None:
         """A frontend answered a request of ``rows`` rows ``ns`` after its
@@ -334,6 +342,11 @@ class Model(abc.ABC):
     #: version number this instance serves (the registry stamps it when a
     #: repository model declares numbered version directories)
     served_version: str = "1"
+
+    #: True for a model that runs its own device loop (the decode worker):
+    #: it books its own ticks and costs, a request's tenant rides its
+    #: parameters, and ``InferenceCore._wire`` finds its ``attach_*`` sockets
+    device_loop: bool = False
 
     @property
     def versions(self) -> List[str]:

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -149,6 +149,99 @@ class InferResponse:
         if self.stats is not None:
             self.stats.record_request(
                 self.rows, time.monotonic_ns() - t_recv_ns)
+
+
+class StepMember(NamedTuple):
+    """One request's part of an executed step."""
+    rows: int
+    tenant: str
+    trace: Any        # its TraceContext, or None
+    enqueue_ns: int   # when it began to wait for the step
+
+
+@dataclass
+class StepRecord:
+    """One execution of a model: what ran, for whom, and when.
+
+    The caller (a formed batch, a direct request, an ensemble member)
+    fills what it knows up to ``t_assembled``; ``InferenceCore._run_model``
+    stamps the rest and hands the record to ``InferenceCore._book``, the
+    one place that writes it into the statistics, the collector, the cost
+    ledger and the members' traces.  Times are ``time.monotonic_ns()``
+    points (0: not reached); every duration a book needs is a difference
+    of two of them."""
+
+    model: str
+    version: str
+    stats: Any             # the ModelStats it counts in; None: a warm-up
+    path: str              # "batch" | "direct" | "member" (of an ensemble)
+    rows: int              # real rows
+    bucket: int            # rows run, pad rows included
+    members: Sequence[StepMember] = ()
+    carried: int = 0       # rows the batcher closed the batch without
+    queue_depth: int = 0   # requests left queued as the batch formed
+    t_assembly: int = 0    # the batch's concat + pad began,
+    t_assembled: int = 0   # and ended; a lone request: both when it ran
+    t_submit: int = 0      # handed to the executor (0: ran inline)
+    t_exec: int = 0        # the worker picked it up
+    t_called: int = 0      # model.execute called,
+    t_returned: int = 0    # and returned
+    t_on_host: int = 0     # outputs read back (0: they stay on the device)
+    t_done: int = 0        # the caller has them: the v2 window's end
+    device_loop: bool = False   # the model books its own ticks and costs
+    signature: Optional[tuple] = None   # compile signature (XLA models)
+    cost: Any = None       # its SignatureCost, where this step analysed it
+    d2h_count: int = 0     # outputs read back from the device,
+    d2h_bytes: int = 0     # and their bytes
+    ok: bool = False
+
+    @property
+    def formed(self) -> bool:
+        """The dynamic batcher formed it."""
+        return self.path == "batch"
+
+    @property
+    def queue_ns(self) -> int:
+        """The v2 ``queue`` entry: the first member's wait, a row."""
+        return self.t_assembled - self.members[0].enqueue_ns
+
+    @property
+    def member_queue_ns(self) -> int:
+        """Each member's own wait until the step began, summed by row."""
+        return sum((self.t_assembly - m.enqueue_ns) * m.rows
+                   for m in self.members)
+
+    @property
+    def assembly_ns(self) -> int:
+        return self.t_assembled - self.t_assembly
+
+    @property
+    def compute_ns(self) -> int:
+        """The v2 ``compute_infer`` window, as the caller lives it."""
+        return self.t_done - self.t_assembled
+
+    @property
+    def executor_wait_ns(self) -> int:
+        return self.t_exec - self.t_submit if self.t_submit else 0
+
+    @property
+    def window_ns(self) -> int:
+        """``model.execute``: the ``dispatch`` entry, the COMPUTE span, the
+        collector's compute window and what the ledger shares out."""
+        return self.t_returned - self.t_called
+
+    @property
+    def device_wait_ns(self) -> int:
+        return self.t_on_host - self.t_returned if self.t_on_host else 0
+
+    @property
+    def fail_ns(self) -> int:
+        """What a failed step is charged a row: a direct request its queue
+        time, a formed batch nothing, an ensemble member the time it ran
+        (each path's convention since before the record)."""
+        if self.path == "direct":
+            return self.queue_ns
+        return self.compute_ns if self.path == "member" else 0
 
 
 class InferError(Exception):
