@@ -69,6 +69,40 @@ _BF16 = (jnp.bfloat16, 2e-2)
                      id="dv-wider-1x2x384x48v64-f32-bidir"),
         pytest.param((1, 1, 1024, 192), True, *_BF16, 128,
                      id="dv-mla-1x1x1024x192v128-bf16-causal"),
+        # each path of the looped form's walk (``_loop_plan``): blocks of
+        # 128 below S = 1024 and of 512 from there, a wide step of four
+        # one row of one block: the diagonal alone, whole and padded
+        pytest.param((1, 2, 128, 24), True, *_F32, 16,
+                     id="walk-diagonal-alone-1x2x128x24v16-f32-causal"),
+        pytest.param((1, 2, 100, 24), True, *_BF16, 16,
+                     id="walk-diagonal-padded-1x2x100x24v16-bf16-causal"),
+        pytest.param((1, 2, 100, 24), False, *_F32, 16,
+                     id="walk-one-padded-block-1x2x100x24v16-f32-bidir"),
+        # single whole blocks, then the diagonal (no program reaches four)
+        pytest.param((1, 2, 512, 48), True, *_F32, 32,
+                     id="walk-narrow-1x2x512x48v32-f32-causal"),
+        # a wide step, single blocks and the diagonal, 3 : 2 as 192 : 128
+        pytest.param((1, 2, 1000, 48), True, *_F32, 32,
+                     id="walk-wide-1x2x1000x48v32-f32-causal"),
+        pytest.param((1, 2, 1000, 48), True, *_BF16, 32,
+                     id="walk-wide-1x2x1000x48v32-bf16-causal"),
+        # no causal mask: the padding crosses the last block alone
+        pytest.param((1, 2, 600, 64), False, *_F32, None,
+                     id="walk-padded-1x2x600x64-f32-bidir"),
+        pytest.param((1, 2, 600, 64), False, *_BF16, None,
+                     id="walk-padded-1x2x600x64-bf16-bidir"),
+        pytest.param((1, 2, 1000, 48), False, *_BF16, 32,
+                     id="walk-wide-padded-1x2x1000x48v32-bf16-bidir"),
+        # no mask at all: neither causal nor padded
+        pytest.param((1, 2, 640, 64), False, *_F32, None,
+                     id="walk-unmasked-1x2x640x64-f32-bidir"),
+        pytest.param((1, 1, 2048, 32), False, *_BF16, None,
+                     id="walk-unmasked-wide-1x1x2048x32-bf16-bidir"),
+        # blocks of 512: wide steps of 2048, single blocks, the diagonal
+        pytest.param((1, 1, 2600, 48), True, *_BF16, 32,
+                     id="walk-512-1x1x2600x48v32-bf16-causal"),
+        pytest.param((1, 1, 2600, 24), False, *_F32, 16,
+                     id="walk-512-1x1x2600x24v16-f32-bidir"),
     ],
 )
 def test_kernel_matches_reference(shape, causal, dtype, tol, dv):
@@ -84,6 +118,62 @@ def test_kernel_matches_reference(shape, causal, dtype, tol, dv):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "bidir"])
+@pytest.mark.parametrize("S,D,dv", [
+    (100, 24, 16),     # one padded block
+    (600, 64, 64),     # blocks of 128, the last one padded
+    (1000, 48, 32),    # a wide step before it
+    (1100, 48, 32),    # blocks of 512
+], ids=["100", "600", "1000", "1100"])
+def test_a_padded_key_carries_no_weight(S, D, dv, causal, dtype):
+    """The looped form pads S to its block with zero keys and zero values:
+    with every real value 1, any weight on a padded key would pull a row's
+    output below 1, and a row without a finite maximum would be NaN."""
+    q = _rand((1, 2, S, D), dtype, 30)
+    k = _rand((1, 2, S, D), dtype, 31)
+    v = jnp.ones((1, 2, S, dv), dtype)
+    got = np.asarray(flash_attention(q, k, v, causal=causal, interpret=True),
+                     np.float32)
+    assert np.isfinite(got).all()
+    # f32: the row sums' rounding; bf16: P is rounded before P.V, not in l
+    np.testing.assert_allclose(got, 1.0, rtol=0, atol=(
+        1e-5 if dtype == jnp.float32 else 2 ** -8))
+
+
+@pytest.mark.parametrize("S,D,dv,causal,want", [
+    # (block, wide, padded S, walked, masked, dead columns).  Latent
+    # attention's prefill: 16 of 136 blocks masked, under an eighth, and
+    # no column of a block computed for nothing
+    (8192, 192, 128, True, (512, 2048, 8192, 136, 16, 0)),
+    (4096, 64, 64, True, (512, 2048, 4096, 36, 8, 0)),      # longctx_tpu's
+    (4096, 64, 64, False, (512, 2048, 4096, 64, 0, 0)),     # no mask at all
+    (5000, 64, 64, False, (512, 2048, 5120, 100, 10, 1200)),
+    (5000, 64, 64, True, (512, 2048, 5120, 55, 10, 120)),
+    (600, 64, 64, True, (128, 512, 640, 15, 5, 40)),
+    (384, 24, 16, False, (128, 384, 384, 9, 0, 0)),
+    (100, 24, 16, True, (128, 128, 128, 1, 1, 28)),
+], ids=["mla", "longctx", "longctx-bidir", "odd-bidir", "odd-causal",
+        "short", "short-dv", "one-block"])
+def test_the_looped_forms_plan(S, D, dv, causal, want):
+    """What the looped form walks at a shape, from the function the call
+    itself sizes its blocks with."""
+    import importlib
+
+    fa = importlib.import_module("triton_client_tpu.ops.flash_attention")
+    plan = fa._loop_plan(S, D, dv, jnp.bfloat16, causal)
+    assert plan[:6] == want
+    assert plan.wide % plan.block == 0 and plan.seq_pad % plan.block == 0
+    # the chip has 128 MiB of VMEM; the call scopes what the plan asks
+    assert plan.vmem_bytes < 100 << 20
+    # a program's walk: whole blocks from key 0, then the masked one
+    n = plan.seq_pad // plan.block
+    padded = plan.seq_pad > S
+    whole = [fa._whole_blocks(i, n, causal, padded) for i in range(n)]
+    assert whole == (list(range(n)) if causal else [n - int(padded)] * n)
 
 
 def test_bf16_inputs_accumulate_in_fp32():
@@ -252,11 +342,13 @@ def _pallas_operands(jaxpr, found):
     # the whole-row form reads [B,H,D,S], the sequence on the lanes
     ((1, 16, 384, 64), 64, [(1, 16, 64, 384)] * 3),
     ((32, 16, 384, 64), 64, [(32, 16, 64, 384)] * 3),
-    # the looped form reads [B*H,S,D], v at its own width
-    ((1, 16, 4096, 64), 64, [(16, 4096, 64)] * 3),
-    ((1, 4, 384, 24), 16, [(4, 384, 24), (4, 384, 24), (4, 384, 16)]),
+    # the looped form reads q and v as [B*H,S,D], v at its own width, and k
+    # with the sequence on the lanes, as the whole-row form does (PR 31:
+    # the MXU takes a key tile 8% sooner than through its transposing path)
+    ((1, 16, 4096, 64), 64, [(16, 4096, 64), (16, 64, 4096), (16, 4096, 64)]),
+    ((1, 4, 384, 24), 16, [(4, 384, 24), (4, 24, 384), (4, 384, 16)]),
     ((2, 64, 8192, 192), 128,
-     [(128, 8192, 192), (128, 8192, 192), (128, 8192, 128)]),
+     [(128, 8192, 192), (128, 192, 8192), (128, 8192, 128)]),
 ], ids=["bert_large-1", "bert_large-32", "longctx", "short-dv", "mla"])
 def test_the_traced_call_is_of_the_form_its_shape_asks_for(shape, dv,
                                                            operands):

@@ -11,11 +11,15 @@ chip.  Two forms of one kernel, chosen from the shape (``_flash_call``):
   serving) with f32 accumulation, and the softmax between them (max, exp,
   sum, normalisation: f32) needs no carry.  Operands are ``[B, H, D, S]``,
   the layout XLA gives the projections on either side.
-* **looped** (longer rows: ``longctx_tpu``): one program per (batch·head,
-  q-block) holds the head's full K/V in VMEM and walks k-blocks with a
-  ``fori_loop`` carrying the online (m, l, acc) state — the in-VMEM mirror
-  of the cross-device ring in ``_ring_attention`` (same math, one chip) —
-  and skips the blocks a causal mask empties.
+* **looped** (longer rows, or a v of another width than the keys:
+  ``longctx_tpu``, latent attention's prefill): one program per
+  (batch·head, q-block) holds the head's full K/V in VMEM and walks
+  k-blocks with ``fori_loop``s carrying the online (m, l, acc) state — the
+  in-VMEM mirror of the cross-device ring in ``_ring_attention`` (same
+  math, one chip).  It skips the blocks a causal mask empties, masks the
+  block on the diagonal (or the one the padding starts in) and no other,
+  and takes the blocks before it four at a time (``_loop_plan`` says what
+  a shape walks).  Operands are ``[B·H, S, D]``, k ``[B·H, D, S]``.
 
 ``flash_attention`` pads S to the block size and masks the padding away, so
 any sequence length works. On a TPU backend the kernel is compiled by
@@ -30,6 +34,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -56,65 +61,142 @@ def flash_attention_reference(q, k, v, *, causal: bool = True, sm_scale=None):
     return o.astype(q.dtype)
 
 
-def _loop_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, block_q,
-                 block_k, seq_len, n_kblocks):
-    """Long rows: one (batch·head, q-block) program. Refs carry a leading
-    length-1 block dim; k/v refs hold the head's full (padded) sequence,
-    walked in ``block_k`` steps under the online-softmax carry.  q and k
-    share one width, v and the output another (latent attention's 192 and
-    128; the same everywhere else)."""
-    qi = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    ki = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    q_start = _pl().program_id(1) * block_q
+class _LoopPlan(NamedTuple):
+    """What the looped form does at one shape: its blocks, and what a
+    head's walk spends on the diagonal and the padding (a test pins it,
+    PERF.md quotes it).  Blocks are counted ``block`` keys wide, whichever
+    step takes them."""
+    block: int          # query rows a program holds, and the keys of a
+    #                     narrow step: the block on the diagonal is square
+    wide: int           # keys of a wide step, a multiple of ``block``
+    seq_pad: int        # S rounded up to the block
+    walked: int         # k-blocks a head walks, all its q-blocks together
+    masked: int         # of them, those that take the masked body
+    dead_columns: int   # key columns computed that no row of their block
+    #                     may see (padding; a square diagonal has none)
+    vmem_bytes: int     # what the call asks the compiler to scope
 
-    # f32 operands here, unlike the whole-row kernel: Mosaic rounds them to
-    # bf16 in the MXU's feed either way (the results agree bit for bit), and
-    # bf16 refs with P packed before each P·V read 3% slower at S=4096
-    # (0.788 against 0.765 ms a layer; my chip run, PR 27)
-    q = q_ref[0].astype(jnp.float32) * scale  # [block_q, D]
 
-    def body(j, carry):
+def _whole_blocks(q_block, n_blocks: int, causal: bool, padded: bool):
+    """k-blocks the ``q_block``-th program walks with no mask, from key 0:
+    those below its diagonal, or all but the one the padding starts in.
+    One masked block follows them, or none (no causal mask, no padding).
+    ``q_block`` may be traced: the kernel and the plan share this."""
+    return q_block if causal else n_blocks - int(padded)
+
+
+def _loop_plan(S: int, D: int, Dv: int, dtype, causal: bool) -> _LoopPlan:
+    """Blocks follow the shape alone: 512 rows from S = 1024 up (a key
+    tile then stays in the MXU for 512 rows, and the diagonal block is
+    square), 128 below, where padding a short row to 512 would cost more
+    than it saves; a wide step is four blocks."""
+    block = 512 if S >= 1024 else 128
+    seq_pad = _round_up(S, block)
+    n = seq_pad // block
+    wide = block * min(4, n)
+    padded = seq_pad > S
+    n_masked = int(causal or padded)
+    walked = sum(_whole_blocks(i, n, causal, padded) + n_masked
+                 for i in range(n))
+    dead = (seq_pad - S) * (1 if causal else n)
+    # K and V of a head whole and q, o by the block, each double-buffered
+    # in tiles of (16, 128); the f32 scores of a wide step, their
+    # exponentials in f32 and packed, and as much again for the compiler
+    itemsize = jnp.dtype(dtype).itemsize
+    resident = 2 * itemsize * (
+        _round_up(D, 16) * seq_pad + seq_pad * _round_up(Dv, 128)
+        + block * (_round_up(D, 128) + _round_up(Dv, 128)))
+    scores = block * wide * 4
+    return _LoopPlan(block, wide, seq_pad, walked, n_masked * n, dead,
+                     resident + 5 * scores + (4 << 20))
+
+
+def _loop_kernel(q_ref, kt_ref, v_ref, o_ref, *, scale, causal, block, wide,
+                 seq_len, n_blocks):
+    """Long rows: one (batch·head, q-block) program.  Refs carry a leading
+    length-1 block dim; the k and v refs hold the head's full (padded)
+    sequence, k turned (``[D, S]``, the sequence on the lanes, as the
+    whole-row form reads it), walked under the online-softmax carry.  q
+    and k share one width, v and the output another (latent attention's
+    192 and 128; the same everywhere else).
+
+    What decides the loop's shape (PERF.md §6, PR 31; one v5e, ms a call
+    at ``[2,64,8192,192/128]`` bf16, the parent's loop 30.9):
+
+    * **the mask is the last block's alone** (28.2).  Every block below
+      the diagonal (without a causal mask: before the one the padding
+      starts in) is whole, so the loops' body has no iota, compare or
+      select; the one masked block is straight-line code after them, and
+      square, so no column of it is computed for nothing.  A masked score
+      underflows to exactly 0 against the finite maximum of its row,
+      which key 0 guarantees, so nothing guards the probabilities either.
+    * **operands as they arrive** (bf16 when serving), P packed once
+      before P·V, accumulation and every statistic in f32.  Mosaic rounds
+      f32 operands to bf16 in the MXU's feed, so this is the same
+      arithmetic.  Alone it moves nothing at either width (31.0; at
+      ``[1,16,4096,64]`` 0.756 against the parent's 0.735, as PR 27
+      read it); it stays at both because the next lever needs a key
+      tile that goes to the MXU as it lies (0.601 at 64 wide with it).
+    * **k turned before the call** (26.1 from 28.3): ``q @ k_blk.T``
+      pushes every key tile into the MXU through its transposing path,
+      272 times a head; one XLA transpose a call is 0.5 ms.
+    * **wide steps**: four blocks' scores in one dot while that many
+      whole blocks are left, then single blocks up to the diagonal (23.5;
+      single blocks alone 25.5, unrolled twice 23.9, eight a step 25.4;
+      two score buffers with the next QK^T issued by hand 24.2 and
+      twice the code).
+    * **row sums spread over the lanes** (23.5 from 25.1): a wide step's
+      cross-lane sum is as long as its cross-lane maximum, and only the
+      maximum is needed before the next step."""
+    pl = _pl()
+    q_block = pl.program_id(1)
+    # scaled in f32 and rounded once, as the MXU's feed would
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
+    padded = n_blocks * block > seq_len
+
+    def step(carry, start, width, masked):
         m, l, acc = carry
-        k_blk = k_ref[0, _pl().ds(j * block_k, block_k), :]  # [block_k, D]
-        v_blk = v_ref[0, _pl().ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk.astype(jnp.float32),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [block_q, block_k]
-        q_idx = q_start + qi
-        k_idx = j * block_k + ki
-        valid = k_idx < seq_len  # mask the S-padding keys
-        if causal:
-            valid = jnp.logical_and(valid, q_idx >= k_idx)
-        s = jnp.where(valid, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        # a fully-masked row would exp(-inf - -inf)=exp(0); zero it instead
-        p = jnp.where(valid, p, 0.0)
+        start = pl.multiple_of(start, width)
+        s = jnp.dot(q, kt_ref[0, :, pl.ds(start, width)],
+                    preferred_element_type=jnp.float32)  # [block, width]
+        if masked:
+            k_idx = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            valid = k_idx < seq_len if padded else None
+            if causal:
+                q_idx = q_block * block + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 0)
+                below = q_idx >= k_idx
+                valid = below if valid is None else jnp.logical_and(
+                    valid, below)
+            s = jnp.where(valid, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
-        l_new = corr * l + jnp.sum(p, axis=-1)
-        acc_new = corr[:, None] * acc + jax.lax.dot_general(
-            p, v_blk.astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc_new
+        # the row sums stay spread over the 128 lanes: one cross-lane
+        # reduction a program, at the end, and not one a step
+        spread = p[:, :128]
+        for t in range(1, width // 128):
+            spread = spread + p[:, t * 128:(t + 1) * 128]
+        v_blk = v_ref[0, pl.ds(start, width), :]
+        acc_new = corr * acc + jnp.dot(
+            p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32)
+        return m_new, corr * l + spread, acc_new
 
-    m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    a0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)  # follows v
-    if causal:
-        # skip k-blocks that lie entirely above the diagonal: the last key
-        # this q-block may attend to is q_start + block_q - 1, so only
-        # ceil((q_start + block_q) / block_k) blocks carry any work — the
-        # causal early exit that halves the FLOPs vs masking everything
-        n_iter = (q_start + block_q + block_k - 1) // block_k
-        n_iter = jnp.minimum(n_iter, n_kblocks)
-    else:
-        n_iter = n_kblocks
-    _, l, acc = jax.lax.fori_loop(0, n_iter, body, (m0, l0, a0))
-    out = acc / jnp.maximum(l, 1e-30)[:, None]
+    carry = (jnp.full((block, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((block, 128), jnp.float32),
+             jnp.zeros((block, v_ref.shape[-1]), jnp.float32))  # follows v
+    whole = _whole_blocks(q_block, n_blocks, causal, padded)
+    per_wide = wide // block
+    n_wide = whole // per_wide
+    carry = jax.lax.fori_loop(
+        0, n_wide, lambda j, c: step(c, j * wide, wide, False), carry)
+    carry = jax.lax.fori_loop(
+        n_wide * per_wide, whole,
+        lambda j, c: step(c, j * block, block, False), carry)
+    if causal or padded:
+        carry = step(carry, whole * block, block, True)
+    _, l, acc = carry
+    out = acc / jnp.sum(l, axis=-1, keepdims=True)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -202,33 +284,35 @@ def _row_call(q, k, v, causal, scale, interpret):
 
 def _loop_call(q, k, v, causal, scale, interpret):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, D = q.shape
     Dv = v.shape[-1]
-    bq, bk = (256, 512) if S >= 1024 else (128, 128)
-    pad = -S % bk
-    if pad:
-        zeros = [(0, 0), (0, 0), (0, pad), (0, 0)]
+    plan = _loop_plan(S, D, Dv, q.dtype, causal)
+    blk, Sp = plan.block, plan.seq_pad
+    if Sp > S:
+        zeros = [(0, 0), (0, 0), (0, Sp - S), (0, 0)]
         q, k, v = (jnp.pad(x, zeros) for x in (q, k, v))
-    Sp = S + pad
     q, k, v = (x.reshape(B * H, Sp, x.shape[-1]) for x in (q, k, v))
     kernel = functools.partial(
-        _loop_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        seq_len=S, n_kblocks=Sp // bk)
+        _loop_kernel, scale=scale, causal=causal, block=blk, wide=plan.wide,
+        seq_len=S, n_blocks=Sp // blk)
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B * H, Sp, Dv), q.dtype),
-        grid=(B * H, Sp // bq),
+        grid=(B * H, Sp // blk),
         in_specs=[
-            pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, Sp, D), lambda bh, qi: (bh, 0, 0)),
+            pl.BlockSpec((1, blk, D), lambda bh, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, D, Sp), lambda bh, qi: (bh, 0, 0)),
             pl.BlockSpec((1, Sp, Dv), lambda bh, qi: (bh, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, Dv), lambda bh, qi: (bh, qi, 0)),
+        out_specs=pl.BlockSpec((1, blk, Dv), lambda bh, qi: (bh, qi, 0)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=plan.vmem_bytes),
         interpret=interpret,
-    )(q, k, v)
+    )(q, k.transpose(0, 2, 1), v)
     out = out.reshape(B, H, Sp, Dv)
-    return out[:, :, :S, :] if pad else out
+    return out[:, :, :S, :] if Sp > S else out
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "sm_scale", "interpret"))
