@@ -360,3 +360,84 @@ def test_the_traced_call_is_of_the_form_its_shape_asks_for(shape, dv,
     traced = jax.make_jaxpr(lambda q, k, v: flash_attention(
         q, k, v, causal=False, interpret=True))(q, q, v)
     assert _pallas_operands(traced.jaxpr, []) == [operands]
+
+
+@pytest.mark.parametrize("S", [384, 1024])
+@pytest.mark.parametrize("group", [1, 8], ids=["heads-equal", "8-a-kv-head"])
+@pytest.mark.parametrize("mask_block", [1, 4, 32])
+def test_block_causal_mask_and_grouped_heads(mask_block, group, S):
+    """A causal mask over blocks of rows (row ``i`` sees key ``j`` where ``j
+    // B <= i // B``) and keys of fewer heads than the queries, read through
+    the index map: the looped kernel against the reference that repeats the
+    heads.  Blocks of 1 over equal heads are the call as it was, bit for
+    bit."""
+    H = 8
+    q = _rand((2, H, S, 32), jnp.float32, 1)
+    k = _rand((2, H // group, S, 32), jnp.float32, 2)
+    v = _rand((2, H // group, S, 32), jnp.float32, 3)
+    got = flash_attention(q, k, v, causal=True, mask_block=mask_block,
+                          interpret=True)
+    want = flash_attention_reference(q, k, v, causal=True,
+                                     mask_block=mask_block)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    if mask_block == 1 and group == 1:
+        plain = flash_attention(q, k, v, causal=True, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(plain))
+    else:
+        # the mask is not the plain causal one, and the heads are not
+        # the first of each group alone
+        rep = (jnp.repeat(x, group, axis=1) for x in (k, v))
+        plain = flash_attention_reference(q, *rep, causal=True)
+        assert mask_block == 1 or not np.allclose(got, plain, atol=1e-3)
+    # what a row may see: the last row of a block and the first see the same
+    # keys, so with equal queries they give equal outputs
+    if mask_block > 1:
+        same = q.at[:, :, mask_block - 1].set(q[:, :, 0])
+        out = flash_attention(same, k, v, causal=True, mask_block=mask_block,
+                              interpret=True)
+        np.testing.assert_allclose(np.asarray(out[:, :, 0]),
+                                   np.asarray(out[:, :, mask_block - 1]),
+                                   rtol=1e-6, atol=1e-6)
+
+
+def test_grouped_heads_are_read_through_the_index_map():
+    """k and v reach the kernel with their own head count: nothing repeats
+    them in HBM; a short row with grouped heads or a block mask takes the
+    looped form."""
+    q = jax.ShapeDtypeStruct((2, 32, 1024, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, 4, 1024, 128), jnp.bfloat16)
+    traced = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, mask_block=4, interpret=True))(q, kv, kv)
+    assert _pallas_operands(traced.jaxpr, []) == [
+        [(64, 1024, 128), (8, 128, 1024), (8, 1024, 128)]]
+    short = jax.ShapeDtypeStruct((1, 16, 384, 64), jnp.bfloat16)
+    traced = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, mask_block=4, interpret=True))(
+            short, short, short)
+    assert _pallas_operands(traced.jaxpr, []) == [
+        [(16, 384, 64), (16, 64, 384), (16, 384, 64)]]
+
+
+@pytest.mark.parametrize("bad", [3, 1024])
+def test_a_mask_block_the_kernel_cannot_take_is_refused(bad):
+    q = _rand((1, 2, 256, 16), jnp.float32, 0)
+    with pytest.raises(ValueError, match="mask_block"):
+        flash_attention(q, q, q, causal=True, mask_block=bad, interpret=True)
+
+
+def test_grouped_head_gradients_match_reference():
+    q = _rand((1, 4, 64, 16), jnp.float32, 1)
+    k = _rand((1, 2, 64, 16), jnp.float32, 2)
+    v = _rand((1, 2, 64, 16), jnp.float32, 3)
+
+    def loss(fn, **kw):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, causal=True, mask_block=4,
+                                          **kw) ** 2)
+
+    got = jax.grad(loss(flash_attention, interpret=True), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(flash_attention_reference), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-4)

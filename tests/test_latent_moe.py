@@ -278,14 +278,15 @@ def test_prefill_then_decode_is_the_full_forward_at_every_step(params,
 
 def test_expert_rows_leave_out_the_rows_the_batcher_padded():
     stats = ModelStats()
-    stats.settle_expert_rows()  # nothing queued: most models
+    stats.settle_device_counters()  # nothing queued: most models
     counts = np.array([[[3, 0, 1], [2, 2, 0]], [[9, 9, 9], [9, 9, 9]]])
-    stats.queue_expert_rows(counts, 1, 10)  # the second row is padding
+    # the second row is padding
+    stats.queue_device_counters({"expert_rows": counts}, 1, 10)
     entries = stats.extension_entries()
     assert entries["expert_rows"] == {"count": 8, "ns": 0}
     assert entries["expert_tokens"] == {"count": 1 * 10 * 2, "ns": 0}
     assert entries["expert_rows_busiest"] == {"count": 3 + 2, "ns": 0}
-    stats.queue_expert_rows(counts, 2, 10)
+    stats.queue_device_counters({"expert_rows": counts}, 2, 10)
     assert stats.expert_rows == 8 + 8 + 54
     assert stats.expert_rows_busiest == 5 + (12 + 11)
 
@@ -345,7 +346,7 @@ def test_ids_outside_the_slice_are_clipped(server, tokens):
 def test_a_mesh_of_two_is_refused_until_the_layer_has_its_exchange(
         monkeypatch):
     monkeypatch.setenv("TRITON_TPU_SERVE_MESH_KIMI_K2", "ep=2")
-    run = language._LazyLatentMoE(TINY, "kimi_k2")
+    run = language._LazyBlock(TINY, "kimi_k2", "latent_moe", "forward")
     with pytest.raises(ValueError, match="exchange"):
         run(jnp.zeros((1, S), jnp.int32))
 
@@ -360,3 +361,124 @@ def test_the_zoo_registers_it_without_allocating():
     assert model.config.output[0].dims == [20480]
     assert list(
         model.config.dynamic_batching.preferred_batch_size) == [1, 2]
+
+
+# -- one expert layer for two models: every routed expert held ---------------
+
+def _all_held_cfg(E=8, k=2):
+    """What ``route`` and ``held_experts`` ask of a configuration, for a
+    layer that holds every expert it routes over (block_diffusion's)."""
+    import types
+
+    return types.SimpleNamespace(
+        n_routed_experts=E, routed_experts_total=E, first_expert=0,
+        num_experts_per_tok=k, scoring_func="softmax", router_bias=False,
+        routed_scaling_factor=1.0)
+
+
+def _expert_blk(E, D=32, F=16, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return {"router": jax.random.normal(keys[0], (D, E)) * 0.5,
+            "we_gate": jax.random.normal(keys[1], (E, D, F)) / math.sqrt(D),
+            "we_up": jax.random.normal(keys[2], (E, D, F)) / math.sqrt(D),
+            "we_down": jax.random.normal(keys[3], (E, F, D)) / math.sqrt(F)}
+
+
+@pytest.mark.parametrize("T", [64, 4096])
+def test_all_held_is_the_dense_form_and_the_matmul_combine(T, monkeypatch):
+    """Every routed expert held: ``T*k`` pairs exactly, several passes at T
+    = 4096 (the pass is cut to 2,048 pairs here), un-sorted by the inverse
+    permutation.  Against every expert computed for every token and masked
+    (the dense form), and against the form that holds a few of many (the
+    chunk with slack, the 0/1-matmul combine) given the same pairs."""
+    monkeypatch.setattr(lm, "_PAIRS_A_PASS", 2048)
+    E, k = 8, 2
+    cfg, blk = _all_held_cfg(E, k), _expert_blk(E)
+    h = jax.random.normal(jax.random.PRNGKey(T), (T, 32))
+    idx, weights = lm.route(blk, h, cfg)
+    y, rows = jax.jit(lambda h, idx, w: lm.held_experts(
+        blk, h, idx, w, cfg, batch=4))(h, idx, weights)
+    dense = sum(
+        jnp.sum(jnp.where(idx == e, weights, 0.0), -1)[:, None]
+        * lm._swiglu(h, blk["we_gate"][e], blk["we_up"][e],
+                     blk["we_down"][e]) for e in range(E))
+    assert _rel_l2(y, dense) < 1e-5
+    assert rows.shape == (4, E) and int(rows.sum()) == T * k
+    np.testing.assert_array_equal(
+        np.asarray(rows), np.stack([np.bincount(
+            np.asarray(part).ravel(), minlength=E)
+            for part in np.split(np.asarray(idx), 4)]))
+    # one more expert routed over than held: the other form, the same pairs
+    few = _all_held_cfg(E, k)
+    few.routed_experts_total = E + 1
+    other, other_rows = jax.jit(lambda h, idx, w: lm.held_experts(
+        blk, h, idx, w, few, batch=4))(h, idx, weights)
+    assert _rel_l2(y, other) < 1e-5
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(other_rows))
+
+
+def test_all_held_reads_a_stack_of_layers_in_place():
+    """``first_group``: the experts of every layer as one stack, this
+    layer's groups in the middle of it; the other layers' get no rows."""
+    E, k, T = 8, 2, 64
+    cfg, blk = _all_held_cfg(E, k), _expert_blk(E, seed=1)
+    h = jax.random.normal(jax.random.PRNGKey(5), (T, 32))
+    idx, weights = lm.route(blk, h, cfg)
+    y, _ = lm.held_experts(blk, h, idx, weights, cfg)
+    stack = dict(blk, first_group=jnp.int32(E), **{
+        name: jnp.concatenate([jnp.full_like(blk[name], 3.0), blk[name],
+                               jnp.full_like(blk[name], -2.0)])
+        for name in ("we_gate", "we_up", "we_down")})
+    z, _ = jax.jit(lambda s: lm.held_experts(s, h, idx, weights, cfg))(stack)
+    np.testing.assert_allclose(np.asarray(z), np.asarray(y), rtol=1e-6,
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+def test_route_against_the_closed_form(scoring):
+    """Softmax over all experts, top k, renormalised (Qwen3-MoE's); sigmoid
+    with a selection bias, weights from the scores alone, times a factor
+    (DeepSeek-V3's): one function, told which by the config."""
+    E, k, T = 8, 3, 16
+    blk = _expert_blk(E, seed=2)
+    h = jax.random.normal(jax.random.PRNGKey(9), (T, 32))
+    logits = np.asarray(h @ blk["router"], np.float64)
+    cfg = _all_held_cfg(E, k)
+    cfg.scoring_func = scoring
+    if scoring == "softmax":
+        scores = np.exp(logits) / np.exp(logits).sum(-1, keepdims=True)
+        chosen_by, factor = scores, 1.0
+    else:
+        bias = np.linspace(-0.3, 0.3, E)
+        blk["router_bias"] = jnp.asarray(bias, jnp.float32)
+        cfg.router_bias, cfg.routed_scaling_factor = True, 2.5
+        scores = 1.0 / (1.0 + np.exp(-logits))
+        chosen_by, factor = scores + bias, 2.5
+    want_idx = np.argsort(-chosen_by, axis=-1, kind="stable")[:, :k]
+    picked = np.take_along_axis(scores, want_idx, -1)
+    want = picked / picked.sum(-1, keepdims=True) * factor
+    idx, weights = lm.route(blk, h, cfg)
+    np.testing.assert_array_equal(np.sort(np.asarray(idx), -1),
+                                  np.sort(want_idx, -1))
+    np.testing.assert_allclose(
+        np.take_along_axis(np.asarray(weights),
+                           np.argsort(np.asarray(idx), -1), -1),
+        np.take_along_axis(want, np.argsort(want_idx, -1), -1), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(weights).sum(-1), factor, rtol=1e-5)
+
+
+def test_route_refuses_a_scoring_it_does_not_know():
+    cfg = _all_held_cfg()
+    cfg.scoring_func = "tanh"
+    with pytest.raises(ValueError, match="scoring_func"):
+        lm.route(_expert_blk(8), jnp.zeros((4, 32)), cfg)
+
+
+@pytest.mark.parametrize("k,n,want", [
+    (2048, 768, (128, 2048, 768)),       # sdar_30b_a3b's gate and up: whole
+    (768, 2048, (128, 768, 2048)),       # its down
+    (7168, 2048, (256, 1024, 1024)),     # kimi_k2's gate and up: as PR 28
+    (2048, 7168, (256, 1024, 1024)),     # its down
+], ids=["sdar-up", "sdar-down", "kimi-up", "kimi-down"])
+def test_the_grouped_matmuls_tile_follows_the_experts_shape(k, n, want):
+    assert lm._gmm_tiling(k, n) == want

@@ -42,7 +42,9 @@ PER_ROW = ("queue_member", "batch_assembly", "executor_wait", "dispatch",
 EXTENSION = ("request",) + PER_ROW + (
     "bucket_rows", "batch_carry", "batch_carry_rows", "pause",
     # an expert layer's routing, counted on the device (test_latent_moe.py)
-    "expert_rows", "expert_tokens", "expert_rows_busiest")
+    "expert_rows", "expert_tokens", "expert_rows_busiest",
+    # generation by diffusion over blocks (test_block_diffusion.py)
+    "denoise_passes", "denoise_tokens", "experts_touched")
 
 
 def _echo(sleep_s):

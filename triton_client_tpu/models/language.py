@@ -311,25 +311,29 @@ def make_longctx_tpu() -> JaxModel:
     return JaxModel(cfg, fn, jit=False, analyzable=True)
 
 
-class _LazyLatentMoE:
-    """``_LazyTransformer``'s lazy first-request init for the latent-
-    attention / expert block (models/latent_moe.py): mesh from
-    ``tr.serve_mesh``, weights drawn in bfloat16 on the device leaf by leaf,
-    one jitted forward.  Nothing is imported or allocated before the first
-    call."""
+class _LazyBlock:
+    """``_LazyTransformer``'s lazy first-request init for the blocks that
+    hold their weights in bfloat16 (``module``: models/latent_moe.py,
+    models/block_diffusion.py): mesh from ``tr.serve_mesh``, weights drawn
+    on the device leaf by leaf by ``module.init_params``, one jitted
+    ``step(params, tokens, cfg)``.  Nothing is imported or allocated before
+    the first call."""
 
-    def __init__(self, cfg, model_name: str):
+    def __init__(self, cfg, model_name: str, module: str, step: str):
         self.cfg = cfg
         self._model_name = model_name
+        self._module, self._step = module, step
         self._fwd = None
         self._params = None
 
     def _ensure(self):
+        import importlib
+
         import jax
 
-        from . import latent_moe
-
         if self._fwd is None:
+            module = importlib.import_module(
+                f"{__package__}.{self._module}")
             mesh = tr.serve_mesh(self.cfg, model_name=self._model_name)
             if mesh.size != 1:
                 raise ValueError(
@@ -339,21 +343,42 @@ class _LazyLatentMoE:
                     f"{mesh.size} devices")
             quant = tr.resolve_quant(self._model_name)
             with jax.default_device(mesh.devices.flat[0]):
-                self._params = latent_moe.init_params(
+                self._params = module.init_params(
                     self.cfg, quantized=(quant == "int8"))
-            cfg = self.cfg
+            cfg, step = self.cfg, getattr(module, self._step)
             self._fwd = jax.jit(
-                lambda params, tokens: latent_moe.forward(params, tokens,
-                                                          cfg))
+                lambda params, tokens: step(params, tokens, cfg))
 
     def __call__(self, tokens):
         self._ensure()
         return self._fwd(self._params, tokens)
 
 
-#: the output the expert block's step carries its routing counts in; the
-#: model's ``host_post`` takes it out of the answer for ``ModelStats``
-EXPERT_ROWS = "EXPERT_ROWS"
+#: prefix of the outputs in which a step carries what the device counted
+#: on the way; the model's ``host_post`` takes them out of the answer for
+#: ``ModelStats``' queue of device counters
+DEVICE_COUNTER = "DEVICE_COUNTER."
+
+
+def _counting_model(config, fn, tokens_per_row: int) -> JaxModel:
+    """A ``JaxModel`` whose ``fn`` returns, beside its declared outputs,
+    device counters under ``DEVICE_COUNTER + name`` (each with a leading
+    axis of batch rows)."""
+
+    def host_post(outputs, parameters):
+        # the counts come from the device with the answer; a batched step's
+        # parameters say how many of its rows are not padding
+        counters = {name[len(DEVICE_COUNTER):]: outputs.pop(name)
+                    for name in list(outputs)
+                    if name.startswith(DEVICE_COUNTER)}
+        rows = len(next(iter(counters.values())))
+        model.stats.queue_device_counters(
+            counters, parameters.get("real_batch", rows), tokens_per_row)
+        return outputs
+
+    model = JaxModel(config, fn, jit=False, host_post=host_post,
+                     analyzable=True)
+    return model
 
 
 def make_kimi_k2(cfg=None) -> JaxModel:
@@ -376,23 +401,58 @@ def make_kimi_k2(cfg=None) -> JaxModel:
         instance_kind="KIND_TPU",
         parameters={"flops_per_inference": str(flops_per_inference(cfg))},
     )
-    run = _LazyLatentMoE(cfg, "kimi_k2")
+    run = _LazyBlock(cfg, "kimi_k2", "latent_moe", "forward")
 
     def fn(INPUT_IDS):
         logits, rows = run(INPUT_IDS)
-        return {"LOGITS": logits, EXPERT_ROWS: rows}
+        return {"LOGITS": logits, DEVICE_COUNTER + "expert_rows": rows}
 
-    def host_post(outputs, parameters):
-        # the counts come from the device with the answer; a batched step's
-        # parameters say how many of its rows are not padding
-        rows = outputs.pop(EXPERT_ROWS)
-        model.stats.queue_expert_rows(
-            rows, parameters.get("real_batch", rows.shape[0]), cfg.seq_len)
-        return outputs
+    return _counting_model(config, fn, cfg.seq_len)
 
-    model = JaxModel(config, fn, jit=False, host_post=host_post,
-                     analyzable=True)
-    return model
+
+def make_sdar_30b_a3b(cfg=None) -> JaxModel:
+    """SDAR-30B-A3B-Chat's block on one stage of an 8-stage pipeline
+    (``block_diffusion.SDAR_30B_A3B_STAGE``; a test passes a tiny ``cfg``):
+    INT32 INPUT_IDS [P] → INT32 TOKENS [G], INT32 COMMIT_PASS [G] (the
+    pass, 0 .. ``denoising_steps - 1``, at which each position was
+    committed), FP32 LOGITS [2, vocabulary] (the committed position's
+    logits at block 0's pass 0 and at the last block's last pass) and INT32
+    ROUTES [2, layers, experts a token] (the experts that position chose,
+    for a reference that recomputes the row).  One request is one prompt,
+    answered whole by ``G`` tokens generated by diffusion over blocks: a
+    completion that does not stream."""
+    if cfg is None:
+        from .block_diffusion import SDAR_30B_A3B_STAGE as cfg
+    from .block_diffusion import flops_per_inference
+
+    G = cfg.new_tokens
+    config = make_config(
+        "sdar_30b_a3b",
+        inputs=[("INPUT_IDS", "INT32", [cfg.seq_len])],
+        outputs=[("TOKENS", "INT32", [G]), ("COMMIT_PASS", "INT32", [G]),
+                 ("LOGITS", "FP32", [2, cfg.vocab_size]),
+                 ("ROUTES", "INT32", [2, cfg.num_hidden_layers,
+                                      cfg.num_experts_per_tok])],
+        max_batch_size=16,
+        preferred_batch_sizes=[8, 16],
+        max_queue_delay_us=2000,
+        instance_kind="KIND_TPU",
+        parameters={"flops_per_inference": str(flops_per_inference(cfg))},
+    )
+    run = _LazyBlock(cfg, "sdar_30b_a3b", "block_diffusion", "generate")
+    # every token of a request passes the expert layers once in the prefill
+    # and once in each pass of its block (the published rule; a threshold
+    # that ends a block early makes this an upper bound)
+    tokens_per_row = cfg.seq_len + G * (cfg.denoising_steps + 1)
+
+    def fn(INPUT_IDS):
+        out = run(INPUT_IDS)
+        return {"TOKENS": out["tokens"], "COMMIT_PASS": out["commit_pass"],
+                "LOGITS": out["logits"], "ROUTES": out["routes"],
+                **{DEVICE_COUNTER + name: array
+                   for name, array in out["counters"].items()}}
+
+    return _counting_model(config, fn, tokens_per_row)
 
 
 # Mixture-of-experts scorer: serves the flagship stack's MoE FFN path

@@ -80,6 +80,10 @@ class LatentMoEConfig:
     rope_mscale_all_dim: float
     seq_len: int
     weights_seed: int
+    # how the router scores (``route``): DeepSeek-V3's sigmoid with a
+    # selection bias; another block's config says otherwise
+    scoring_func: str = "sigmoid"
+    router_bias: bool = True
 
     @property
     def n_expert_layers(self) -> int:
@@ -136,6 +140,8 @@ _LEAF_KEYS = {
     "w_vb": 5, "w_o": 6, "w_gate": 7, "w_up": 8, "w_down": 9, "router": 10,
     "router_bias": 11, "we_gate": 12, "we_up": 13, "we_down": 14,
     "ws_gate": 15, "ws_up": 16, "ws_down": 17, "embed": 18, "head": 19,
+    # models/block_diffusion.py's attention (grouped-query heads)
+    "w_q": 20, "w_k": 21, "w_v": 22,
 }
 
 #: the matrices ``TRITON_TPU_QUANT=int8`` stores as int8 (MLA and experts),
@@ -143,6 +149,7 @@ _LEAF_KEYS = {
 _INT8_CONTRACT = {
     "w_qa": (0,), "w_qb_nope": (0,), "w_qb_rope": (0,), "w_kva": (0,),
     "w_kb": (0,), "w_vb": (0,), "w_o": (0, 1),
+    "w_q": (0,), "w_k": (0,), "w_v": (0,),
     "w_gate": (0,), "w_up": (0,), "w_down": (0,),
     "we_gate": (1,), "we_up": (1,), "we_down": (1,),
     "ws_gate": (0,), "ws_up": (0,), "ws_down": (0,),
@@ -388,14 +395,24 @@ def _dense_ffn(blk, x, cfg: LatentMoEConfig):
     return x + y.astype(x.dtype)
 
 
-def route(blk, h, cfg: LatentMoEConfig):
+def route(blk, h, cfg):
     """``h [T,D]`` -> the chosen experts ``idx [T,k]`` (of all
-    ``routed_experts_total``) and their weights ``[T,k]`` f32: sigmoid
-    scores, the top k of score + bias, weights from the scores alone,
-    renormalised and scaled."""
+    ``routed_experts_total``) and their weights ``[T,k]`` f32.  The config
+    says how: ``scoring_func`` ("sigmoid", or "softmax" over all experts, in
+    f32), ``router_bias`` (the top k is taken of score + the layer's
+    selection bias, or of the scores alone); the weights come from the
+    scores alone, renormalised over the k and times
+    ``routed_scaling_factor``."""
     logits = jnp.dot(h, blk["router"], preferred_element_type=jnp.float32)
-    scores = jax.nn.sigmoid(logits)
-    _, idx = lax.top_k(scores + blk["router_bias"], cfg.num_experts_per_tok)
+    if cfg.scoring_func == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif cfg.scoring_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"scoring_func {cfg.scoring_func!r}: 'sigmoid' or "
+                         "'softmax'")
+    chosen_by = scores + blk["router_bias"] if cfg.router_bias else scores
+    _, idx = lax.top_k(chosen_by, cfg.num_experts_per_tok)
     picked = jnp.take_along_axis(scores, idx, axis=-1)
     weights = (picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
                * cfg.routed_scaling_factor)
@@ -403,23 +420,109 @@ def route(blk, h, cfg: LatentMoEConfig):
 
 
 _GMM_TILING = (256, 1024, 1024)
+#: an expert's matrix of at most this many elements is one tile
+_GMM_WHOLE = 2048 * 768
+
+
+def _gmm_tiling(k: int, n: int):
+    """The megablox tile ``(rows, k, n)`` for experts of ``[k,n]``, from
+    the operands' shape alone.  A matrix that fits VMEM whole is one tile,
+    with 128 rows (``[.,2048] x [128,2048,768]`` at 131,072 rows 3.13 ms
+    against 4.84 at ``_GMM_TILING``, at 512 rows 0.65 against 0.92; ``[.,768]
+    x [128,768,2048]`` 3.32 against 4.31 and 0.66 against 0.87; PERF.md §6,
+    PR 32); larger ones (``kimi_k2``'s 7168 x 2048) keep ``_GMM_TILING``,
+    the best PR 28 read for them."""
+    return (128, k, n) if k * n <= _GMM_WHOLE else _GMM_TILING
 
 
 def _grouped_matmul(lhs, rhs, sizes):
     """``lhs [m,k]`` in groups of ``sizes`` rows against ``rhs [G,k,n]`` ->
     ``[m,n]`` f32; rows past ``sum(sizes)`` hold nothing the caller may use.
     The megablox kernel on a TPU where the rows fill its tiles (PERF.md §6,
-    PR 28 has the candidates' numbers), ``lax.ragged_dot`` elsewhere."""
-    if jax.default_backend() == "tpu" and lhs.shape[0] % _GMM_TILING[0] == 0:
+    PR 28 and PR 32 have the candidates' numbers), ``lax.ragged_dot``
+    elsewhere."""
+    tiling = _gmm_tiling(*rhs.shape[1:])
+    if jax.default_backend() == "tpu" and lhs.shape[0] % tiling[0] == 0:
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
         return gmm(lhs, rhs, sizes, preferred_element_type=jnp.float32,
-                   tiling=_GMM_TILING)
+                   tiling=tiling)
     return lax.ragged_dot(lhs, rhs, sizes,
                           preferred_element_type=jnp.float32)
 
 
-def held_experts(blk, h, idx, weights, cfg: LatentMoEConfig, batch: int = 1):
+#: (token, expert) pairs a pass of an all-held layer takes: what bounds its
+#: temporaries (gathered rows, two f32 activations, the output), whatever T
+_PAIRS_A_PASS = 32768
+
+
+def _rows_by_run(group, batch: int, n_experts: int):
+    """Pairs on each expert by run of tokens ``[batch, E]``; ``group [T*k]``
+    holds each pair's expert, ``n_experts`` for a pair that is not held."""
+    return jnp.sum(group.reshape(batch, -1, 1) == jnp.arange(n_experts),
+                   axis=1, dtype=jnp.int32)
+
+
+def _grouped_swiglu(blk, rows, sizes):
+    """``rows`` in groups of ``sizes`` through the layer's experts.  Where
+    ``blk`` holds the experts of every layer as one stack (``first_group``
+    says where this layer's begin), the stack is read in place: the other
+    layers' groups get no rows."""
+    first = blk.get("first_group")
+    if first is not None:
+        sizes = lax.dynamic_update_slice(
+            jnp.zeros(blk["we_gate"].shape[:1], sizes.dtype), sizes, (first,))
+    with jax.named_scope("experts"):
+        g = _grouped_matmul(rows, _w(blk, "we_gate"), sizes)
+        u = _grouped_matmul(rows, _w(blk, "we_up"), sizes)
+        a = (jax.nn.silu(g) * u).astype(rows.dtype)
+        return _grouped_matmul(a, _w(blk, "we_down"), sizes)
+
+
+def _all_held(blk, h, idx, weights, cfg, batch: int):
+    """``held_experts`` where every routed expert is held: all ``T*k`` pairs
+    are computed, so there is no slack to leave and nothing to mask.  Sorted
+    by expert, they are taken ``_PAIRS_A_PASS`` at a time (no temporary
+    grows with T), and a pass's output rows go back to their pairs' places
+    by the inverse permutation: a token's k rows then lie side by side and
+    are weighted and summed in f32, in time linear in T (PERF.md §6, PR 32
+    has the segment sum's and the 0/1 matmul's numbers beside it)."""
+    T, D = h.shape
+    E, k = cfg.n_routed_experts, cfg.num_experts_per_tok
+    N = T * k
+    C = min(N, _PAIRS_A_PASS)
+    n_passes = -(-N // C)
+    with jax.named_scope("dispatch"):
+        group = idx.reshape(-1)
+        rows = _rows_by_run(group, batch, E)
+        counts = jnp.sum(rows, axis=0)
+        starts = jnp.cumsum(counts) - counts
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        # where each pair's row lies among the sorted ones
+        place = jnp.zeros((N,), jnp.int32).at[order].set(
+            jnp.arange(N, dtype=jnp.int32))
+        order = jnp.pad(order, (0, n_passes * C - N))
+
+    def one_pass(i):
+        lo = i * C
+        with jax.named_scope("dispatch"):
+            pairs = lax.dynamic_slice(order, (lo,), (C,))
+            taken = jnp.take(h, pairs // k, axis=0)
+            sizes = jnp.clip(jnp.minimum(starts + counts, lo + C)
+                             - jnp.maximum(starts, lo), 0, C)
+        return _grouped_swiglu(blk, taken, sizes).astype(h.dtype)
+
+    if n_passes == 1:
+        out = one_pass(0)
+    else:
+        out = lax.map(one_pass, jnp.arange(n_passes)).reshape(-1, D)
+    with jax.named_scope("combine"):
+        mine = jnp.take(out, place, axis=0).reshape(T, k, D)
+        y = jnp.einsum("tkd,tk->td", mine.astype(jnp.float32), weights)
+    return y, rows
+
+
+def held_experts(blk, h, idx, weights, cfg, batch: int = 1):
     """The held experts' part of the layer for ``h [T,D]`` (``batch`` equal
     runs of tokens): ``(y [T,D] f32, rows routed to each held expert by run
     [batch,E])``.  Pairs on held experts are
@@ -428,7 +531,12 @@ def held_experts(blk, h, idx, weights, cfg: LatentMoEConfig, batch: int = 1):
     weight, add into ``y`` (in the activations' dtype, summed in f32).  The
     loop runs as many chunks as the routing
     filled, so nothing is dropped and nothing of the worst case's size
-    stands allocated."""
+    stands allocated.  The form follows the config's static shape: a layer
+    that holds every routed expert takes :func:`_all_held`; one that holds a
+    few of many keeps the chunk with slack and the 0/1-matmul combine, the
+    faster there (PERF.md §6, PR 28)."""
+    if cfg.n_routed_experts == cfg.routed_experts_total:
+        return _all_held(blk, h, idx, weights, cfg, batch)
     T, D = h.shape
     E, k = cfg.n_routed_experts, cfg.num_experts_per_tok
     # pairs a pass takes: a quarter over what even routing sends the held
@@ -440,8 +548,7 @@ def held_experts(blk, h, idx, weights, cfg: LatentMoEConfig, batch: int = 1):
         local = idx - cfg.first_expert
         held = (local >= 0) & (local < E)
         group = jnp.where(held, local, E).reshape(-1)           # [T*k]
-        rows = jnp.sum(group.reshape(batch, -1, 1) == jnp.arange(E),
-                       axis=1, dtype=jnp.int32)                 # [batch,E]
+        rows = _rows_by_run(group, batch, E)
         counts = jnp.sum(rows, axis=0)
         starts = jnp.cumsum(counts) - counts
         n_held = jnp.sum(counts)
@@ -459,11 +566,7 @@ def held_experts(blk, h, idx, weights, cfg: LatentMoEConfig, batch: int = 1):
             rows = jnp.take(h, token, axis=0)
             sizes = jnp.clip(jnp.minimum(starts + counts, lo + C)
                              - jnp.maximum(starts, lo), 0, C)
-        with jax.named_scope("experts"):
-            g = _grouped_matmul(rows, _w(blk, "we_gate"), sizes)
-            u = _grouped_matmul(rows, _w(blk, "we_up"), sizes)
-            a = (jax.nn.silu(g) * u).astype(h.dtype)
-            out = _grouped_matmul(a, _w(blk, "we_down"), sizes)
+        out = _grouped_swiglu(blk, rows, sizes)
         with jax.named_scope("combine"):
             # y[t] += the chunk's rows of token t, as one matmul against
             # the 0/1 matrix (token, row): a scatter-add of the same rows

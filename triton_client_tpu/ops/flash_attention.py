@@ -42,19 +42,27 @@ import jax.numpy as jnp
 _NEG_INF = -1e30
 
 
-def flash_attention_reference(q, k, v, *, causal: bool = True, sm_scale=None):
+def flash_attention_reference(q, k, v, *, causal: bool = True, sm_scale=None,
+                              mask_block: int = 1):
     """Plain-jnp attention with the same signature/semantics as the kernel.
 
-    q, k: [B, H, S, D]; v: [B, H, S, Dv]; returns [B, H, S, Dv] in q.dtype.
+    q: [B, H, S, D]; k: [B, Hkv, S, D]; v: [B, Hkv, S, Dv] with ``H`` a
+    multiple of ``Hkv`` (query head ``h`` reads key/value head ``h // (H //
+    Hkv)``); returns [B, H, S, Dv] in q.dtype.  ``mask_block`` widens the
+    causal mask to blocks: row ``i`` sees key ``j`` where ``j // mask_block
+    <= i // mask_block`` (1 is the plain causal mask).
     """
     B, H, S, D = q.shape
+    group = H // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale
     if causal:
         idx = jnp.arange(S)
-        mask = idx[:, None] >= idx[None, :]
+        mask = (idx[:, None] | (mask_block - 1)) >= idx[None, :]
         s = jnp.where(mask, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
@@ -112,13 +120,16 @@ def _loop_plan(S: int, D: int, Dv: int, dtype, causal: bool) -> _LoopPlan:
 
 
 def _loop_kernel(q_ref, kt_ref, v_ref, o_ref, *, scale, causal, block, wide,
-                 seq_len, n_blocks):
+                 seq_len, n_blocks, mask_block=1):
     """Long rows: one (batch·head, q-block) program.  Refs carry a leading
     length-1 block dim; the k and v refs hold the head's full (padded)
     sequence, k turned (``[D, S]``, the sequence on the lanes, as the
     whole-row form reads it), walked under the online-softmax carry.  q
     and k share one width, v and the output another (latent attention's
-    192 and 128; the same everywhere else).
+    192 and 128; the same everywhere else).  A causal mask over blocks
+    of ``mask_block`` rows (a power of two that divides ``block``) differs
+    from the plain one inside the diagonal block alone: row ``i`` sees the
+    keys up to ``i | (mask_block - 1)``.
 
     What decides the loop's shape (PERF.md §6, PR 31; one v5e, ms a call
     at ``[2,64,8192,192/128]`` bf16, the parent's loop 30.9):
@@ -165,6 +176,8 @@ def _loop_kernel(q_ref, kt_ref, v_ref, o_ref, *, scale, causal, block, wide,
             if causal:
                 q_idx = q_block * block + jax.lax.broadcasted_iota(
                     jnp.int32, s.shape, 0)
+                if mask_block > 1:  # the end of the row's block of rows
+                    q_idx = q_idx | (mask_block - 1)
                 below = q_idx >= k_idx
                 valid = below if valid is None else jnp.logical_and(
                     valid, below)
@@ -282,29 +295,37 @@ def _row_call(q, k, v, causal, scale, interpret):
     return out[..., :S].transpose(0, 1, 3, 2)
 
 
-def _loop_call(q, k, v, causal, scale, interpret):
+def _loop_call(q, k, v, causal, scale, interpret, mask_block=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, D = q.shape
     Dv = v.shape[-1]
+    # query heads a key/value head serves: the programs of one group read
+    # the same k and v block (``bh // group``), which is fetched once for
+    # them all and never repeated in HBM
+    group = H // k.shape[1]
     plan = _loop_plan(S, D, Dv, q.dtype, causal)
     blk, Sp = plan.block, plan.seq_pad
+    if mask_block & (mask_block - 1) or blk % mask_block:
+        raise ValueError(f"mask_block {mask_block}: a power of two that "
+                         f"divides the kernel's block of {blk}")
     if Sp > S:
         zeros = [(0, 0), (0, 0), (0, Sp - S), (0, 0)]
         q, k, v = (jnp.pad(x, zeros) for x in (q, k, v))
-    q, k, v = (x.reshape(B * H, Sp, x.shape[-1]) for x in (q, k, v))
+    q, k, v = (x.reshape(-1, Sp, x.shape[-1]) for x in (q, k, v))
     kernel = functools.partial(
         _loop_kernel, scale=scale, causal=causal, block=blk, wide=plan.wide,
-        seq_len=S, n_blocks=Sp // blk)
+        seq_len=S, n_blocks=Sp // blk,
+        mask_block=mask_block if causal else 1)
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B * H, Sp, Dv), q.dtype),
         grid=(B * H, Sp // blk),
         in_specs=[
             pl.BlockSpec((1, blk, D), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, D, Sp), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, Sp, Dv), lambda bh, qi: (bh, 0, 0)),
+            pl.BlockSpec((1, D, Sp), lambda bh, qi: (bh // group, 0, 0)),
+            pl.BlockSpec((1, Sp, Dv), lambda bh, qi: (bh // group, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, blk, Dv), lambda bh, qi: (bh, qi, 0)),
         compiler_params=pltpu.CompilerParams(
@@ -315,35 +336,43 @@ def _loop_call(q, k, v, causal, scale, interpret):
     return out[:, :, :S, :] if Sp > S else out
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "sm_scale", "interpret"))
-def _flash_call(q, k, v, causal, sm_scale, interpret):
+@functools.partial(jax.jit, static_argnames=("causal", "sm_scale", "interpret",
+                                             "mask_block"))
+def _flash_call(q, k, v, causal, sm_scale, interpret, mask_block=1):
     """The form follows the shape: a key row short enough for one f32 score
     tile in VMEM takes the whole-row kernel, a longer one the looped, and so
-    does a value narrower or wider than the keys."""
-    S, D = q.shape[2:]
+    does a value narrower or wider than the keys, keys of fewer heads than
+    the queries, or a causal mask over blocks of rows."""
+    H, S, D = q.shape[1:]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
-    if _round_up(S, 128) <= _ROW_MAX_S and v.shape[-1] == D:
+    if H % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(f"{H} query heads over {k.shape[1]} key and "
+                         f"{v.shape[1]} value heads")
+    plain = k.shape[1] == H and (mask_block == 1 or not causal)
+    if _round_up(S, 128) <= _ROW_MAX_S and v.shape[-1] == D and plain:
         return _row_call(q, k, v, causal, scale, interpret)
-    return _loop_call(q, k, v, causal, scale, interpret)
+    return _loop_call(q, k, v, causal, scale, interpret, mask_block)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, sm_scale, interpret):
-    return _flash_call(q, k, v, causal, sm_scale, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, sm_scale, interpret, mask_block):
+    return _flash_call(q, k, v, causal, sm_scale, interpret, mask_block)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, interpret):
-    return _flash_call(q, k, v, causal, sm_scale, interpret), (q, k, v)
+def _flash_fwd(q, k, v, causal, sm_scale, interpret, mask_block):
+    return (_flash_call(q, k, v, causal, sm_scale, interpret, mask_block),
+            (q, k, v))
 
 
-def _flash_bwd(causal, sm_scale, interpret, res, g):
+def _flash_bwd(causal, sm_scale, interpret, mask_block, res, g):
     # Backward recomputes attention through the jnp reference and takes its
     # VJP — the standard flash trade (no stored [S,S] probabilities costs a
     # recompute); XLA fuses it into one fp32 pass.
     q, k, v = res
     _, vjp = jax.vjp(
         lambda q_, k_, v_: flash_attention_reference(
-            q_, k_, v_, causal=causal, sm_scale=sm_scale),
+            q_, k_, v_, causal=causal, sm_scale=sm_scale,
+            mask_block=mask_block),
         q, k, v)
     return vjp(g)
 
@@ -352,11 +381,16 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, sm_scale=None,
-                    interpret: bool = False, force: bool = False):
+                    mask_block: int = 1, interpret: bool = False,
+                    force: bool = False):
     """Attention over [B, H, S, D] tensors without the scores in HBM;
     differentiable.  ``v`` may have another width than ``q`` and ``k``; the
-    output and the VJP follow it.  Block shapes and the kernel's form
-    (whole-row or looped) follow ``(S, D)``: see :func:`_flash_call`.
+    output and the VJP follow it.  ``k`` and ``v`` may have fewer heads than
+    ``q`` (grouped queries: head ``h`` reads ``h // (H // Hkv)``), and a
+    causal mask may run over blocks of ``mask_block`` rows (a power of two;
+    row ``i`` sees key ``j`` where ``j // mask_block <= i // mask_block``).
+    Block shapes and the kernel's form (whole-row or looped) follow the
+    operands: see :func:`_flash_call`.
 
     On a TPU backend (or with ``force``) this runs the compiled pallas
     kernel, and a Mosaic refusal raises — no fallback.  Off TPU, with
@@ -366,5 +400,6 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale=None,
     """
     if not (interpret or force) and jax.default_backend() != "tpu":
         return flash_attention_reference(q, k, v, causal=causal,
-                                         sm_scale=sm_scale)
-    return _flash(q, k, v, causal, sm_scale, interpret)
+                                         sm_scale=sm_scale,
+                                         mask_block=mask_block)
+    return _flash(q, k, v, causal, sm_scale, interpret, mask_block)

@@ -2504,7 +2504,7 @@ class InferenceCore:
         out = []
         for m in models:
             s = m.stats
-            s.settle_expert_rows()
+            s.settle_device_counters()
             with s.lock:
                 out.append(
                     {

@@ -175,16 +175,21 @@ class ModelStats:
     batch_carry_rows: int = 0   # and the rows it left for the next batch
     pause_count: int = 0        # collector / late-loop pauses that held
     pause_ns: int = 0           # requests of this model
-    # an expert layer's routing, counted on the device and read back with
-    # the answer (models/latent_moe.py); 0 for a model without one
+    # counted on the device and read back with the answer; 0 for a model
+    # whose step carries none.  An expert layer's routing
+    # (models/latent_moe.py):
     expert_rows: int = 0          # (token, expert) pairs on held experts
     expert_tokens: int = 0        # tokens x expert layers run, no pad rows
     expert_rows_busiest: int = 0  # per layer and execution, the fullest
     #                               held expert's rows, summed
+    # generation by diffusion over blocks (models/block_diffusion.py):
+    denoise_passes: int = 0       # passes x sequences, commit passes too
+    denoise_tokens: int = 0       # tokens committed
+    experts_touched: int = 0      # per pass and layer, experts with a row
     lock: threading.Lock = field(default_factory=threading.Lock)
-    # steps whose counts are still on the device: (array [rows, layers,
-    # experts], real rows, tokens a row)
-    _expert_pending: Deque = field(default_factory=deque)
+    # steps whose counters are still on the device: ({name: array with a
+    # leading axis of batch rows}, real rows, tokens a row)
+    _device_pending: Deque = field(default_factory=deque)
 
     def inc_pending(self) -> None:
         with self.lock:
@@ -266,35 +271,54 @@ class ModelStats:
             self.lock.release()
         return True
 
-    def queue_expert_rows(self, rows, real_rows: int,
-                          tokens_per_row: int) -> None:
-        """A model's ``host_post`` hands over its step's routing counts
-        (``int32 [batch, expert layers, held experts]``, still on the
-        device) with the rows the batcher did not pad on: start their copy
+    def queue_device_counters(self, counters: Dict[str, Any], real_rows: int,
+                              tokens_per_row: int = 0) -> None:
+        """A model's ``host_post`` hands over what its step counted on the
+        device, by name (``_fold_device_counter`` says what each array
+        holds; all have a leading axis of batch rows and are still on the
+        device), with the rows the batcher did not pad on: start their copy
         to the host and fold the earlier steps that have finished."""
-        if hasattr(rows, "copy_to_host_async"):
-            rows.copy_to_host_async()
-        self._expert_pending.append((rows, real_rows, tokens_per_row))
-        self.settle_expert_rows()
+        for array in counters.values():
+            if hasattr(array, "copy_to_host_async"):
+                array.copy_to_host_async()
+        self._device_pending.append((counters, real_rows, tokens_per_row))
+        self.settle_device_counters()
 
-    def settle_expert_rows(self) -> None:
-        """Fold the queued steps' counts into the three entries, oldest
+    def settle_device_counters(self) -> None:
+        """Fold the queued steps' counters into their entries, oldest
         first, up to the first whose step is still running on the device:
         like ``inference_count``, the entries count finished steps, and
         reading them never waits for the device."""
         with self.lock:
-            while self._expert_pending:
-                rows, real_rows, tokens_per_row = self._expert_pending[0]
-                ready = getattr(rows, "is_ready", None)
-                if ready is not None and not ready():
-                    return
-                self._expert_pending.popleft()
-                counts = np.asarray(rows)[:real_rows]
-                self.expert_rows += int(counts.sum())
-                self.expert_tokens += \
-                    real_rows * tokens_per_row * counts.shape[1]
-                self.expert_rows_busiest += int(
-                    counts.sum(axis=0).max(axis=-1).sum())
+            while self._device_pending:
+                counters, real_rows, tokens_per_row = self._device_pending[0]
+                for array in counters.values():
+                    ready = getattr(array, "is_ready", None)
+                    if ready is not None and not ready():
+                        return
+                self._device_pending.popleft()
+                for name, array in counters.items():
+                    self._fold_device_counter(
+                        name, np.asarray(array)[:real_rows], tokens_per_row)
+
+    def _fold_device_counter(self, name: str, counts, tokens_per_row: int):
+        # caller holds ``lock``; ``counts`` holds the real rows alone
+        if name == "expert_rows":
+            # pairs routed to each held expert [rows, expert layers, experts]
+            self.expert_rows += int(counts.sum())
+            self.expert_tokens += \
+                counts.shape[0] * tokens_per_row * counts.shape[1]
+            self.expert_rows_busiest += int(
+                counts.sum(axis=0).max(axis=-1).sum())
+        elif name in ("denoise_passes", "denoise_tokens"):
+            # a count a row [rows]
+            setattr(self, name, getattr(self, name) + int(counts.sum()))
+        elif name == "experts_touched":
+            # [rows], entry r counted over the rows 0 .. r: the last real
+            # row's entry is the batch's without its padding
+            self.experts_touched += int(counts[-1]) if len(counts) else 0
+        else:
+            raise KeyError(f"no device counter named {name!r}")
 
     def extension_entries(self) -> Dict[str, Dict[str, int]]:
         """The extension's ``inference_stats`` entries (caller holds
@@ -315,6 +339,9 @@ class ModelStats:
             "expert_tokens": {"count": self.expert_tokens, "ns": 0},
             "expert_rows_busiest": {"count": self.expert_rows_busiest,
                                     "ns": 0},
+            "denoise_passes": {"count": self.denoise_passes, "ns": 0},
+            "denoise_tokens": {"count": self.denoise_tokens, "ns": 0},
+            "experts_touched": {"count": self.experts_touched, "ns": 0},
         }
 
 
