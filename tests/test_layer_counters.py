@@ -40,7 +40,7 @@ V2 = ("success", "fail", "queue", "compute_input", "compute_infer",
 PER_ROW = ("queue_member", "batch_assembly", "executor_wait", "dispatch",
            "device_wait")
 EXTENSION = ("request",) + PER_ROW + (
-    "bucket_rows", "batch_carry", "batch_carry_rows", "pause",
+    "bucket_rows", "batch_carry", "batch_carry_rows", "batch_hold", "pause",
     # an expert layer's routing, counted on the device (test_latent_moe.py)
     "expert_rows", "expert_tokens", "expert_rows_busiest",
     # generation by diffusion over blocks (test_block_diffusion.py)

@@ -386,10 +386,215 @@ async def _batch_carry_counts():
     assert ext["bucket_rows"]["count"] == 22  # not one pad row
 
 
+class _OnDevice:
+    """An output still on ``_DeviceRig``'s device: reading it waits for the
+    end of its step, as ``np.asarray`` of a dispatched ``jax.Array`` does."""
+
+    def __init__(self, array, end):
+        self._array, self._end = array, end
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(max(0.0, self._end - time.monotonic()))
+        return self._array
+
+
+class _DeviceRig(_GatedRig):
+    """The same model on a device of its own: ``execute`` returns after
+    ``dispatch_s``, the device runs one step at a time in dispatch order,
+    each for ``step_s(rows)`` seconds, and the outputs are on the host when
+    their step ends.  Executions finish, so a bucket gets a service time on
+    record; ``began`` and ``ends`` hold each execution's dispatch and its
+    end on the device (``time.monotonic()``)."""
+
+    def __init__(self, step_s, dispatch_s=0.0, buckets=(4, 8, 16), max_bs=16,
+                 delay_us=20_000):
+        super().__init__(buckets=buckets, max_bs=max_bs, delay_us=delay_us)
+        self._step_s, self._dispatch_s = step_s, dispatch_s
+        self._free_at = 0.0
+        self.began, self.ends = [], []
+
+    def _fn(self, inputs, params):
+        now = time.monotonic()
+        with self._lock:
+            self.executions.append(inputs["INPUT"][:, 0].tolist())
+            end = max(now, self._free_at) + self._step_s(len(inputs["INPUT"]))
+            self._free_at = end
+            self.began.append(now)
+            self.ends.append(end)
+        time.sleep(self._dispatch_s)
+        return {"OUTPUT": _OnDevice(inputs["INPUT"], end)}
+
+    async def teach(self, *rows):
+        """One client-made batch of each size, alone: its bucket's service
+        time is on record afterwards."""
+        for n in rows:
+            await self.send(99, rows=n)
+
+    async def one_ahead(self, rows=8):
+        """A batch of ``rows`` in flight (value 100); its index."""
+        k = len(self.executions)
+        task = self.send(100, rows=rows)
+        await self.until(lambda: len(self.executions) == k + 1
+                         and self.ahead() == 1, "the batch ahead in flight")
+        return k, task
+
+    def hold_counter(self):
+        (row,) = self.core.statistics("gated")
+        return row["inference_stats"]["batch_hold"]
+
+
+def _long(rows):
+    return 0.6
+
+
+async def _one_ahead_with_long_to_run_waits_for_the_fill():
+    # 8 wait behind a batch with 0.6 s to run: they stay open past their
+    # window (five of them) and go as 16 the moment the other 8 arrive,
+    # while that batch is still running
+    rig = _DeviceRig(_long)
+    await rig.teach(8)
+    k, ahead = await rig.one_ahead(8)
+    tasks = [ahead] + [rig.send(v) for v in range(1, 9)]
+    await asyncio.sleep(0.1)
+    assert len(rig.executions) == k + 1 and rig.ahead() == 1
+    tasks += [rig.send(v) for v in range(9, 17)]
+    await rig.until(lambda: len(rig.executions) == k + 2, "the batch of 16")
+    assert rig.executions[k + 1] == [float(v) for v in range(1, 17)]
+    assert rig.began[k + 1] < rig.ends[k]
+    got = await rig.finish(tasks)
+    assert not any(isinstance(g, Exception) for g in got)
+
+
+async def _the_fill_never_comes_closes_before_the_batch_ahead_ends():
+    # six wait and no more come: they close at the bucket they fill before
+    # the batch ahead ends, not after, and carry the rest
+    rig = _DeviceRig(_long)
+    await rig.teach(8)
+    k, ahead = await rig.one_ahead(8)
+    tasks = [ahead] + [rig.send(v) for v in range(1, 7)]
+    await rig.until(lambda: len(rig.executions) == k + 2, "the batch of four")
+    assert rig.executions[k + 1] == [1., 2., 3., 4.]
+    assert rig.began[k + 1] < rig.ends[k]
+    await rig.until(lambda: rig.held("_pending") == [5., 6.], "two carried")
+    got = await rig.finish(tasks)
+    assert not any(isinstance(g, Exception) for g in got)
+    assert rig.hold_counter()["count"] == 1
+
+
+async def _bucket_never_seen_closes_at_the_windows_end():
+    # nothing on record for the bucket ahead: today's rule
+    rig = _DeviceRig(_long)
+    await rig.teach(4)
+    k, ahead = await rig.one_ahead(8)
+    tasks = [ahead] + [rig.send(v) for v in (1, 2, 3)]
+    await rig.until(lambda: len(rig.executions) == k + 2, "the batch of three")
+    assert rig.executions[k + 1] == [1., 2., 3., 0.]
+    assert rig.began[k + 1] < rig.ends[k]
+    await rig.finish(tasks)
+    assert rig.hold_counter() == {"count": 0, "ns": 0}
+
+
+async def _steps_shorter_than_the_lead_close_at_the_windows_end():
+    # the host takes 40 ms to stand a batch on the device, so the lead is
+    # four times that: a step of 120 ms is not waited for
+    rig = _DeviceRig(lambda rows: 0.12, dispatch_s=0.04, delay_us=10_000)
+    await rig.teach(8)
+    k, ahead = await rig.one_ahead(8)
+    tasks = [ahead] + [rig.send(v) for v in (1, 2, 3)]
+    await rig.until(lambda: len(rig.executions) == k + 2, "the batch of three")
+    assert rig.executions[k + 1] == [1., 2., 3., 0.]
+    await rig.finish(tasks)
+    assert rig.hold_counter() == {"count": 0, "ns": 0}
+
+
+async def _held_behind_one_stays_in_the_tiered_queue():
+    # held behind one batch, arrivals stay in the queue: tier 0 leaves it
+    # first, and a deadline that passes there is a 504 with zero compute
+    rig = _DeviceRig(_long)
+    await rig.teach(8)
+    k, ahead = await rig.one_ahead(8)
+    tasks = [ahead, rig.send(1, priority=1)]
+    await rig.until(lambda: rig.held("_pending") == [1.], "the first taken")
+    tasks.append(rig.send(2, priority=1))
+    tasks.append(rig.send(3, priority=1, deadline_s=0.15))
+    tasks += [rig.send(v, priority=0) for v in (4, 5)]
+    await rig.until(lambda: rig.batcher._queue.depths()[:2] == [2, 2],
+                    "four left in the queue")
+    await rig.until(lambda: len(rig.executions) == k + 2, "the batch of four")
+    assert rig.executions[k + 1] == [1., 4., 5., 2.]
+    assert rig.began[k + 1] < rig.ends[k]
+    got = await rig.finish(tasks)
+    assert isinstance(got[3], InferError) and got[3].http_status == 504
+    assert not any(isinstance(g, Exception) for g in got[:3] + got[4:])
+    assert all(3. not in e for e in rig.executions)
+    assert rig.core.deadline_exceeded_by_model == {"gated": 1}
+
+
+async def _held_behind_one_at_shutdown_gets_503():
+    rig = _DeviceRig(_long)
+    await rig.teach(8)
+    k, ahead = await rig.one_ahead(8)
+    tasks = [rig.send(v) for v in (1, 2, 3)]
+    await asyncio.sleep(0.1)
+    assert rig.held("_pending") == [1.] and rig.batcher._queue.qsize() == 2
+    down = asyncio.ensure_future(rig.core.shutdown(drain_s=0.05))
+    got = await asyncio.wait_for(asyncio.gather(
+        *tasks, return_exceptions=True), 10)
+    assert [g.http_status for g in got] == [503] * 3
+    await down
+    assert (await ahead).outputs[0].data.shape == (8, 4)
+    assert len(rig.executions) == k + 1  # the batch in flight still ran
+
+
+async def _batch_hold_counts():
+    # one close waited for the batch ahead (a tenth of a second and more
+    # past its window), the others did not
+    rig = _DeviceRig(_long)
+    await rig.teach(8)
+    assert rig.hold_counter() == {"count": 0, "ns": 0}
+    k, ahead = await rig.one_ahead(8)
+    tasks = [ahead] + [rig.send(v) for v in range(1, 9)]
+    await asyncio.sleep(0.12)
+    tasks += [rig.send(v) for v in range(9, 17)]
+    await rig.finish(tasks)
+    (row,) = rig.core.statistics("gated")
+    hold = row["inference_stats"]["batch_hold"]
+    assert hold["count"] == 1 and hold["ns"] >= 100_000_000
+    assert row["execution_count"] == 3 and row["inference_count"] == 32
+
+
+async def _closed_loop_of_32_leaves_16_8_8():
+    # 32 callers over a top bucket of 16, a step affine in its bucket at
+    # sdar_30b_a3b.blockgen's ratio (412.8 : 624 ms), an answer 5 ms on the
+    # wire: started as 16, 8, 8 (how the window's end alone keeps it), the
+    # loop has only full batches once both buckets have a step on record
+    rig = _DeviceRig(lambda rows: 0.082 if rows <= 8 else 0.124,
+                     buckets=(8, 16), delay_us=5_000)
+    stop = False
+
+    async def caller(v):
+        while not stop:
+            await rig.core.infer(_request("gated", np.full((1, 4), float(v))))
+            await asyncio.sleep(0.005)
+
+    callers = [asyncio.ensure_future(caller(v)) for v in range(1, 17)]
+    await rig.until(lambda: len(rig.executions) == 1, "the first 16")
+    callers += [asyncio.ensure_future(caller(v)) for v in range(17, 25)]
+    await rig.until(lambda: len(rig.executions) == 2, "8 behind them")
+    callers += [asyncio.ensure_future(caller(v)) for v in range(25, 33)]
+    await rig.until(lambda: len(rig.executions) >= 11, "eleven executions")
+    ran = [len(_real(e)) for e in rig.executions[:11]]
+    stop = True
+    await asyncio.gather(*callers)
+    await rig.core.shutdown()
+    assert ran[:3] == [16, 8, 8] and ran[3:] == [16] * 8, ran
+
+
 class TestBatcherBacklog:
     """A batch is padded only while nothing of the model is in flight; with
-    a batch ahead it closes at the bucket it fills and carries the rest,
-    with two ahead it stays open (``_DynamicBatcher``'s docstring)."""
+    a batch ahead it closes at the bucket it fills and carries the rest, or
+    stays open while that batch outlasts the host's lead; with two ahead it
+    stays open (``_DynamicBatcher``'s docstring)."""
 
     @pytest.mark.parametrize("scenario", [
         _carry_21_behind_one,
@@ -402,6 +607,14 @@ class TestBatcherBacklog:
         _carried_request_at_shutdown_gets_503,
         _tiers_keep_priority_across_a_carry,
         _batch_carry_counts,
+        _one_ahead_with_long_to_run_waits_for_the_fill,
+        _the_fill_never_comes_closes_before_the_batch_ahead_ends,
+        _bucket_never_seen_closes_at_the_windows_end,
+        _steps_shorter_than_the_lead_close_at_the_windows_end,
+        _held_behind_one_stays_in_the_tiered_queue,
+        _held_behind_one_at_shutdown_gets_503,
+        _batch_hold_counts,
+        _closed_loop_of_32_leaves_16_8_8,
     ], ids=lambda f: f.__name__.lstrip("_"))
     def test_batch_is_sized_against_the_backlog(self, scenario):
         _run(scenario())

@@ -238,7 +238,8 @@ class _DynamicBatcher:
     splits results.
 
     A batch is sized against the device's backlog, read from one
-    observable: the batches of this model in flight (``_batch_tasks``).
+    observable: the batches of this model in flight (``_batch_tasks``),
+    and with one in flight how long it still has to run.
 
     * **None in flight**: the batch collects for the queue delay from its
       first member's arrival, is padded to the smallest bucket ≥ its rows
@@ -252,6 +253,19 @@ class _DynamicBatcher:
       ahead like a real one; carried, its place goes to a request that
       arrives meanwhile (the reference's ``preferred_batch_size``:
       dispatch a preferred size from what is queued, leave the rest).
+    * **One in flight that outlasts the host's lead**: a batch short of
+      the top bucket stays open past its window, until the batch ahead is
+      expected to have no more than ``lead`` left to run, and then closes
+      as above.  Dispatched earlier it would wait on the device's FIFO
+      until that batch ends; held here it starts on the chip at the same
+      moment with more rows.  Both times are observed, neither is set:
+      the batch ahead's end is its start on the device (its dispatch, or
+      the end of the step before it) plus the shortest of its bucket's
+      last ``_STEPS_KEPT`` service times; ``lead`` is what the host has
+      lately needed between closing a batch and standing it on the device
+      (``_lead_ns``).  A bucket with no step on record is not waited for:
+      the window's end closes the batch, as does the batch ahead ending
+      early.
     * **Two or more in flight**: a batch short of the top bucket stays
       open.  Dispatched it would wait on the device's FIFO for both; held
       here it costs its members nothing and grows.  One running and one
@@ -259,9 +273,10 @@ class _DynamicBatcher:
 
     A batch forms once its in-flight permit is held, so whatever arrives
     while every permit is taken joins the batch that is forming and not
-    the one after.  While two batches are ahead the forming batch leaves
-    requests in the queue (still ordered by tier, still preemptible)
-    until it can close with them.
+    the one after.  While it is held behind two batches, or behind one
+    that outlasts the lead, the forming batch leaves requests in the
+    queue (still ordered by tier, still preemptible) until it can close
+    with them.
 
     Queue items are ``(inputs, params, fut, enqueue_ns, trace,
     deadline_ns, (tenant, tier))``; an item whose deadline already passed
@@ -284,12 +299,23 @@ class _DynamicBatcher:
     # autoscaler moves the live value per model through ``set_instances``
     # (server/fleet.py).
     MAX_INFLIGHT = 4
+    # Steps the one-ahead rule reads its two times from: a bucket's
+    # service time is the shortest of its last ones (one stalled step must
+    # not teach the batcher to wait), the host's close-to-device time the
+    # longest of the model's last ones (a step that compiled is forgotten
+    # after as many).
+    _STEPS_KEPT = 4
+    # ``lead`` over that close-to-device time: the pump's timer, the
+    # executor's pick-up and the dispatch call each run late by their own
+    # length now and then.  Too long a lead closes a batch as the window's
+    # end alone would; too short a one idles the chip.
+    _LEAD_FACTOR = 4
 
     def __init__(self, core: "InferenceCore", model: Model):
         self._core = core
         self._model = model
         dbcfg = model.config.dynamic_batching
-        self._max_delay_s = dbcfg.max_queue_delay_microseconds / 1e6
+        self._max_delay_ns = dbcfg.max_queue_delay_microseconds * 1000
         self._buckets = sorted(dbcfg.preferred_batch_size) or []
         self._max_bs = model.config.max_batch_size
         self._queue: TieredQueue = TieredQueue(
@@ -309,7 +335,16 @@ class _DynamicBatcher:
         # and never touches the queue — concurrency just tapers down as
         # running batches finish
         self._shrink_debt = 0
-        self._batch_tasks: set = set()
+        # batches in flight, in dispatch order: task -> (bucket, when the
+        # pump dispatched it, ns)
+        self._batch_tasks: Dict[asyncio.Task, Tuple[int, int]] = {}
+        # what the finished steps taught (``_observe``): per bucket the
+        # service times of the last steps, when the last one's outputs
+        # were on the host, and the last steps' close-to-device times
+        self._service_ns: Dict[int, collections.deque] = {}
+        self._last_end_ns = 0
+        self._host_ns: collections.deque = collections.deque(
+            maxlen=self._STEPS_KEPT)
         # what the pump holds outside the queue: the batch that is forming,
         # and the requests dequeued for it that lead the next one instead
         # (a batch closed at a bucket leaves its tail here; so does a
@@ -395,24 +430,67 @@ class _DynamicBatcher:
                 best, best_rows, best_pad = k, rows, bucket - rows
         return best, best_rows
 
-    async def _form(self) -> Tuple[list, int]:
+    def _observe(self, step: StepRecord) -> None:
+        """What a finished step teaches the one-ahead rule.  Its service
+        time runs from when it could begin on the device (``t_called``, or
+        the end of the step ahead of it where that is later) until its
+        outputs were on the host; its close-to-device time from the
+        assembly's start until ``model.execute`` returned."""
+        if step.ok:
+            self._service_ns.setdefault(step.bucket, collections.deque(
+                maxlen=self._STEPS_KEPT)).append(
+                    step.t_on_host - max(step.t_called, self._last_end_ns))
+            self._host_ns.append(step.t_returned - step.t_assembly)
+        self._last_end_ns = max(self._last_end_ns,
+                                step.t_on_host or step.t_done)
+
+    def _lead_ns(self) -> int:
+        """How long before the batch ahead ends a held batch has to close
+        to stand on the device by then: ``_LEAD_FACTOR`` times the longest
+        close-to-device time lately seen, and never less than the queue
+        delay.  A model whose ``execute`` is the step itself (host-placed:
+        its batches run side by side, not in a FIFO) has a lead of four
+        steps and is never held."""
+        return max(self._max_delay_ns,
+                   self._LEAD_FACTOR * max(self._host_ns, default=0))
+
+    def _hold_until_ns(self) -> int:
+        """With one batch in flight: when it is expected to have ``lead``
+        left to run (``time.monotonic_ns()``); 0 where its bucket has no
+        step on record."""
+        (bucket, dispatched_ns), = self._batch_tasks.values()
+        seen = self._service_ns.get(bucket)
+        if not seen:
+            return 0
+        return (max(dispatched_ns, self._last_end_ns) + min(seen)
+                - self._lead_ns())
+
+    async def _form(self) -> Tuple[list, int, int]:
         """Collect the next batch (permit held) and close it by the class
-        docstring's rules.  Returns its requests and the rows carried over
-        to the next batch by a close at a bucket; no requests when every
-        one taken had expired and the queue is empty."""
+        docstring's rules.  Returns its requests, the rows carried over to
+        the next batch by a close at a bucket, and how long past its
+        window's end it stayed open for the one batch ahead (ns); no
+        requests when every one taken had expired and the queue is
+        empty."""
         pending, carry, queue = self._pending, self._carry, self._queue
         # rows at which the batch is full and goes whatever is ahead
         cap = min(self._buckets[-1], self._max_bs) \
             if self._buckets else self._max_bs
         total = 0
         overflowed = False
+        # when the window's end alone would have closed the batch, once
+        # the one batch ahead has kept it open beyond that
+        held_since = 0
         while True:
             self._wake.clear()
             ahead = len(self._batch_tasks)
+            now = time.monotonic_ns()
+            hold_until = self._hold_until_ns() if ahead == 1 else 0
             # carried requests first (they left the queue in order); from
-            # the queue everything, or under two batches nothing until it
-            # holds enough to fill the batch (a request has a row or more)
-            held_back = ahead >= 2 and \
+            # the queue everything, or while the batch is held nothing
+            # until it holds enough to fill the batch (a request has a row
+            # or more)
+            held_back = (ahead >= 2 or now < hold_until) and \
                 total + len(carry) + queue.qsize() < cap
             while total < cap and (
                     carry or not (held_back or queue.empty())):
@@ -435,9 +513,11 @@ class _DynamicBatcher:
                 await self._wake.wait()
                 continue
             if not pending:
-                return [], 0
-            timeout = (pending[0][3] / 1e9 + self._max_delay_s
-                       - time.monotonic())
+                return [], 0, 0
+            window_end = pending[0][3] + self._max_delay_ns
+            if not held_since and hold_until > max(window_end, now):
+                held_since = max(window_end, now)
+            timeout = (max(window_end, hold_until) - now) / 1e9
             if timeout <= 0:
                 break
             try:
@@ -453,7 +533,9 @@ class _DynamicBatcher:
                 carry[:0] = batch[keep:]
                 del batch[keep:]
         pending.clear()
-        return batch, carried
+        held_ns = max(0, time.monotonic_ns() - held_since) \
+            if held_since else 0
+        return batch, carried, held_ns
 
     def _batch_done(self, task) -> None:
         if self._shrink_debt > 0:
@@ -463,7 +545,7 @@ class _DynamicBatcher:
             self._shrink_debt -= 1
         else:
             self._inflight.release()
-        self._batch_tasks.discard(task)
+        self._batch_tasks.pop(task, None)
         self._wake.set()
 
     async def _run(self) -> None:
@@ -472,13 +554,15 @@ class _DynamicBatcher:
                 if not self._carry:
                     self._carry.append(await self._queue.get())
                 await self._inflight.acquire()
-                batch, carried = await self._form()
+                batch, carried, held_ns = await self._form()
                 if not batch:
                     self._inflight.release()  # whatever it took had expired
                     continue
+                rows = sum(_batch_count(item[0]) for item in batch)
                 task = asyncio.get_running_loop().create_task(
-                    self._execute_batch(batch, carried))
-                self._batch_tasks.add(task)
+                    self._execute_batch(batch, carried, held_ns))
+                self._batch_tasks[task] = (
+                    self._bucket_for(rows) or rows, time.monotonic_ns())
                 task.add_done_callback(self._batch_done)
         except asyncio.CancelledError:
             # shutdown mid-batch: fail whatever we were holding
@@ -490,7 +574,8 @@ class _DynamicBatcher:
             self._carry.clear()
             raise
 
-    async def _execute_batch(self, pending, carried: int = 0) -> None:
+    async def _execute_batch(self, pending, carried: int = 0,
+                             held_ns: int = 0) -> None:
         # Requests with different parameters must not share an execution —
         # the model sees one parameters dict per execute (reference dynamic
         # batching merges only parameter-compatible requests).
@@ -498,13 +583,15 @@ class _DynamicBatcher:
         for item in pending:
             key = tuple(sorted((k, repr(v)) for k, v in item[1].items()))
             groups.setdefault(key, []).append(item)
-        # ``carried`` (rows a close at a bucket left for the next batch) is
+        # ``carried`` (rows a close at a bucket left for the next batch) and
+        # ``held_ns`` (how long the batch ahead kept this one open) are
         # counted once, with the first group's execution
         await asyncio.gather(
-            *(self._execute_group(g, 0 if k else carried)
+            *(self._execute_group(g, *((0, 0) if k else (carried, held_ns)))
               for k, g in enumerate(groups.values())))
 
-    async def _execute_group(self, pending, carried: int = 0) -> None:
+    async def _execute_group(self, pending, carried: int = 0,
+                             held_ns: int = 0) -> None:
         # last deadline gate before compute: a member that expired between
         # dequeue and its batch forming must not ride the execution
         pending = [p for p in pending if not self._drop_if_expired(p)]
@@ -521,7 +608,8 @@ class _DynamicBatcher:
             rows=total, bucket=padded,
             members=[StepMember(count, p[6][0], p[4], p[3])
                      for p, count in zip(pending, counts)],
-            carried=carried, t_assembly=time.monotonic_ns(),
+            carried=carried, held_ns=held_ns,
+            t_assembly=time.monotonic_ns(),
             queue_depth=self._queue.qsize())
         try:
             merged = {}
@@ -558,6 +646,8 @@ class _DynamicBatcher:
                 fut = item[2]
                 if not fut.done():
                     fut.set_exception(e)
+        finally:
+            self._observe(step)
 
 
 def _model_cache_ttl(model: Model) -> Optional[float]:

@@ -173,6 +173,8 @@ class ModelStats:
     bucket_rows: int = 0        # rows executed, pad rows included
     batch_carry_count: int = 0  # executions the batcher closed at a bucket,
     batch_carry_rows: int = 0   # and the rows it left for the next batch
+    batch_hold_count: int = 0   # executions whose close waited past the
+    batch_hold_ns: int = 0      # window's end for the batch ahead; how long
     pause_count: int = 0        # collector / late-loop pauses that held
     pause_ns: int = 0           # requests of this model
     # counted on the device and read back with the answer; 0 for a model
@@ -222,6 +224,9 @@ class ModelStats:
             if step.carried:
                 self.batch_carry_count += 1
                 self.batch_carry_rows += step.carried
+            if step.held_ns:
+                self.batch_hold_count += 1
+                self.batch_hold_ns += step.held_ns
 
     def record_answered(self, rows: int, queue_ns: int, compute_ns: int,
                         ok: bool) -> None:
@@ -334,6 +339,8 @@ class ModelStats:
             "bucket_rows": {"count": self.bucket_rows, "ns": 0},
             "batch_carry": {"count": self.batch_carry_count, "ns": 0},
             "batch_carry_rows": {"count": self.batch_carry_rows, "ns": 0},
+            "batch_hold": {"count": self.batch_hold_count,
+                           "ns": self.batch_hold_ns},
             "pause": {"count": self.pause_count, "ns": self.pause_ns},
             "expert_rows": {"count": self.expert_rows, "ns": 0},
             "expert_tokens": {"count": self.expert_tokens, "ns": 0},

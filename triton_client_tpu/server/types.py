@@ -179,6 +179,8 @@ class StepRecord:
     bucket: int            # rows run, pad rows included
     members: Sequence[StepMember] = ()
     carried: int = 0       # rows the batcher closed the batch without
+    held_ns: int = 0       # how long past its window's end the batcher kept
+    #                        the batch open for the one batch ahead
     queue_depth: int = 0   # requests left queued as the batch formed
     t_assembly: int = 0    # the batch's concat + pad began,
     t_assembled: int = 0   # and ended; a lone request: both when it ran
