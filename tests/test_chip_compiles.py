@@ -1,0 +1,71 @@
+"""Programs of the served path compiled at their real size for a TPU v5e that
+is described, not attached (the chip's compiler is installed here): what it
+refuses, or how much memory it plans, costs no chip time.  Nothing runs, so
+nothing here is a timing.
+
+Keep every such test in this one file: the worker that is given it loads the
+TPU's library, and only one process may (the topology is described inside a
+fixture, never while a module is imported).
+"""
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps it from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described device is written to the persistent cache
+    and cannot be read back without a chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def test_the_looped_generation_holds_one_cache_of_temporaries(
+        one_chip, no_compile_cache, monkeypatch):
+    """``looped.generate`` at Ouro-2.6B's size for 16 sequences: 5.34 GB of
+    arguments, and temporaries of one cache (3.62 GB) and the q/k/v matrices
+    re-laid once (1.2 GB).  Without the layout constraint on the cache's
+    writes the compiler re-lays the whole cache between the prefill and the
+    decode loop: 8.25 GB."""
+    import functools
+
+    import triton_client_tpu.ops as ops
+    from triton_client_tpu.models import looped
+
+    # the kernel itself, though the default backend here is the CPU
+    monkeypatch.setattr(ops, "flash_attention", functools.partial(
+        ops.flash_attention, force=True))
+    cfg = looped.OURO_2_6B
+    on_chip = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
+        s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        on_chip, jax.eval_shape(lambda: looped.init_params(cfg)))
+    tokens = on_chip(jax.ShapeDtypeStruct((16, cfg.seq_len), jnp.int32))
+    compiled = jax.jit(lambda p, t: looped.generate(p, t, cfg)).lower(
+        params, tokens).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(5.336e9, rel=1e-3)
+    cache = 2 * 4 * 48 * 144 * 16 * 16 * 128 * 2
+    assert cache == 3_623_878_656
+    assert cache < memory.temp_size_in_bytes < cache + 1.5e9
+    assert "tpu_custom_call" in compiled.as_text()  # the row kernel is there

@@ -44,7 +44,9 @@ EXTENSION = ("request",) + PER_ROW + (
     # an expert layer's routing, counted on the device (test_latent_moe.py)
     "expert_rows", "expert_tokens", "expert_rows_busiest",
     # generation by diffusion over blocks (test_block_diffusion.py)
-    "denoise_passes", "denoise_tokens", "experts_touched")
+    "denoise_passes", "denoise_tokens", "experts_touched",
+    # a stack run several times over one set of weights (test_looped.py)
+    "loop_steps", "loop_tokens")
 
 
 def _echo(sleep_s):

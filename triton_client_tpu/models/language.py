@@ -314,7 +314,7 @@ def make_longctx_tpu() -> JaxModel:
 class _LazyBlock:
     """``_LazyTransformer``'s lazy first-request init for the blocks that
     hold their weights in bfloat16 (``module``: models/latent_moe.py,
-    models/block_diffusion.py): mesh from ``tr.serve_mesh``, weights drawn
+    models/block_diffusion.py, models/looped.py): mesh from ``tr.serve_mesh``, weights drawn
     on the device leaf by leaf by ``module.init_params``, one jitted
     ``step(params, tokens, cfg)``.  Nothing is imported or allocated before
     the first call."""
@@ -337,9 +337,9 @@ class _LazyBlock:
             mesh = tr.serve_mesh(self.cfg, model_name=self._model_name)
             if mesh.size != 1:
                 raise ValueError(
-                    f"{self._model_name}: the expert layer computes the "
-                    "experts the configuration says it holds and has no "
-                    f"exchange between chips yet; the serve mesh has "
+                    f"{self._model_name}: the block has no exchange between "
+                    "chips yet (an expert layer computes the experts the "
+                    "configuration says it holds); the serve mesh has "
                     f"{mesh.size} devices")
             quant = tr.resolve_quant(self._model_name)
             with jax.default_device(mesh.devices.flat[0]):
@@ -453,6 +453,45 @@ def make_sdar_30b_a3b(cfg=None) -> JaxModel:
                    for name, array in out["counters"].items()}}
 
     return _counting_model(config, fn, tokens_per_row)
+
+
+def make_ouro_2_6b(cfg=None) -> JaxModel:
+    """Ouro-2.6B's block, the whole model (``looped.OURO_2_6B``; a test
+    passes a tiny ``cfg``): INT32 INPUT_IDS [P] → INT32 TOKENS [G] (greedy),
+    FP32 LOGITS [2, vocabulary] (the logits that chose the first new token,
+    from the prefill's last position, and those that chose the last, which
+    have read every cached position of every loop step) and FP32 EXIT_PDF
+    [2, loop steps] (the exit gate's probabilities at those two positions:
+    at the published threshold of 1 the gate does not move the logits, so
+    it is returned).  One request is one prompt, answered whole by ``G``
+    tokens: a completion that does not stream."""
+    if cfg is None:
+        from .looped import OURO_2_6B as cfg
+    from .looped import flops_per_inference
+
+    G = cfg.new_tokens
+    config = make_config(
+        "ouro_2_6b",
+        inputs=[("INPUT_IDS", "INT32", [cfg.seq_len])],
+        outputs=[("TOKENS", "INT32", [G]),
+                 ("LOGITS", "FP32", [2, cfg.vocab_size]),
+                 ("EXIT_PDF", "FP32", [2, cfg.total_ut_steps])],
+        max_batch_size=16,
+        preferred_batch_sizes=[8, 16],
+        max_queue_delay_us=2000,
+        instance_kind="KIND_TPU",
+        parameters={"flops_per_inference": str(flops_per_inference(cfg))},
+    )
+    run = _LazyBlock(cfg, "ouro_2_6b", "looped", "generate")
+
+    def fn(INPUT_IDS):
+        out = run(INPUT_IDS)
+        return {"TOKENS": out["tokens"], "LOGITS": out["logits"],
+                "EXIT_PDF": out["exit_pdf"],
+                **{DEVICE_COUNTER + name: array
+                   for name, array in out["counters"].items()}}
+
+    return _counting_model(config, fn, cfg.seq_len + G - 1)
 
 
 # Mixture-of-experts scorer: serves the flagship stack's MoE FFN path

@@ -142,6 +142,8 @@ _LEAF_KEYS = {
     "ws_gate": 15, "ws_up": 16, "ws_down": 17, "embed": 18, "head": 19,
     # models/block_diffusion.py's attention (grouped-query heads)
     "w_q": 20, "w_k": 21, "w_v": 22,
+    # models/looped.py's exit gate
+    "exit_gate": 23, "exit_gate_bias": 24,
 }
 
 #: the matrices ``TRITON_TPU_QUANT=int8`` stores as int8 (MLA and experts),

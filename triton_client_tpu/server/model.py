@@ -188,6 +188,9 @@ class ModelStats:
     denoise_passes: int = 0       # passes x sequences, commit passes too
     denoise_tokens: int = 0       # tokens committed
     experts_touched: int = 0      # per pass and layer, experts with a row
+    # a stack run several times over one set of weights (models/looped.py):
+    loop_steps: int = 0           # loop steps tokens took before they left
+    loop_tokens: int = 0          # tokens through the stack
     lock: threading.Lock = field(default_factory=threading.Lock)
     # steps whose counters are still on the device: ({name: array with a
     # leading axis of batch rows}, real rows, tokens a row)
@@ -315,7 +318,8 @@ class ModelStats:
                 counts.shape[0] * tokens_per_row * counts.shape[1]
             self.expert_rows_busiest += int(
                 counts.sum(axis=0).max(axis=-1).sum())
-        elif name in ("denoise_passes", "denoise_tokens"):
+        elif name in ("denoise_passes", "denoise_tokens", "loop_steps",
+                      "loop_tokens"):
             # a count a row [rows]
             setattr(self, name, getattr(self, name) + int(counts.sum()))
         elif name == "experts_touched":
@@ -349,6 +353,8 @@ class ModelStats:
             "denoise_passes": {"count": self.denoise_passes, "ns": 0},
             "denoise_tokens": {"count": self.denoise_tokens, "ns": 0},
             "experts_touched": {"count": self.experts_touched, "ns": 0},
+            "loop_steps": {"count": self.loop_steps, "ns": 0},
+            "loop_tokens": {"count": self.loop_tokens, "ns": 0},
         }
 
 
