@@ -112,26 +112,28 @@ def test_the_whole_trajectory_is_the_references(params, tokens, monkeypatch,
     np.testing.assert_array_equal(counters["denoise_tokens"], [G] * 3)
     blocks = G // B
     if threshold is None:
-        # 4 passes and the commit pass a block: 1.25 passes a token
+        # 4 passes a block and none for the cache alone: 1.0 passes a token
         np.testing.assert_array_equal(counters["denoise_passes"],
-                                      [5 * blocks] * 3)
+                                      [4 * blocks] * 3)
         for block in got["commit_pass"].reshape(-1, B):
             assert sorted(block) == [0, 1, 2, 3]
     else:
         # a batch runs as long as its slowest sequence: no fewer passes than
         # the reference's slowest prompt alone, fewer than the static rule
-        assert passes + blocks <= counters["denoise_passes"][0] < 5 * blocks
-    # every pair of every pass is counted: prefill and passes together
+        assert passes <= counters["denoise_passes"][0] < 4 * blocks
+    # every pair of every pass is counted, prefill and passes together: the
+    # first pass of every block but block 0 carries the block before it
     rows_a_token = TINY.num_experts_per_tok * TINY.num_hidden_layers
     np.testing.assert_array_equal(
         counters["expert_rows"].sum((1, 2)),
-        (P + B * counters["denoise_passes"]) * rows_a_token)
+        (P + B * (counters["denoise_passes"] + blocks - 1)) * rows_a_token)
 
 
 def test_passes_through_the_cache_are_the_full_forward(params, tokens):
     """Prefill, then a block through the cache: the logits of the block's
     rows equal the full forward's over prompt and block under the block
-    mask; after the commit pass so do the next block's."""
+    mask; with the block's keys written to the cache so do the next
+    block's."""
     p32 = _f32(params)
     rng = np.random.default_rng(1)
     blocks = rng.integers(0, TINY.vocab_size, (3, 2 * B)).astype(np.int32)
@@ -161,6 +163,97 @@ def test_passes_through_the_cache_are_the_full_forward(params, tokens):
             [tokens, blocks[:, :B], (blocks[:, B:] + 1) % 512], axis=1))
     np.testing.assert_array_equal(np.asarray(other[:, :P + B]),
                                   np.asarray(full[:, :P + B]))
+
+
+@pytest.fixture(scope="module")
+def two_blocks(params, tokens):
+    """In float32, behind one prefill: ``(first, lone, joint)``, a pass over
+    a block of final tokens alone, a pass over the next block of ids
+    against the cache that holds the first's keys, and one pass over
+    both, each as ``(x, (k, v), rows, routes)``; ``joint(ids)`` runs the
+    joint pass with other ids in the second block."""
+    p32 = _f32(params)
+    rng = np.random.default_rng(2)
+    blocks = jnp.asarray(
+        rng.integers(0, TINY.vocab_size, (3, 2 * B)).astype(np.int32))
+    cache, _, _ = jax.jit(lambda p, t: bd.prefill(p, t, TINY))(
+        p32, jnp.asarray(tokens))
+    run = jax.jit(lambda c, t, at: bd.block_pass(p32, c, t, at, TINY))
+    first = run(cache, blocks[:, :B], P)
+    written = tuple(jax.lax.dynamic_update_slice_in_dim(c, new, P, 3)
+                    for c, new in zip(cache, first[1]))
+    lone = run(written, blocks[:, B:], P + B)
+    joint = lambda ids: run(  # noqa: E731
+        cache, jnp.concatenate([blocks[:, :B], ids], axis=1), P)
+    return p32, blocks, first, lone, joint
+
+
+@pytest.mark.parametrize("what", ["keys", "logits", "counts", "one_way"])
+def test_a_block_riding_the_next_blocks_pass_is_a_pass_of_its_own(two_blocks,
+                                                                 what):
+    """``[block n final | block n+1]`` as one pass of ``2B`` rows: block
+    ``n`` gets the keys and values a lone pass gives it, block ``n+1`` the
+    logits a lone pass gives against the cache so written; every row's
+    pairs are counted; block ``n``'s half cannot see block ``n+1``."""
+    p32, blocks, first, lone, joint = two_blocks
+    x, (k, v), rows, routes = joint(blocks[:, B:])
+    if what == "keys":
+        assert k.shape == v.shape == (
+            TINY.num_hidden_layers, 3, TINY.num_key_value_heads, 2 * B,
+            TINY.head_dim)
+        for got, want in zip((k, v), first[1]):
+            assert _rel_l2(got[..., :B, :], want) < 1e-6
+    elif what == "logits":
+        assert _rel_l2(bd._head(p32, x[:, B:], TINY),
+                       bd._head(p32, lone[0], TINY)) < 1e-5
+        np.testing.assert_array_equal(np.asarray(routes[:, B:]),
+                                      np.asarray(lone[3]))
+    elif what == "counts":
+        np.testing.assert_array_equal(np.asarray(rows),
+                                      np.asarray(first[2] + lone[2]))
+        assert int(rows.sum()) == 3 * 2 * B * (
+            TINY.num_experts_per_tok * TINY.num_hidden_layers)
+    else:
+        x2, (k2, v2), _, routes2 = joint((blocks[:, B:] + 1) % 512)
+        for got, want in ((x2[:, :B], x[:, :B]),
+                          (k2[..., :B, :], k[..., :B, :]),
+                          (v2[..., :B, :], v[..., :B, :]),
+                          (routes2[:, :B], routes[:, :B])):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert not np.array_equal(np.asarray(x2[:, B:]), np.asarray(x[:, B:]))
+
+
+def test_the_head_runs_over_the_open_block_alone(params, tokens):
+    """No pass puts the riding rows through the head: the matrix
+    ``[D,V]`` meets ``[b,B,D]`` in every instance of the pass, never
+    ``[b,2B,D]``; and the first pass of a later block is of ``2B``
+    rows."""
+    text = str(jax.make_jaxpr(lambda p, t: bd.generate(p, t, TINY))(
+        params, jnp.asarray(tokens)))
+    V, D = TINY.vocab_size, TINY.hidden_size
+    assert f"f32[3,{B},{V}]" in text
+    assert f"[3,{2 * B},{V}]" not in text
+    assert f"bf16[3,{2 * B},{D}]" in text      # the joint pass is there
+
+
+@pytest.mark.parametrize("blocks", [1, 2])
+def test_no_pass_follows_the_last_block(params, tokens, blocks):
+    """A generation of one block runs its denoising passes and nothing
+    else; of two, the second's first pass carries the first and nothing
+    carries the second.  The answer's first block is the same either way:
+    it never saw what came after."""
+    cfg = dataclasses.replace(TINY, new_tokens=blocks * B)
+    got = _generate(params, tokens, cfg)
+    T = TINY.denoising_steps
+    np.testing.assert_array_equal(got["counters"]["denoise_passes"],
+                                  [T * blocks] * 3)
+    np.testing.assert_array_equal(
+        got["counters"]["expert_rows"].sum((1, 2)),
+        [(P + B * (T * blocks + blocks - 1))
+         * TINY.num_experts_per_tok * TINY.num_hidden_layers] * 3)
+    whole = _generate(params, tokens)
+    np.testing.assert_array_equal(got["tokens"][:, :B], whole["tokens"][:, :B])
+    np.testing.assert_array_equal(got["logits"][:, 0], whole["logits"][:, 0])
 
 
 def test_replay_on_the_programs_own_answer(params, tokens):
@@ -309,15 +402,36 @@ def test_the_factory_serves_the_generation_and_its_counters(server, params,
     assert result.as_numpy("DEVICE_COUNTER.denoise_passes") is None
     stats = server.core.statistics("sdar_30b_a3b")[0]["inference_stats"]
     blocks = G // B
-    assert stats["denoise_passes"]["count"] == 3 * 5 * blocks
+    assert stats["denoise_passes"]["count"] == 3 * 4 * blocks
     assert stats["denoise_tokens"]["count"] == 3 * G
-    tokens_a_row = P + 5 * G
+    # a token passes the experts in the prefill or in its block's 4 passes
+    # and once more riding the next block's first, but for the last block's
+    tokens_a_row = P + 5 * G - B
     assert stats["expert_tokens"]["count"] == 3 * tokens_a_row * 2
     assert stats["expert_rows"]["count"] == 3 * tokens_a_row * 2 * 2
     # the padded rows' experts are not among those touched
     assert stats["experts_touched"]["count"] == int(
         want["counters"]["experts_touched"][2])
-    assert 0 < stats["experts_touched"]["count"] <= 5 * blocks * 2 * 8
+    assert 0 < stats["experts_touched"]["count"] <= 4 * blocks * 2 * 8
+
+
+def test_the_cost_analysis_reads_the_program_that_ran(tokens):
+    """A signature's cost comes from the step's own jitted program, lowered
+    where it was traced (``_LazyBlock.lower``), and is what tracing the
+    callable afresh with its weights as arguments gives."""
+    from triton_client_tpu.server.costs import analyze_jax_callable
+
+    model = language.make_sdar_30b_a3b(TINY)
+    ids = np.concatenate([tokens, tokens, tokens[:2]])
+    model.execute({"INPUT_IDS": ids}, {})
+    cost = model.analyze_cost({"INPUT_IDS": ids}, {})
+    fn = model._fn
+    assert cost is not None and hasattr(fn, "lower")
+    del fn.lower
+    afresh = analyze_jax_callable(fn, INPUT_IDS=ids)
+    assert (cost.flops, cost.bytes_accessed) == (afresh.flops,
+                                                 afresh.bytes_accessed)
+    assert cost.flops > 0
 
 
 def test_a_mesh_of_two_is_refused(monkeypatch):

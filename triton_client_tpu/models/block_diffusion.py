@@ -21,9 +21,18 @@ The third block of the zoo.  What it has that the other two have not:
   themselves in both directions; each pass commits the most confident of the
   positions still masked (and every one over ``confidence_threshold``, when
   that is set).  **The cache is written once a block, after its last token
-  is committed, by one more pass (the commit pass)**, since every position's
-  keys depend on its peers' final tokens; no pass reads a stale key.  The
-  output at position ``i`` predicts the token at ``i`` (no shift).
+  is committed**, since every position's keys depend on its peers' final
+  tokens; no pass reads a stale key.  **No pass is run for that alone: the
+  block's final tokens ride the next block's first pass**, ``2B`` rows a
+  sequence under the mask that is causal over blocks.  The riding rows see
+  the cache and themselves, so their keys and values are the ones a pass
+  of their own would give; the open block's rows see them as they will lie
+  in the cache, where they are written before the second pass reads it.  A
+  pass is bound by the experts' weights it reads, not by its rows, so the
+  four more rows cost the experts they newly touch and not a pass.  **The
+  last block's keys are never computed**: ``generate`` returns no cache,
+  and no output depends on them.  The output at position ``i`` predicts
+  the token at ``i`` (no shift).
 
 The whole generation of a batch is one program (``generate``).  It also
 returns what the device counted on the way, a row of the batch each, for
@@ -331,32 +340,41 @@ def prefill(params, tokens, cfg: BlockDiffusionConfig,
 
 @jax.named_scope("pass")
 def block_pass(params, cache, tokens, start, cfg: BlockDiffusionConfig):
-    """The block ``tokens [b,B]`` at positions ``start ..`` through every
-    layer, attending to the cache's keys before ``start`` and to all ``B``
-    of itself -> ``(x [b,B,D] before the head, the block's (k, v)
-    [L,b,Hkv,B,dh], rows routed [b,L,E], the experts each row chose
-    [b,B,L,k])``.  The cache is read, never written."""
-    B = tokens.shape[1]
-    cos, sin = _rotary(cfg, start + jnp.arange(B))
+    """The rows ``tokens [b,R]`` of one block or of two adjacent ones (``R``
+    = ``B`` or ``2B``) at positions ``start ..`` through every layer,
+    attending to the cache's keys before ``start`` and among themselves
+    under the mask that is causal over blocks: a block sees all of itself,
+    the second block sees the first as well, the first never the second
+    -> ``(x [b,R,D] before the head, the rows' (k, v) [L,b,Hkv,R,dh], rows
+    routed [b,L,E], the experts each row chose [b,R,L,k])``.  The cache is
+    read, never written.  Of two blocks the first's keys and values are
+    what a pass over it alone gives, and the second's ``x`` what a pass
+    over it gives against a cache that holds them: ``generate`` runs a
+    block's final tokens so beside the next block's first pass."""
+    R, B = tokens.shape[1], cfg.block_length
+    cos, sin = _rotary(cfg, start + jnp.arange(R))
     group = cfg.num_attention_heads // cfg.num_key_value_heads
     scale = 1.0 / math.sqrt(cfg.head_dim)
+    block = jnp.arange(R) // B
+    among = block[:, None] >= block[None, :]
 
     def layer(blk, x, k_cache, v_cache):
         with jax.named_scope("attention"):
             q, k, v = _qkv(blk, x, cfg, cos, sin)
             with jax.named_scope("cache_attend"):
                 b = q.shape[0]
-                qg = q.reshape(b, -1, group, B, cfg.head_dim)
+                qg = q.reshape(b, -1, group, R, cfg.head_dim)
                 past = jnp.einsum("bgrqk,bgtk->bgrqt", qg, k_cache,
                                   preferred_element_type=jnp.float32)
                 seen = jnp.arange(k_cache.shape[2]) < start
                 own = jnp.einsum("bgrqk,bgtk->bgrqt", qg, k,
                                  preferred_element_type=jnp.float32)
                 s = jnp.concatenate(
-                    [jnp.where(seen, past, -1e30), own], axis=-1) * scale
+                    [jnp.where(seen, past, -1e30),
+                     jnp.where(among, own, -1e30)], axis=-1) * scale
                 p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-                o = (jnp.einsum("bgrqt,bgtk->bgrqk", p[..., :-B], v_cache)
-                     + jnp.einsum("bgrqt,bgtk->bgrqk", p[..., -B:], v))
+                o = (jnp.einsum("bgrqt,bgtk->bgrqk", p[..., :-R], v_cache)
+                     + jnp.einsum("bgrqt,bgtk->bgrqk", p[..., -R:], v))
                 o = o.reshape(q.shape)
             x = _out_proj(blk, x, o)
         x, rows, routes = _moe(blk, x, cfg)
@@ -386,10 +404,17 @@ def generate(params, tokens, cfg: BlockDiffusionConfig):
       that position chose in each layer of that pass (a reference that
       recomputes the row has to be told: where two experts lie closer than
       bfloat16's rounding the choice is not the reference's);
-    * ``counters``: ``expert_rows [b,L,E]`` (pairs on each expert, prefill
-      and passes together), ``denoise_passes [b]`` (passes run, the commit
-      passes among them), ``denoise_tokens [b]``, ``experts_touched [b]``
-      (over passes and layers; entry ``r`` counts the rows ``0 .. r``)."""
+    * ``counters``: ``expert_rows [b,L,E]`` (pairs on each expert, the
+      prefill's and every row's of every pass, the riding rows' too),
+      ``denoise_passes [b]`` (passes run: ``denoising_steps`` a block under
+      the static rule, none for the cache alone), ``denoise_tokens [b]``,
+      ``experts_touched [b]`` (over passes and layers; entry ``r`` counts
+      the rows ``0 .. r``).
+
+    Block 0 runs its passes alone (the prefill wrote the keys before it);
+    every later block's first pass carries the block before it
+    (``block_pass``) and writes that block's keys and values; after the
+    last block nothing runs."""
     b, P = tokens.shape
     B, T, G, V = (cfg.block_length, cfg.denoising_steps, cfg.new_tokens,
                   cfg.vocab_size)
@@ -399,70 +424,84 @@ def generate(params, tokens, cfg: BlockDiffusionConfig):
     none = jnp.zeros((b,), jnp.int32)
     no_routes = jnp.zeros(
         (b, cfg.num_hidden_layers, cfg.num_experts_per_tok), jnp.int32)
+    all_masked = {
+        "t": jnp.int32(0),
+        "tokens": jnp.full((b, B), cfg.mask_token_id, jnp.int32),
+        "masked": jnp.ones((b, B), bool),
+        "when": jnp.zeros((b, B), jnp.int32),
+        "committed": none,
+        "first_logits": jnp.zeros((b, V), jnp.float32),
+        "last_logits": jnp.zeros((b, V), jnp.float32),
+        "first_routes": no_routes, "last_routes": no_routes,
+        "rows": jnp.zeros_like(expert_rows),
+        "touched": none}
 
-    def one_block(n, state):
+    def masked_left(s):
+        return (s["t"] < T) & jnp.any(s["masked"])
+
+    def end_pass(s, x, rows, routes):
+        """The end of a pass: the head over the open block's ``x [b,B,D]``,
+        the commit rule, and the pass's ``rows`` among the counters."""
+        lg = _head(params, x, cfg)                              # [b,B,V]
+        with jax.named_scope("confidence"):
+            conf = jnp.max(jax.nn.softmax(lg, axis=-1), axis=-1)
+            best = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+        with jax.named_scope("commit"):
+            open_conf = jnp.where(s["masked"], conf, -jnp.inf)
+            _, top = lax.top_k(open_conf, B // T)
+            commit = jnp.any(top[..., None] == jnp.arange(B), axis=1)
+            if cfg.confidence_threshold is not None:
+                commit |= open_conf > cfg.confidence_threshold
+            commit &= s["masked"]
+            first = jnp.argmax(commit, axis=-1)     # lowest index
+            mine, my_routes = lg[row, first], routes[row, first]
+            # a sequence that has no mask left rides along with the
+            # batch: its last pass is the last at which it committed
+            moved = jnp.any(commit, axis=-1, keepdims=True)
+            return {
+                "t": s["t"] + 1,
+                "tokens": jnp.where(commit, best, s["tokens"]),
+                "masked": s["masked"] & ~commit,
+                "when": jnp.where(commit, s["t"], s["when"]),
+                "committed": s["committed"]
+                + jnp.sum(commit, axis=-1, dtype=jnp.int32),
+                "first_logits": jnp.where(s["t"] == 0, mine,
+                                          s["first_logits"]),
+                "last_logits": jnp.where(moved, mine, s["last_logits"]),
+                "first_routes": jnp.where(s["t"] == 0, my_routes,
+                                          s["first_routes"]),
+                "last_routes": jnp.where(moved[..., None], my_routes,
+                                         s["last_routes"]),
+                "rows": s["rows"] + rows,
+                "touched": s["touched"] + _touched(rows),
+            }
+
+    def one_block(n, state, rides=True):
         cache, out, logits, routes, counters = state
         start = P + n * B
-
-        def masked_left(s):
-            return (s["t"] < T) & jnp.any(s["masked"])
 
         def one_pass(s):
             x, _, rows, routes = block_pass(params, cache, s["tokens"],
                                             start, cfg)
-            lg = _head(params, x, cfg)                          # [b,B,V]
-            with jax.named_scope("confidence"):
-                conf = jnp.max(jax.nn.softmax(lg, axis=-1), axis=-1)
-                best = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-            with jax.named_scope("commit"):
-                open_conf = jnp.where(s["masked"], conf, -jnp.inf)
-                _, top = lax.top_k(open_conf, B // T)
-                commit = jnp.any(top[..., None] == jnp.arange(B), axis=1)
-                if cfg.confidence_threshold is not None:
-                    commit |= open_conf > cfg.confidence_threshold
-                commit &= s["masked"]
-                first = jnp.argmax(commit, axis=-1)     # lowest index
-                mine, my_routes = lg[row, first], routes[row, first]
-                # a sequence that has no mask left rides along with the
-                # batch: its last pass is the last at which it committed
-                moved = jnp.any(commit, axis=-1, keepdims=True)
-                return {
-                    "t": s["t"] + 1,
-                    "tokens": jnp.where(commit, best, s["tokens"]),
-                    "masked": s["masked"] & ~commit,
-                    "when": jnp.where(commit, s["t"], s["when"]),
-                    "committed": s["committed"]
-                    + jnp.sum(commit, axis=-1, dtype=jnp.int32),
-                    "first_logits": jnp.where(s["t"] == 0, mine,
-                                              s["first_logits"]),
-                    "last_logits": jnp.where(moved, mine, s["last_logits"]),
-                    "first_routes": jnp.where(s["t"] == 0, my_routes,
-                                              s["first_routes"]),
-                    "last_routes": jnp.where(moved[..., None], my_routes,
-                                             s["last_routes"]),
-                    "rows": s["rows"] + rows,
-                    "touched": s["touched"] + _touched(rows),
-                }
+            return end_pass(s, x, rows, routes)
 
         with jax.named_scope("denoise"):
-            s = lax.while_loop(masked_left, one_pass, {
-                "t": jnp.int32(0),
-                "tokens": jnp.full((b, B), cfg.mask_token_id, jnp.int32),
-                "masked": jnp.ones((b, B), bool),
-                "when": jnp.zeros((b, B), jnp.int32),
-                "committed": jnp.zeros((b,), jnp.int32),
-                "first_logits": jnp.zeros((b, V), jnp.float32),
-                "last_logits": jnp.zeros((b, V), jnp.float32),
-                "first_routes": no_routes, "last_routes": no_routes,
-                "rows": jnp.zeros_like(expert_rows),
-                "touched": jnp.zeros((b,), jnp.int32)})
-        with jax.named_scope("cache_commit"):
-            # the block once more, all tokens final: its keys and values
-            # are the ones the later blocks read
-            _, (k, v), rows, _ = block_pass(params, cache, s["tokens"],
-                                            start, cfg)
-            cache = tuple(lax.dynamic_update_slice_in_dim(c, new, start, 3)
-                          for c, new in zip(cache, (k, v)))
+            s = all_masked
+            if rides:
+                # pass 0: the block before, all tokens final, rides it; its
+                # keys and values are the ones this and the later blocks read
+                before = lax.dynamic_slice_in_dim(out["tokens"], (n - 1) * B,
+                                                  B, 1)
+                x, kv, rows, chose = block_pass(
+                    params, cache,
+                    jnp.concatenate([before, s["tokens"]], axis=1),
+                    start - B, cfg)
+                s = end_pass(s, x[:, B:], rows, chose[:, B:])
+                with jax.named_scope("cache_commit"):
+                    cache = tuple(lax.dynamic_update_slice_in_dim(
+                        c, new[..., :B, :], start - B, 3)
+                        for c, new in zip(cache, kv))
+            s = lax.while_loop(masked_left, one_pass, s)
         out = {"tokens": lax.dynamic_update_slice_in_dim(
                    out["tokens"], s["tokens"], n * B, 1),
                "commit_pass": lax.dynamic_update_slice_in_dim(
@@ -472,19 +511,20 @@ def generate(params, tokens, cfg: BlockDiffusionConfig):
         routes = (jnp.where(n == 0, s["first_routes"], routes[0]),
                   s["last_routes"])
         counters = {
-            "expert_rows": counters["expert_rows"] + s["rows"] + rows,
-            "denoise_passes": counters["denoise_passes"] + s["t"] + 1,
+            "expert_rows": counters["expert_rows"] + s["rows"],
+            "denoise_passes": counters["denoise_passes"] + s["t"],
             "denoise_tokens": counters["denoise_tokens"] + s["committed"],
-            "experts_touched": counters["experts_touched"] + s["touched"]
-            + _touched(rows)}
+            "experts_touched": counters["experts_touched"] + s["touched"]}
         return cache, out, logits, routes, counters
 
+    # block 0 stands before the loop: nothing rides its first pass
+    state = one_block(0, (
+        cache, {"tokens": zeros, "commit_pass": zeros},
+        (jnp.zeros((b, V), jnp.float32),) * 2, (no_routes,) * 2,
+        {"expert_rows": expert_rows, "denoise_passes": none,
+         "denoise_tokens": none, "experts_touched": none}), rides=False)
     _, out, logits, routes, counters = lax.fori_loop(
-        0, cfg.n_blocks, one_block,
-        (cache, {"tokens": zeros, "commit_pass": zeros},
-         (jnp.zeros((b, V), jnp.float32),) * 2, (no_routes,) * 2,
-         {"expert_rows": expert_rows, "denoise_passes": none,
-          "denoise_tokens": none, "experts_touched": none}))
+        1, cfg.n_blocks, one_block, state)
     return {**out, "logits": jnp.stack(logits, axis=1),
             "routes": jnp.stack(routes, axis=1), "counters": counters}
 
@@ -496,9 +536,13 @@ def generate(params, tokens, cfg: BlockDiffusionConfig):
 def flops_per_inference(cfg: BlockDiffusionConfig) -> float:
     """FLOPs one request needs: the prompt through every matrix a token
     passes (its 8 experts among them) with the block-causal half of the
-    scores, no head; then ``denoising_steps + 1`` passes a block, each the
-    block's rows through the layers against the keys so far, the
-    ``denoising_steps`` of them with the head.  No padding, norms or
+    scores, no head; then a block's rows ``denoising_steps + 1`` times
+    through the layers against the keys so far (the last time final,
+    beside the next block's first pass), ``denoising_steps`` of them with
+    the head.  The last block's rows are counted so too, though nothing
+    runs them the last time: 0.3% of the total, kept so that the count
+    stays the benchmark's yardstick to the digit
+    (``chipbench/flop_counts/sdar_30b_a3b.py``).  No padding, norms or
     rotary."""
     D, H, Hkv, dh = (cfg.hidden_size, cfg.num_attention_heads,
                      cfg.num_key_value_heads, cfg.head_dim)

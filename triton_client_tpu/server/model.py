@@ -185,7 +185,9 @@ class ModelStats:
     expert_rows_busiest: int = 0  # per layer and execution, the fullest
     #                               held expert's rows, summed
     # generation by diffusion over blocks (models/block_diffusion.py):
-    denoise_passes: int = 0       # passes x sequences, commit passes too
+    denoise_passes: int = 0       # passes x sequences (none is run for
+    #                               the cache alone: a block's keys ride
+    #                               the next block's first pass)
     denoise_tokens: int = 0       # tokens committed
     experts_touched: int = 0      # per pass and layer, experts with a row
     # a stack run several times over one set of weights (models/looped.py):
