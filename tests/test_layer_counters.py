@@ -39,8 +39,12 @@ V2 = ("success", "fail", "queue", "compute_input", "compute_infer",
       "compute_output")
 PER_ROW = ("queue_member", "batch_assembly", "executor_wait", "dispatch",
            "device_wait")
+# a formed step that found the chip out of the model's work, by cause: a
+# step, not a row (tests/test_dry_account.py)
+DRY = ("dry_no_request", "dry_window", "dry_late", "dry_host")
 EXTENSION = ("request",) + PER_ROW + (
-    "bucket_rows", "batch_carry", "batch_carry_rows", "batch_hold", "pause",
+    "bucket_rows", "batch_carry", "batch_carry_rows", "batch_hold") + DRY + (
+    "pause",
     # an expert layer's routing, counted on the device (test_latent_moe.py)
     "expert_rows", "expert_tokens", "expert_rows_busiest",
     # generation by diffusion over blocks (test_block_diffusion.py)
@@ -359,8 +363,8 @@ def test_both_frontends_report_a_profiler_that_did_not_start(
 
 def test_annotations_outside_a_profiler_session_write_and_raise_nothing(
         monkeypatch):
-    with profiler_mod.annotation("batcher.assemble", bucket=4, rows=3,
-                                 queue_depth=0):
+    with profiler_mod.annotation("step.record", step=7, bucket=4, rows=3,
+                                 t_called=1, now=2):
         pass
     # a process that never imported JAX is not made to
     monkeypatch.setattr(profiler_mod, "sys",
@@ -372,8 +376,10 @@ def test_annotations_outside_a_profiler_session_write_and_raise_nothing(
 
 
 def test_the_per_batch_spans_are_on_the_profilers_clock(server, tmp_path):
-    """Inside a profiler session the batcher's and the executor's sections
-    are host events of the trace, with their arguments."""
+    """Inside a profiler session the executor's sections are host events of
+    the trace, with their arguments, and the step's record beside them
+    carries every host point of the step and the clock pair that maps them
+    onto the trace's clock."""
     import jax
     from jax.profiler import ProfileData
 
@@ -392,18 +398,42 @@ def test_the_per_batch_spans_are_on_the_profilers_clock(server, tmp_path):
         # one line a host thread; the lines share the process's name
         for thread, line in enumerate(plane.lines):
             for event in line.events:
-                if event.name in ("batcher.assemble", "step.dispatch",
-                                  "step.device_wait", "host.gc"):
-                    seen[event.name] = ((plane.name, thread),
-                                        dict(event.stats))
-    assert set(seen) == {"batcher.assemble", "step.dispatch",
-                         "step.device_wait", "host.gc"}
-    assert seen["batcher.assemble"][1]["bucket"] == 4
-    assert seen["batcher.assemble"][1]["rows"] == 3
+                if event.name in ("batcher.assemble", "step.record",
+                                  "step.dispatch", "step.device_wait",
+                                  "host.gc"):
+                    seen[event.name] = (
+                        (plane.name, thread), dict(event.stats),
+                        (event.start_ns, event.start_ns + event.duration_ns))
+    assert set(seen) == {"step.record", "step.dispatch", "step.device_wait",
+                         "host.gc"}
+    record = seen["step.record"][1]
+    assert (record["model"], record["bucket"], record["rows"]) == \
+        ("batched", 4, 3)
     assert seen["step.dispatch"][1]["model"] == "batched"
-    # the executor's two sections share a thread; the batcher's is the loop's
-    assert seen["step.dispatch"][0] == seen["step.device_wait"][0]
-    assert seen["batcher.assemble"][0] != seen["step.dispatch"][0]
+    # the spans of one step share its identifier and the executor's thread
+    assert record["step"] == seen["step.dispatch"][1]["step"] \
+        == seen["step.device_wait"][1]["step"] > 0
+    assert seen["step.dispatch"][0] == seen["step.device_wait"][0] \
+        == seen["step.record"][0]
+    # every host point of the step, in the order it passed them
+    points = [record[k] for k in (
+        "first_enqueue", "t_assembly", "t_assembled", "t_submit", "t_exec",
+        "t_called", "t_returned", "t_on_host", "now")]
+    assert points == sorted(points) and points[0] > 0
+    assert record["t_window_end"] == record["first_enqueue"] + 50_000_000
+    # the clock pair (the event's own start beside ``now``) puts the
+    # record's t_called and t_returned inside the step's dispatch event.
+    # ``now`` is read after ``t_on_host``, which is read inside the
+    # device_wait event, and before the record event starts: the pair is
+    # off by no more than lies between those two on the trace itself
+    at = seen["step.record"][2][0]
+    offset = at - record["now"]
+    lo, hi = seen["step.dispatch"][2]
+    slack = at - seen["step.device_wait"][2][0]
+    assert slack >= 0
+    assert lo - slack <= record["t_called"] + offset \
+        <= record["t_returned"] + offset <= hi + slack
+    assert record["t_on_host"] + offset <= at
 
 
 def test_triton_top_names_the_longest_pause_on_an_outlier():

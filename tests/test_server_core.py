@@ -812,6 +812,11 @@ class TestStepIsBookedOnce:
             "infer_count": stats.infer_count,
             "fail_count": stats.fail_count,
             "dispatch_ns": stats.dispatch_ns,
+            "dry_steps": max(stats.dry_no_request_count,
+                             stats.dry_window_count, stats.dry_late_count,
+                             stats.dry_host_count),
+            "dry_ns": stats.dry_no_request_ns + stats.dry_window_ns
+            + stats.dry_late_ns + stats.dry_host_ns,
             "inferences": compute.get("inferences", 0),
             "compute_us": compute.get("compute_ms_total", 0.0) * 1e3,
             "tick_padded": sum(b["padded_total"] for b in
@@ -821,19 +826,32 @@ class TestStepIsBookedOnce:
 
     @pytest.mark.parametrize("case", [
         "direct_request", "batched_group_of_three_with_a_pad_row",
-        "ensemble_member_not_batchable", "execute_raises"])
+        "ensemble_member_not_batchable", "execute_raises",
+        "batched_after_an_earlier_step",
+        "batched_execute_raises_after_an_earlier_step"])
     def test_books_agree_about_one_step(self, case):
-        core, booked = self._core(fail=case == "execute_raises")
+        core, booked = self._core(fail="execute_raises" in case)
         target, ran, sent = {
             "direct_request": ("plain", "plain", [2]),
             "batched_group_of_three_with_a_pad_row":
                 ("batched", "batched", [1, 1, 1]),
             "ensemble_member_not_batchable": ("ens", "plain", [2]),
             "execute_raises": ("plain", "plain", [2]),
+            "batched_after_an_earlier_step": ("batched", "batched", [1, 2]),
+            "batched_execute_raises_after_an_earlier_step":
+                ("batched", "batched", [1, 2]),
         }[case]
-        before = self._books(core, ran)
+        earlier = case.endswith("after_an_earlier_step")
+        before = {}
 
         async def drive():
+            if earlier:
+                # the model's first step leaves the chip dry behind it
+                await asyncio.gather(
+                    core.infer(_request(target, np.ones((1, 4)))),
+                    return_exceptions=True)
+                booked.clear()
+            before.update(self._books(core, ran))
             return await asyncio.gather(
                 *(core.infer(_request(target, np.ones((rows, 4))))
                   for rows in sent), return_exceptions=True)
@@ -847,8 +865,14 @@ class TestStepIsBookedOnce:
             (ran, rows, len(sent))
         assert step.path == {"batched": "batch", "ens": "member"}.get(
             target, "direct")
-        if case == "execute_raises":
-            assert all(isinstance(a, InferError) for a in answers)
+        # only a formed step with steps before it can have found the chip dry
+        assert (step.t_dry > 0) == earlier
+        if "execute_raises" in case:
+            # a failed step is counted as failed and books nothing else,
+            # the dry time before it included
+            # (the batcher hands its members the model's own exception)
+            assert all(isinstance(a, RuntimeError if step.formed
+                                  else InferError) for a in answers)
             assert not step.ok and delta == {
                 **dict.fromkeys(delta, 0), "fail_count": rows}
             return
@@ -860,6 +884,10 @@ class TestStepIsBookedOnce:
         assert delta["tick_padded"] == (step.bucket if step.formed else 0)
         assert delta["infer_count"] == delta["inferences"] == rows
         assert delta["dispatch_ns"] == step.window_ns * rows
+        # the dry interval is booked whole, by the step, not by the row
+        assert delta["dry_steps"] == earlier
+        assert delta["dry_ns"] == sum(step.dry_ns) == (
+            step.t_called - step.t_dry if earlier else 0)
         assert delta["compute_us"] == pytest.approx(
             step.window_ns / 1e3, abs=1.0)
         # the members' shares sum to the window the collector recorded

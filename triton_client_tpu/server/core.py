@@ -343,6 +343,10 @@ class _DynamicBatcher:
         # were on the host, and the last steps' close-to-device times
         self._service_ns: Dict[int, collections.deque] = {}
         self._last_end_ns = 0
+        # the formed steps whose execution has not ended, oldest first:
+        # after a pause the read-backs' threads wake in any order, and a
+        # step observed before one called ahead of it found no chip dry
+        self._out: List[StepRecord] = []
         self._host_ns: collections.deque = collections.deque(
             maxlen=self._STEPS_KEPT)
         # what the pump holds outside the queue: the batch that is forming,
@@ -431,18 +435,27 @@ class _DynamicBatcher:
         return best, best_rows
 
     def _observe(self, step: StepRecord) -> None:
-        """What a finished step teaches the one-ahead rule.  Its service
-        time runs from when it could begin on the device (``t_called``, or
-        the end of the step ahead of it where that is later) until its
-        outputs were on the host; its close-to-device time from the
-        assembly's start until ``model.execute`` returned."""
+        """What a finished step teaches the one-ahead rule, and when the
+        chip ran dry before it (``InferenceCore._book`` calls this first,
+        on the event loop, once the step has ended).  Its service time runs
+        from when it could begin on the device (``t_called``, or the end of
+        the step ahead of it where that is later) until its outputs were on
+        the host; its close-to-device time from the assembly's start until
+        ``model.execute`` returned.  Where the steps ahead were all on the
+        host by ``t_called`` the step found the chip dry since then
+        (``t_dry``): only now is that known, a step ahead that still ran at
+        ``t_called`` having been observed since, or being out still
+        (``_out``)."""
+        ahead_end = self._last_end_ns
+        if ahead_end < step.t_called and not any(
+                0 < ahead.t_called < step.t_called for ahead in self._out):
+            step.t_dry = ahead_end  # 0: the model's first step
         if step.ok:
             self._service_ns.setdefault(step.bucket, collections.deque(
                 maxlen=self._STEPS_KEPT)).append(
-                    step.t_on_host - max(step.t_called, self._last_end_ns))
+                    step.t_on_host - max(step.t_called, ahead_end))
             self._host_ns.append(step.t_returned - step.t_assembly)
-        self._last_end_ns = max(self._last_end_ns,
-                                step.t_on_host or step.t_done)
+        self._last_end_ns = max(ahead_end, step.t_on_host or step.t_done)
 
     def _lead_ns(self) -> int:
         """How long before the batch ahead ends a held batch has to close
@@ -609,19 +622,19 @@ class _DynamicBatcher:
             members=[StepMember(count, p[6][0], p[4], p[3])
                      for p, count in zip(pending, counts)],
             carried=carried, held_ns=held_ns,
-            t_assembly=time.monotonic_ns(),
-            queue_depth=self._queue.qsize())
+            queue_depth=self._queue.qsize(), batcher=self,
+            t_window_end=pending[0][3] + self._max_delay_ns,
+            t_assembly=time.monotonic_ns())
+        self._out.append(step)
         try:
             merged = {}
-            with annotation("batcher.assemble", bucket=padded, rows=total,
-                            queue_depth=step.queue_depth):
-                for n in pending[0][0]:
-                    parts = [p[0][n] for p in pending]
-                    arr = np.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
-                    if padded > total:
-                        pad_widths = [(0, padded - total)] + [(0, 0)] * (arr.ndim - 1)
-                        arr = np.pad(arr, pad_widths)
-                    merged[n] = arr
+            for n in pending[0][0]:
+                parts = [p[0][n] for p in pending]
+                arr = np.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
+                if padded > total:
+                    pad_widths = [(0, padded - total)] + [(0, 0)] * (arr.ndim - 1)
+                    arr = np.pad(arr, pad_widths)
+                merged[n] = arr
             step.t_assembled = time.monotonic_ns()
             # keep_device=set(): every output resolves D2H on the executor
             # thread, not the event loop — a blocking np.asarray here would
@@ -647,7 +660,7 @@ class _DynamicBatcher:
                 if not fut.done():
                     fut.set_exception(e)
         finally:
-            self._observe(step)
+            self._out.remove(step)
 
 
 def _model_cache_ttl(model: Model) -> Optional[float]:
@@ -2153,9 +2166,12 @@ class InferenceCore:
                 # duty/MFU window
                 step.signature = sig
 
+        # the step's identifier on the profiler's clock: its spans share it
+        step.seq = model.step_seq = model.step_seq + 1
+
         def _exec():
             step.t_exec = time.monotonic_ns()
-            with annotation("step.dispatch", model=model.name,
+            with annotation("step.dispatch", model=model.name, step=step.seq,
                             bucket=step.bucket, rows=step.rows):
                 step.t_called = time.monotonic_ns()
                 # a padded step's parameters say how many rows are real, for
@@ -2177,7 +2193,8 @@ class InferenceCore:
             if keep_device is None:
                 return outputs
             with annotation("step.device_wait", model=model.name,
-                            bucket=step.bucket, rows=step.rows):
+                            step=step.seq, bucket=step.bucket,
+                            rows=step.rows):
                 drained = [n for n, v in outputs.items()
                            if n not in keep_device
                            and hasattr(v, "copy_to_host_async")]
@@ -2186,6 +2203,21 @@ class InferenceCore:
                 resolved = {n: (v if n in keep_device else np.asarray(v))
                             for n, v in outputs.items()}
                 step.t_on_host = time.monotonic_ns()
+            # the record's host points on the profiler's clock: a zero-length
+            # event whose own start beside ``now`` is the clock pair that
+            # maps every monotonic_ns point onto the trace's time line, the
+            # batcher's awaits (which can carry no span) among them
+            with annotation(
+                    "step.record", model=model.name, step=step.seq,
+                    bucket=step.bucket, rows=step.rows,
+                    first_enqueue=step.members[0].enqueue_ns
+                    if step.members else 0,
+                    t_window_end=step.t_window_end,
+                    t_assembly=step.t_assembly, t_assembled=step.t_assembled,
+                    t_submit=step.t_submit, t_exec=step.t_exec,
+                    t_called=step.t_called, t_returned=step.t_returned,
+                    t_on_host=step.t_on_host, now=time.monotonic_ns()):
+                pass
             step.d2h_count = len(drained)
             step.d2h_bytes = sum(resolved[n].nbytes for n in drained)
             return resolved
@@ -2213,14 +2245,19 @@ class InferenceCore:
             self._book(step)
 
     def _book(self, step: StepRecord) -> None:
-        """Write one step into every book that keeps one: the members'
-        traces (QUEUE, BATCH_ASSEMBLY, COMPUTE and D2H_TRANSFER spans, the
-        ``tick`` and ``cost`` stamps, mirrored on their flight records),
-        the model's statistics, the collector (compute window and compile
-        event, read-back, the batcher's tick) and the cost ledger.  No
+        """Write one step into every book that keeps one: the batcher that
+        formed it (what its one-ahead rule learns from finished steps;
+        there the record's ``t_dry`` becomes known, which the statistics'
+        dry-time account then splits), the members' traces (QUEUE,
+        BATCH_ASSEMBLY, COMPUTE and D2H_TRANSFER spans, the ``tick`` and
+        ``cost`` stamps, mirrored on their flight records), the model's
+        statistics, the collector (compute window and compile event,
+        read-back, the batcher's tick) and the cost ledger.  No
         other code writes an execution of ``_run_model``'s into any of
         them; a book that needs a new fact gets it from the record."""
         ran = step.t_returned > 0
+        if step.batcher is not None:
+            step.batcher._observe(step)
         for m in step.members:
             trace = m.trace
             if trace is None:

@@ -175,6 +175,18 @@ class ModelStats:
     batch_carry_rows: int = 0   # and the rows it left for the next batch
     batch_hold_count: int = 0   # executions whose close waited past the
     batch_hold_ns: int = 0      # window's end for the batch ahead; how long
+    # a formed step that found the chip out of this model's work: steps
+    # with a part of the dry interval under each cause, and its ns
+    # (``types.dry_split``; a step, not a row; a lower bound, blind across
+    # a process-wide pause)
+    dry_no_request_count: int = 0   # no request of the model was in the
+    dry_no_request_ns: int = 0      # batcher
+    dry_window_count: int = 0       # a request waited, the batcher's window
+    dry_window_ns: int = 0          # was open
+    dry_late_count: int = 0         # the window was over, the batch not
+    dry_late_ns: int = 0            # closed (late pump, pause, hold)
+    dry_host_count: int = 0         # assembly and the hop to the executor
+    dry_host_ns: int = 0
     pause_count: int = 0        # collector / late-loop pauses that held
     pause_ns: int = 0           # requests of this model
     # counted on the device and read back with the answer; 0 for a model
@@ -232,6 +244,19 @@ class ModelStats:
             if step.held_ns:
                 self.batch_hold_count += 1
                 self.batch_hold_ns += step.held_ns
+            if step.t_dry:
+                # the chip had run out of this model's work before the step
+                # reached it: the interval's four causes, a step (it is
+                # device time, not a row's wait)
+                no_request, window, late, host = step.dry_ns
+                self.dry_no_request_count += no_request > 0
+                self.dry_no_request_ns += no_request
+                self.dry_window_count += window > 0
+                self.dry_window_ns += window
+                self.dry_late_count += late > 0
+                self.dry_late_ns += late
+                self.dry_host_count += host > 0
+                self.dry_host_ns += host
 
     def record_answered(self, rows: int, queue_ns: int, compute_ns: int,
                         ok: bool) -> None:
@@ -347,6 +372,14 @@ class ModelStats:
             "batch_carry_rows": {"count": self.batch_carry_rows, "ns": 0},
             "batch_hold": {"count": self.batch_hold_count,
                            "ns": self.batch_hold_ns},
+            "dry_no_request": {"count": self.dry_no_request_count,
+                               "ns": self.dry_no_request_ns},
+            "dry_window": {"count": self.dry_window_count,
+                           "ns": self.dry_window_ns},
+            "dry_late": {"count": self.dry_late_count,
+                         "ns": self.dry_late_ns},
+            "dry_host": {"count": self.dry_host_count,
+                         "ns": self.dry_host_ns},
             "pause": {"count": self.pause_count, "ns": self.pause_ns},
             "expert_rows": {"count": self.expert_rows, "ns": 0},
             "expert_tokens": {"count": self.expert_tokens, "ns": 0},
@@ -384,6 +417,9 @@ class Model(abc.ABC):
     #: version number this instance serves (the registry stamps it when a
     #: repository model declares numbered version directories)
     served_version: str = "1"
+
+    #: steps ``InferenceCore._run_model`` has numbered (``StepRecord.seq``)
+    step_seq: int = 0
 
     #: True for a model that runs its own device loop (the decode worker):
     #: it books its own ticks and costs, a request's tenant rides its

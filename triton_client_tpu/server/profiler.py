@@ -47,8 +47,12 @@ overflow folds into a synthetic ``~overflow`` frame rather than growing.
 
 from __future__ import annotations
 
+import bisect
 import contextlib
 import gc
+import glob
+import heapq
+import itertools
 import os
 import sys
 import threading
@@ -56,6 +60,8 @@ import time
 import traceback
 from collections import Counter, deque
 from typing import Any, Dict, List, Optional, Tuple
+
+from .types import cut_by_step
 
 PROFILE_HZ_ENV = "TRITON_TPU_PROFILE_HZ"
 DEFAULT_PROFILE_HZ = 19.0
@@ -90,6 +96,131 @@ def annotation(name: str, **kwargs):
     cls = getattr(getattr(sys.modules.get("jax"), "profiler", None),
                   "TraceAnnotation", None)
     return cls(name, **kwargs) if cls is not None else _NO_SPAN
+
+
+# what the host was doing in a gap of the device's time line
+# (``types.cut_by_step``): the dry account's four causes and, from
+# ``t_called`` to the gap's end, ``dispatch``: inside ``model.execute``
+# before a program ran
+GAP_CAUSES = ("no_request", "window", "late", "host", "dispatch")
+_OP_LINE = "XLA Ops"
+_GAPS_KEPT = 32
+
+
+def attribute_gaps(op_lines: Dict[str, list], records: List[dict],
+                   window: Optional[Tuple[int, int]] = None) -> dict:
+    """Every idle gap of every device, charged to the step that follows it
+    and cut by that step's host points.
+
+    ``op_lines`` maps a device to its op line's ``(name, start, end)``
+    events, ns on the trace's clock.  ``records`` are the ``step.record``
+    events: their arguments and ``at``, the event's own start on that
+    clock; ``at - now`` maps the record's ``monotonic_ns`` points onto it.
+    The gaps are those between the ops inside ``window`` (``(lo, hi)``;
+    default: each device's first op to its last).  The step after a gap is
+    the one called last before the gap's end whose outputs were not yet on
+    the host then; a gap with none is charged to ``"none"`` (a program of
+    no recorded step follows, such as a device loop's tick, or a step the
+    trace ended before).  Returns ``by_cause`` (ns, summed over devices),
+    ``devices`` (each one's ``window_ns``, ``idle_ns`` and ``by_cause``)
+    and ``gaps``, the longest ones with their step and parts."""
+    steps = []
+    for r in records:
+        offset = r["at"] - r["now"]
+        # a step no batcher formed (a direct request, an ensemble member;
+        # a warm-up, which has no member either) has no window and no
+        # close: from its arrival until the call it is the host's
+        formed = r["t_window_end"] > 0
+        enqueue = r["first_enqueue"] or r["t_assembly"]
+        steps.append((r["t_called"] + offset, r["t_on_host"] + offset,
+                      enqueue + offset,
+                      (r["t_window_end"] if formed else enqueue) + offset,
+                      (r["t_assembly"] if formed else enqueue) + offset,
+                      r.get("model", ""), r.get("step", 0)))
+    steps.sort()
+    called = [step[0] for step in steps]
+    # the latest end among a step and those called before it: where that
+    # lies before a gap's end no step called by then is still out
+    ends = list(itertools.accumulate((step[1] for step in steps), max))
+    devices, longest = {}, []
+    for device, events in sorted(op_lines.items()):
+        lo, hi = window or (min((e[1] for e in events), default=0),
+                            max((e[2] for e in events), default=0))
+        spans = []
+        for _, start, end in sorted(events, key=lambda e: e[1]):
+            start, end = max(start, lo), min(end, hi)
+            if end <= start:
+                continue
+            if spans and start <= spans[-1][1]:
+                spans[-1][1] = max(spans[-1][1], end)
+            else:
+                spans.append([start, end])
+        edges = [lo] + [t for span in spans for t in span] + [hi]
+        by_cause = dict.fromkeys(GAP_CAUSES + ("none",), 0)
+        for gap_lo, gap_hi in zip(edges[0::2], edges[1::2]):
+            if gap_hi <= gap_lo:
+                continue
+            at = bisect.bisect_right(called, gap_hi) - 1
+            while at >= 0 and steps[at][1] < gap_hi:
+                at = at - 1 if ends[at] >= gap_hi else -1
+            if at < 0:
+                by_cause["none"] += gap_hi - gap_lo
+                parts, model, step = None, "", 0
+            else:
+                t_called, _, enqueue, window_end, assembly, model, step = \
+                    steps[at]
+                parts = cut_by_step(gap_lo, gap_hi, enqueue, window_end,
+                                    assembly, t_called)
+                for cause, ns in zip(GAP_CAUSES, parts):
+                    by_cause[cause] += ns
+            longest.append((gap_hi - gap_lo, device, gap_lo, model, step,
+                            parts))
+        devices[device] = {
+            "window_ns": hi - lo,
+            "idle_ns": (hi - lo) - sum(e - s for s, e in spans),
+            "by_cause": by_cause}
+    return {
+        "by_cause": {cause: sum(d["by_cause"][cause]
+                                for d in devices.values())
+                     for cause in GAP_CAUSES + ("none",)},
+        "devices": devices,
+        "gaps": [{"ns": ns, "device": device, "start": start,
+                  "model": model, "step": step,
+                  "parts": dict(zip(GAP_CAUSES, parts)) if parts else None}
+                 for ns, device, start, model, step, parts
+                 in heapq.nlargest(_GAPS_KEPT, longest,
+                                   key=lambda gap: gap[0])]}
+
+
+def device_gaps(trace_dir: str,
+                window: Optional[Tuple[int, int]] = None) -> Optional[dict]:
+    """``attribute_gaps`` over the newest profile under ``trace_dir`` (a
+    ``jax.profiler`` session's directory): the devices' ``XLA Ops`` lines
+    and the ``step.record`` events of the host's lines, which share the
+    trace's clock; ``profile`` names the file read.  None where the trace
+    holds no device op line (a CPU run: its ops lie on host threads)."""
+    from jax.profiler import ProfileData
+
+    paths = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    op_lines, records = {}, []
+    path = max(paths)  # a session's directory is named by when it began
+    for plane in ProfileData.from_file(path).planes:
+        on_device = plane.name.startswith("/device:")
+        for line in plane.lines:
+            if on_device:
+                if line.name == _OP_LINE:
+                    op_lines[plane.name] = [
+                        (e.name, e.start_ns, e.start_ns + e.duration_ns)
+                        for e in line.events]
+                continue
+            records += [{**dict(e.stats), "at": e.start_ns}
+                        for e in line.events if e.name == "step.record"]
+    if not op_lines:
+        return None
+    return {"profile": path, **attribute_gaps(op_lines, records, window)}
 
 
 def profile_hz_from_env(default: float = DEFAULT_PROFILE_HZ) -> float:

@@ -59,6 +59,7 @@ from typing import Dict, List, Optional
 
 import time
 
+from .profiler import device_gaps
 from .types import InferError
 
 _KNOWN_LEVELS = {"OFF", "TIMESTAMPS", "TENSORS", "PROFILE"}
@@ -547,6 +548,28 @@ class RequestTracer:
                     f"trace: jax.profiler.stop_trace failed, "
                     f"{self._profile_dir()} may be incomplete: "
                     f"{type(e).__name__}: {e}")
+            return
+        try:
+            gaps = device_gaps(self._profile_dir())
+            if gaps is None:
+                return  # no device line: nothing to account for
+            path = os.path.join(os.path.dirname(gaps["profile"]),
+                                "gaps.json")
+            with open(path, "w") as f:
+                json.dump(gaps, f)
+        except Exception as e:
+            # the profile itself is written; its reduction is a service
+            if self.log is not None:
+                self.log.error(f"trace: the device's idle gaps were not "
+                               f"accounted: {type(e).__name__}: {e}")
+            return
+        if self.log is not None:
+            idle = sum(d["idle_ns"] for d in gaps["devices"].values())
+            self.log.info(
+                f"trace: device idle {idle / 1e6:.1f} ms by cause: "
+                + ", ".join(f"{cause} {ns / 1e6:.1f}"
+                            for cause, ns in gaps["by_cause"].items())
+                + f" ({path})")
 
     def _profile_dir(self) -> str:
         return self._trace_file() + ".profile"

@@ -151,6 +151,45 @@ class InferResponse:
                 self.rows, time.monotonic_ns() - t_recv_ns)
 
 
+def cut_by_step(lo: int, hi: int, first_enqueue: int, t_window_end: int,
+                t_assembly: int, t_called: int) -> Tuple[int, int, int, int,
+                                                         int]:
+    """An interval ``[lo, hi]`` in which the chip had none of a model's
+    work, cut by the time line of the step that followed into what the host
+    was doing: ``(no_request, window, late, host, dispatch)`` ns.  No
+    request of the step was in the batcher before ``first_enqueue``; from
+    there to ``t_window_end`` the batcher held its window open (to
+    ``t_assembly`` where the batch closed inside it); from the window's
+    end to ``t_assembly`` the batch did not close though its window was
+    over; from ``t_assembly`` to ``t_called`` it was assembled and crossed
+    to the executor thread; after ``t_called`` it was inside
+    ``model.execute``.  The points are clamped into the interval in the
+    order the step passed them, so every nanosecond has one cause and the
+    parts sum to ``hi - lo``."""
+    enqueued = min(max(first_enqueue, lo), hi)
+    assembly = min(max(t_assembly, enqueued), hi)
+    window_end = min(max(t_window_end, enqueued), assembly)
+    called = min(max(t_called, assembly), hi)
+    return (enqueued - lo, window_end - enqueued, assembly - window_end,
+            called - assembly, hi - called)
+
+
+def dry_split(t_dry: int, first_enqueue: int, t_window_end: int,
+              t_assembly: int, t_called: int) -> Tuple[int, int, int, int]:
+    """The dry interval ``[t_dry, t_called]`` of a formed step by cause
+    (``cut_by_step``; nothing of it lies after ``t_called``):
+    ``(no_request, window, late, host)`` ns, which sum to ``t_called -
+    t_dry``; all 0 where ``t_dry`` is 0 or not before ``t_called``
+    (nothing ran dry).  A lower bound on what the chip felt, and blind
+    across a process-wide pause: ``t_dry`` is the step ahead's
+    ``t_on_host``, stamped only when the pause is over, next to this
+    step's ``t_called`` (``profiler.device_gaps`` sees the stall whole)."""
+    if not 0 < t_dry < t_called:
+        return 0, 0, 0, 0
+    return cut_by_step(t_dry, t_called, first_enqueue, t_window_end,
+                       t_assembly, t_called)[:4]
+
+
 class StepMember(NamedTuple):
     """One request's part of an executed step."""
     rows: int
@@ -178,10 +217,16 @@ class StepRecord:
     rows: int              # real rows
     bucket: int            # rows run, pad rows included
     members: Sequence[StepMember] = ()
+    seq: int = 0           # its number among the model's steps: the ``step``
+    #                        argument its profiler annotations share
     carried: int = 0       # rows the batcher closed the batch without
     held_ns: int = 0       # how long past its window's end the batcher kept
     #                        the batch open for the one batch ahead
     queue_depth: int = 0   # requests left queued as the batch formed
+    batcher: Any = None    # the _DynamicBatcher that formed it and learns
+    #                        from it as it is booked (None: no batcher did)
+    t_window_end: int = 0  # a formed batch: its first member's enqueue_ns +
+    #                        the model's queue delay
     t_assembly: int = 0    # the batch's concat + pad began,
     t_assembled: int = 0   # and ended; a lone request: both when it ran
     t_submit: int = 0      # handed to the executor (0: ran inline)
@@ -189,6 +234,11 @@ class StepRecord:
     t_called: int = 0      # model.execute called,
     t_returned: int = 0    # and returned
     t_on_host: int = 0     # outputs read back (0: they stay on the device)
+    t_dry: int = 0         # a formed batch: when the model's earlier steps
+    #                        were last all on the host, where that was before
+    #                        t_called (0: one still ran or was queued on the
+    #                        device then, or none came before); known once
+    #                        the step has ended, stamped as it is booked
     t_done: int = 0        # the caller has them: the v2 window's end
     device_loop: bool = False   # the model books its own ticks and costs
     signature: Optional[tuple] = None   # compile signature (XLA models)
@@ -235,6 +285,13 @@ class StepRecord:
     @property
     def device_wait_ns(self) -> int:
         return self.t_on_host - self.t_returned if self.t_on_host else 0
+
+    @property
+    def dry_ns(self) -> Tuple[int, int, int, int]:
+        """``dry_split`` of this step: the ``dry_no_request``,
+        ``dry_window``, ``dry_late`` and ``dry_host`` entries, a step."""
+        return dry_split(self.t_dry, self.members[0].enqueue_ns,
+                         self.t_window_end, self.t_assembly, self.t_called)
 
     @property
     def fail_ns(self) -> int:
