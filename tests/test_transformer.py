@@ -203,3 +203,158 @@ class TestInt8EncoderServing:
         assert out.shape == (2, 16, run.cfg.vocab_size)
         assert any(k.endswith("_scale") for k in run._params)
         assert run._params["w1"].dtype == jnp.int8
+
+
+# ---------------------------------------------------------------------------
+# A served model holds its weights as the forward reads them
+# (tr.serving_params): the cases are the dense stack, the plain-MoE preset
+# and both under TRITON_TPU_QUANT=int8, at cfg.dtype = bfloat16.
+# ---------------------------------------------------------------------------
+
+_SERVED_CASES = {
+    "dense": dict(n_experts=0, quant=False),
+    "moe": dict(n_experts=2, quant=False),
+    "int8": dict(n_experts=0, quant=True),
+    "moe_int8": dict(n_experts=2, quant=True),
+}
+_F32_LEAVES = ("head", "router", "ln1", "ln2", "final_ln")
+
+served_case = pytest.mark.parametrize("case", sorted(_SERVED_CASES))
+
+
+def _served_cfg(case):
+    # a vocabulary no other width equals, so that a shape names its leaf
+    return _cfg(n_experts=_SERVED_CASES[case]["n_experts"], causal=False,
+                vocab_size=48, dtype=jnp.bfloat16)
+
+
+def _f32_params(case, cfg, seed=7):
+    params = tr.init_params(jax.random.PRNGKey(seed), cfg)
+    if _SERVED_CASES[case]["quant"]:
+        params = tr.quantize_layer_weights(params, cfg)
+    return params
+
+
+@served_case
+def test_serving_params_forward_bit_for_bit(case):
+    cfg = _served_cfg(case)
+    tokens, _ = _data(cfg, B=2, S=16)
+    params = _f32_params(case, cfg)
+    mesh = _mesh1(cfg)
+    fwd = tr.make_forward(mesh, cfg, quantized=_SERVED_CASES[case]["quant"])
+    cast_each_forward = fwd(tr.place_params(params, mesh, cfg), tokens)
+    cast_once = fwd(
+        tr.place_params(tr.serving_params(params, cfg), mesh, cfg), tokens)
+    assert cast_once.dtype == cast_each_forward.dtype
+    np.testing.assert_array_equal(np.asarray(cast_once),
+                                  np.asarray(cast_each_forward))
+
+
+@served_case
+def test_serving_params_casts_what_the_forward_casts_and_no_more(case):
+    cfg = _served_cfg(case)
+    params = _f32_params(case, cfg)
+    served = tr.serving_params(params, cfg)
+    assert set(served) == set(params)
+    for k, v in served.items():
+        if params[k].dtype == jnp.int8:
+            assert v.dtype == jnp.int8, k
+        elif k in _F32_LEAVES or k.endswith("_scale"):
+            assert v.dtype == jnp.float32, k
+        else:
+            assert k in tr._CAST_LEAVES and v.dtype == jnp.bfloat16, k
+        assert v.shape == params[k].shape, k
+    assert served["embed"].dtype == jnp.bfloat16
+    # an f32 model (the tests' own, __graft_entry__) has nothing to narrow
+    f32 = dataclasses.replace(cfg, dtype=jnp.float32)
+    assert all(v is params[k]
+               for k, v in tr.serving_params(params, f32).items())
+
+
+def _lazy(case, monkeypatch):
+    from triton_client_tpu.models import language
+
+    if _SERVED_CASES[case]["quant"]:
+        monkeypatch.setenv("TRITON_TPU_QUANT", "int8")
+    else:
+        monkeypatch.delenv("TRITON_TPU_QUANT", raising=False)
+    run = language._LazyTransformer(_served_cfg(case), seed=24,
+                                    model_name="served_test")
+    run._ensure()
+    return run
+
+
+@served_case
+def test_lazy_transformer_holds_no_f32_cast_leaf(case, monkeypatch):
+    run = _lazy(case, monkeypatch)
+    quant = _SERVED_CASES[case]["quant"]
+    for k in tr._CAST_LEAVES:
+        if k in run._params:
+            want = (jnp.int8 if quant and k != "embed" else jnp.bfloat16)
+            assert run._params[k].dtype == want, k
+    for k in _F32_LEAVES:
+        if k in run._params:
+            assert run._params[k].dtype == jnp.float32, k
+    # and it answers as the forward over the f32 parameters does
+    tokens, _ = _data(run.cfg, B=2, S=16)
+    params = _f32_params(case, run.cfg, seed=24)
+    want = tr.make_forward(run.mesh, run.cfg, quantized=quant)(
+        tr.place_params(params, run.mesh, run.cfg), tokens)
+    np.testing.assert_array_equal(np.asarray(run(tokens)), np.asarray(want))
+
+
+def _weight_converts(lowered_text, params):
+    """The ``convert`` lines of a lowered forward whose operand has the
+    shape of a matrix or of the embedding, stacked or one layer's slice of
+    it (the scan's body converts a slice; XLA lifts it to the stack)."""
+    shapes = set()
+    for k in tr._CAST_LEAVES:
+        if k in params:
+            shape = params[k].shape
+            for dims in (shape, shape[1:]) if k != "embed" else (shape,):
+                shapes.add("tensor<" + "x".join(map(str, dims)) + "x")
+    return [line.strip() for line in lowered_text.splitlines()
+            if "stablehlo.convert" in line
+            and any(s in line.split(":", 1)[-1] for s in shapes)]
+
+
+@served_case
+def test_served_forward_lowers_without_a_weight_convert(case, monkeypatch):
+    run = _lazy(case, monkeypatch)
+    tokens, _ = _data(run.cfg, B=2, S=16)
+    text = run._fwd.lower(run._params, tokens).as_text()
+    quant = _SERVED_CASES[case]["quant"]
+    found = _weight_converts(text, run._params)
+    if case == "moe_int8":
+        # expert matrices stay int8 and are widened on the fly (``_mw``):
+        # those converts read int8, never f32
+        assert found and all("xi8>" in line for line in found), found
+    else:
+        assert not found, found
+    # the reader does see the cast where the parameters are f32
+    params = _f32_params(case, run.cfg)
+    f32_text = tr.make_forward(run.mesh, run.cfg, quantized=quant).lower(
+        tr.place_params(params, run.mesh, run.cfg), tokens).as_text()
+    assert any("xf32>" in line.split("->")[0]
+               for line in _weight_converts(f32_text, params))
+
+
+@pytest.mark.parametrize("moe", [True, False])
+def test_train_step_takes_and_returns_f32(moe):
+    # training keeps its master weights: what serving_params narrows for a
+    # served forward stays f32 through a step, optimizer state too
+    cfg = _cfg(n_experts=2 if moe else 0, dtype=jnp.bfloat16)
+    tokens, labels = _data(cfg)
+    params = tr.init_params(jax.random.PRNGKey(0), cfg)
+    assert all(v.dtype == jnp.float32 for v in params.values())
+    mesh = _mesh1(cfg)
+    step = tr.make_train_step(mesh, cfg, n_micro=2)
+    p, o, loss = step(tr.place_params(params, mesh, cfg),
+                      tr.place_opt(tr.adam_init(params), mesh, cfg),
+                      tokens, labels)
+    assert np.isfinite(float(loss))
+    assert set(p) == set(params)
+    for k in params:
+        assert p[k].dtype == jnp.float32, k
+        assert o["mu"][k].dtype == jnp.float32, k
+        assert o["nu"][k].dtype == jnp.float32, k

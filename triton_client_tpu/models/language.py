@@ -214,11 +214,18 @@ class _LazyTransformer:
             # TRITON_TPU_QUANT[_<MODEL>]=int8: weight-only int8 storage +
             # dynamic activation quantization → the layer matmuls run on
             # the MXU's int8 path (2× bf16 peak on v5e); norms/embed/head
-            # stay full precision (closeness proven in test_transformer.py)
+            # are not quantized (closeness proven in test_transformer.py)
             quant = tr.resolve_quant(self._model_name)
             if quant == "int8":
                 params = tr.quantize_layer_weights(params, self.cfg)
-            self._params = tr.place_params(params, self._mesh, self.cfg)
+            # held as the forward reads them (tr.serving_params), a leaf at
+            # a time: each f32 leaf is let go before the next is cast, so
+            # no f32 copy of the matrices stands beside the served one
+            served = {}
+            for k in list(params):
+                served.update(
+                    tr.serving_params({k: params.pop(k)}, self.cfg))
+            self._params = tr.place_params(served, self._mesh, self.cfg)
             self._fwd = tr.make_forward(self._mesh, self.cfg,
                                         quantized=(quant == "int8"),
                                         head_cols=self._head_cols)

@@ -26,7 +26,9 @@ Design (scaling-book recipe, hand-rolled collectives under ``jax.shard_map``):
 * Static shapes throughout; layer loop is ``lax.scan`` over stacked layer
   params; pipeline and ring loops are ``lax.fori_loop`` — no Python control
   flow inside jit.
-* bfloat16 activations/matmuls (MXU-friendly), float32 params/optimizer.
+* bfloat16 activations/matmuls (MXU-friendly); float32 params/optimizer
+  where the model is trained, and the matmul weights and the embedding
+  stored once in the compute dtype where it is served (``serving_params``).
 """
 
 from __future__ import annotations
@@ -349,6 +351,32 @@ def quantize_layer_weights(params, cfg: TransformerConfig):
     return out
 
 
+#: The leaves the forward reads through ``_cast(…, cfg.dtype)``: the
+#: embedding (``make_forward``), the attention matrices (``_qkv_proj``,
+#: ``_out_proj``) and the FFN's, dense or expert (``_ffn_apply``).  ``head``,
+#: ``router``, the norms and the int8 ``*_scale`` siblings are read in f32.
+_CAST_LEAVES = ("embed", "wq", "wk", "wv", "wo", "w1", "w2", "we1", "we2")
+
+
+def serving_params(params, cfg: TransformerConfig):
+    """``params`` as a served forward holds them: every leaf the forward
+    would ``_cast`` to ``cfg.dtype`` stored in ``cfg.dtype``, once, where it
+    is floating point and wider (an int8 leaf of ``quantize_layer_weights``
+    stays int8); every other leaf as it is.  ``bf16(w)`` taken here is bit
+    for bit ``bf16(w)`` taken in every forward, without the 6 bytes a
+    parameter the cast moves each time.  Training keeps f32 (the master
+    weights): this is for ``make_forward`` alone."""
+    dtype = jnp.dtype(cfg.dtype)
+
+    def stored(k, v):
+        if (k in _CAST_LEAVES and jnp.issubdtype(v.dtype, jnp.floating)
+                and v.dtype.itemsize > dtype.itemsize):
+            return v.astype(dtype)
+        return v
+
+    return {k: stored(k, v) for k, v in params.items()}
+
+
 def quant_env_key(model_name: str) -> str:
     return "TRITON_TPU_QUANT_" + "".join(
         c if c.isalnum() else "_" for c in model_name.upper())
@@ -398,7 +426,10 @@ def _int8_quant(h, axes):
 
 @jax.named_scope("weight_cast")
 def _cast(w, dtype):
-    """The weights are held in f32 and cast in every forward."""
+    """A weight as the matmul reads it.  Training holds f32 master weights
+    and casts them here in every step; a served model stores these leaves
+    in ``dtype`` already (``serving_params``), so this is a no-op there and
+    the scope names nothing in its program."""
     return w.astype(dtype)
 
 
