@@ -69,3 +69,36 @@ def test_the_looped_generation_holds_one_cache_of_temporaries(
     assert cache == 3_623_878_656
     assert cache < memory.temp_size_in_bytes < cache + 1.5e9
     assert "tpu_custom_call" in compiled.as_text()  # the row kernel is there
+
+
+def test_the_hybrid_generation_fits_beside_its_weights(
+        one_chip, no_compile_cache, monkeypatch):
+    """``hybrid_conv.generate`` at LFM2-8B-A1B's cut for 16 sequences of 512:
+    9.33 GB of arguments and under a gigabyte of temporaries (both kinds of
+    state are 55 MB; the prefill's 32,768 pairs through an expert layer are
+    the rest).  The attention kernel takes heads of 64 over 8 key/value
+    heads, and every grouped matmul is the megablox kernel, a decode step's
+    64 pairs as one tile of their own: ``ragged_dot`` would read every
+    layer's experts."""
+    import functools
+
+    import triton_client_tpu.ops as ops
+    from triton_client_tpu.models import hybrid_conv, latent_moe
+
+    monkeypatch.setattr(ops, "flash_attention", functools.partial(
+        ops.flash_attention, force=True))
+    # the branch the chip takes, though the default backend here is the CPU
+    monkeypatch.setattr(latent_moe.jax, "default_backend", lambda: "tpu")
+    cfg = hybrid_conv.LFM2_8B_A1B_STAGE
+    on_chip = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
+        s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        on_chip, jax.eval_shape(lambda: hybrid_conv.init_params(cfg)))
+    tokens = on_chip(jax.ShapeDtypeStruct((16, cfg.seq_len), jnp.int32))
+    compiled = jax.jit(lambda p, t: hybrid_conv.generate(p, t, cfg)).lower(
+        params, tokens).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(9.334e9, rel=1e-3)
+    assert 0.3e9 < memory.temp_size_in_bytes < 1.2e9
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged" not in text

@@ -373,7 +373,7 @@ def _all_held_cfg(E=8, k=2):
     return types.SimpleNamespace(
         n_routed_experts=E, routed_experts_total=E, first_expert=0,
         num_experts_per_tok=k, scoring_func="softmax", router_bias=False,
-        routed_scaling_factor=1.0)
+        routed_scaling_factor=1.0, router_eps=1e-20)
 
 
 def _expert_blk(E, D=32, F=16, seed=0):

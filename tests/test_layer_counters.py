@@ -50,7 +50,9 @@ EXTENSION = ("request",) + PER_ROW + (
     # generation by diffusion over blocks (test_block_diffusion.py)
     "denoise_passes", "denoise_tokens", "experts_touched",
     # a stack run several times over one set of weights (test_looped.py)
-    "loop_steps", "loop_tokens")
+    "loop_steps", "loop_tokens",
+    # greedy generation through two kinds of state (test_hybrid_conv.py)
+    "decode_steps", "decode_tokens")
 
 
 def _echo(sleep_s):

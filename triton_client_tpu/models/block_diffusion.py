@@ -115,6 +115,7 @@ class BlockDiffusionConfig:
     scoring_func = "softmax"
     router_bias = False
     routed_scaling_factor = 1.0   # ``norm_topk_prob`` and nothing more
+    router_eps = 1e-20
 
     # what ``tr.serve_mesh`` asks of a configuration
     @property

@@ -321,7 +321,8 @@ def make_longctx_tpu() -> JaxModel:
 class _LazyBlock:
     """``_LazyTransformer``'s lazy first-request init for the blocks that
     hold their weights in bfloat16 (``module``: models/latent_moe.py,
-    models/block_diffusion.py, models/looped.py): mesh from ``tr.serve_mesh``, weights drawn
+    models/block_diffusion.py, models/looped.py, models/hybrid_conv.py):
+    mesh from ``tr.serve_mesh``, weights drawn
     on the device leaf by leaf by ``module.init_params``, one jitted
     ``step(params, tokens, cfg)``.  Nothing is imported or allocated before
     the first call."""
@@ -512,6 +513,51 @@ def make_ouro_2_6b(cfg=None) -> JaxModel:
                 **{DEVICE_COUNTER + name: array
                    for name, array in out["counters"].items()}}
 
+    return _counting_model(config, fn, cfg.seq_len + G - 1)
+
+
+def make_lfm2_8b_a1b(cfg=None) -> JaxModel:
+    """LFM2-8B-A1B's block on stage 0 of a 2-stage pipeline
+    (``hybrid_conv.LFM2_8B_A1B_STAGE``; a test passes a tiny ``cfg``): INT32
+    INPUT_IDS [P] → INT32 TOKENS [G] (greedy) and FP32 LOGITS [3,
+    vocabulary] (the logits that chose the first new token, from the
+    prefill's last position; the second, from the first decode step, which
+    reads what the prefill handed over of both kinds of state; the last,
+    which has read every cached key) and INT32 ROUTES [P + G - 1, expert
+    layers, experts a token] (the experts every position of prompt and
+    answer but the last chose, for a reference that recomputes the rows).  One request is one prompt,
+    answered whole by ``G`` tokens: a completion that does not stream."""
+    if cfg is None:
+        from .hybrid_conv import LFM2_8B_A1B_STAGE as cfg
+    from .hybrid_conv import flops_per_inference
+
+    G = cfg.new_tokens
+    config = make_config(
+        "lfm2_8b_a1b",
+        inputs=[("INPUT_IDS", "INT32", [cfg.seq_len])],
+        outputs=[("TOKENS", "INT32", [G]),
+                 ("LOGITS", "FP32", [3, cfg.vocab_size]),
+                 ("ROUTES", "INT32", [cfg.seq_len + G - 1,
+                                      cfg.n_expert_layers,
+                                      cfg.num_experts_per_tok])],
+        max_batch_size=16,
+        preferred_batch_sizes=[8, 16],
+        max_queue_delay_us=2000,
+        instance_kind="KIND_TPU",
+        parameters={"flops_per_inference": str(flops_per_inference(cfg))},
+    )
+    run = _LazyBlock(cfg, "lfm2_8b_a1b", "hybrid_conv", "generate")
+
+    def fn(INPUT_IDS):
+        out = run(INPUT_IDS)
+        return {"TOKENS": out["tokens"], "LOGITS": out["logits"],
+                "ROUTES": out["routes"],
+                **{DEVICE_COUNTER + name: array
+                   for name, array in out["counters"].items()}}
+
+    # the cost analysis reads the program that ran (``make_sdar_30b_a3b``)
+    fn.lower = lambda INPUT_IDS: run.lower(INPUT_IDS)
+    # every token of prompt and answer but the last passes the expert layers
     return _counting_model(config, fn, cfg.seq_len + G - 1)
 
 

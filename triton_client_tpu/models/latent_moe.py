@@ -84,6 +84,7 @@ class LatentMoEConfig:
     # selection bias; another block's config says otherwise
     scoring_func: str = "sigmoid"
     router_bias: bool = True
+    router_eps: float = 1e-20      # added to the chosen scores' sum
 
     @property
     def n_expert_layers(self) -> int:
@@ -144,6 +145,8 @@ _LEAF_KEYS = {
     "w_q": 20, "w_k": 21, "w_v": 22,
     # models/looped.py's exit gate
     "exit_gate": 23, "exit_gate_bias": 24,
+    # models/hybrid_conv.py's gated short convolution
+    "w_in": 25, "conv_w": 26, "w_out": 27,
 }
 
 #: the matrices ``TRITON_TPU_QUANT=int8`` stores as int8 (MLA and experts),
@@ -152,6 +155,7 @@ _INT8_CONTRACT = {
     "w_qa": (0,), "w_qb_nope": (0,), "w_qb_rope": (0,), "w_kva": (0,),
     "w_kb": (0,), "w_vb": (0,), "w_o": (0, 1),
     "w_q": (0,), "w_k": (0,), "w_v": (0,),
+    "w_in": (0,), "w_out": (0,),
     "w_gate": (0,), "w_up": (0,), "w_down": (0,),
     "we_gate": (1,), "we_up": (1,), "we_down": (1,),
     "ws_gate": (0,), "ws_up": (0,), "ws_down": (0,),
@@ -403,8 +407,8 @@ def route(blk, h, cfg):
     says how: ``scoring_func`` ("sigmoid", or "softmax" over all experts, in
     f32), ``router_bias`` (the top k is taken of score + the layer's
     selection bias, or of the scores alone); the weights come from the
-    scores alone, renormalised over the k and times
-    ``routed_scaling_factor``."""
+    scores alone, renormalised over the k (their sum + ``router_eps``) and
+    times ``routed_scaling_factor``."""
     logits = jnp.dot(h, blk["router"], preferred_element_type=jnp.float32)
     if cfg.scoring_func == "softmax":
         scores = jax.nn.softmax(logits, axis=-1)
@@ -416,8 +420,8 @@ def route(blk, h, cfg):
     chosen_by = scores + blk["router_bias"] if cfg.router_bias else scores
     _, idx = lax.top_k(chosen_by, cfg.num_experts_per_tok)
     picked = jnp.take_along_axis(scores, idx, axis=-1)
-    weights = (picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
-               * cfg.routed_scaling_factor)
+    weights = (picked / (jnp.sum(picked, axis=-1, keepdims=True)
+                         + cfg.router_eps) * cfg.routed_scaling_factor)
     return idx, weights
 
 
@@ -440,17 +444,26 @@ def _gmm_tiling(k: int, n: int):
 def _grouped_matmul(lhs, rhs, sizes):
     """``lhs [m,k]`` in groups of ``sizes`` rows against ``rhs [G,k,n]`` ->
     ``[m,n]`` f32; rows past ``sum(sizes)`` hold nothing the caller may use.
-    The megablox kernel on a TPU where the rows fill its tiles (PERF.md §6,
-    PR 28 and PR 32 have the candidates' numbers), ``lax.ragged_dot``
-    elsewhere."""
+    The megablox kernel on a TPU (PERF.md §6, PR 28 and PR 32 have the
+    candidates' numbers), ``lax.ragged_dot`` elsewhere.  Rows that do not
+    fill the kernel's tiles are padded to them, and fewer rows than a tile
+    (a decode step's pairs) are one tile of their own, in whole sublanes:
+    ``ragged_dot`` on the chip would read every group's matrix, the other
+    layers' too where the experts are one stack."""
     tiling = _gmm_tiling(*rhs.shape[1:])
-    if jax.default_backend() == "tpu" and lhs.shape[0] % tiling[0] == 0:
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
+    if jax.default_backend() != "tpu":
+        return lax.ragged_dot(lhs, rhs, sizes,
+                              preferred_element_type=jnp.float32)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        return gmm(lhs, rhs, sizes, preferred_element_type=jnp.float32,
-                   tiling=tiling)
-    return lax.ragged_dot(lhs, rhs, sizes,
-                          preferred_element_type=jnp.float32)
+    m = lhs.shape[0]
+    rows = min(tiling[0], -(-m // 16) * 16)
+    pad = -m % rows
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    out = gmm(lhs, rhs, sizes, preferred_element_type=jnp.float32,
+              tiling=(rows,) + tiling[1:])
+    return out[:m] if pad else out
 
 
 #: (token, expert) pairs a pass of an all-held layer takes: what bounds its

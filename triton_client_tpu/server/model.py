@@ -205,6 +205,9 @@ class ModelStats:
     # a stack run several times over one set of weights (models/looped.py):
     loop_steps: int = 0           # loop steps tokens took before they left
     loop_tokens: int = 0          # tokens through the stack
+    # greedy generation through two kinds of state (models/hybrid_conv.py):
+    decode_steps: int = 0         # decode steps x the sequences they ran
+    decode_tokens: int = 0        # tokens those steps yielded
     lock: threading.Lock = field(default_factory=threading.Lock)
     # steps whose counters are still on the device: ({name: array with a
     # leading axis of batch rows}, real rows, tokens a row)
@@ -346,7 +349,7 @@ class ModelStats:
             self.expert_rows_busiest += int(
                 counts.sum(axis=0).max(axis=-1).sum())
         elif name in ("denoise_passes", "denoise_tokens", "loop_steps",
-                      "loop_tokens"):
+                      "loop_tokens", "decode_steps", "decode_tokens"):
             # a count a row [rows]
             setattr(self, name, getattr(self, name) + int(counts.sum()))
         elif name == "experts_touched":
@@ -390,6 +393,8 @@ class ModelStats:
             "experts_touched": {"count": self.experts_touched, "ns": 0},
             "loop_steps": {"count": self.loop_steps, "ns": 0},
             "loop_tokens": {"count": self.loop_tokens, "ns": 0},
+            "decode_steps": {"count": self.decode_steps, "ns": 0},
+            "decode_tokens": {"count": self.decode_tokens, "ns": 0},
         }
 
 
