@@ -43,7 +43,8 @@ PER_ROW = ("queue_member", "batch_assembly", "executor_wait", "dispatch",
 # step, not a row (tests/test_dry_account.py)
 DRY = ("dry_no_request", "dry_window", "dry_late", "dry_host")
 EXTENSION = ("request",) + PER_ROW + (
-    "bucket_rows", "batch_carry", "batch_carry_rows", "batch_hold") + DRY + (
+    "bucket_rows", "batch_carry", "batch_carry_rows", "batch_hold",
+    "batch_early") + DRY + (
     "pause",
     # an expert layer's routing, counted on the device (test_latent_moe.py)
     "expert_rows", "expert_tokens", "expert_rows_busiest",

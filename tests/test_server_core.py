@@ -195,6 +195,21 @@ class _GatedRig:
             req.deadline_ns = time.monotonic_ns() + int(deadline_s * 1e9)
         return asyncio.ensure_future(self.core.infer(req))
 
+    async def queued_together(self, *values):
+        """One-row requests, every one queued before the pump may form a
+        batch of any: it waits on its in-flight permits, held here until
+        the last is in, so no case rests on the event loop's order."""
+        b = self.core._batcher(self.core.registry.get("gated"))
+        for _ in range(b.instances):
+            await b._inflight.acquire()
+        tasks = [self.send(v) for v in values]
+        await self.until(
+            lambda: b._queue.qsize() + len(b._carry) == len(values),
+            "every request queued")
+        for _ in range(b.instances):
+            b._inflight.release()
+        return tasks
+
     async def until(self, cond, what):
         for _ in range(3000):
             if cond():
@@ -248,11 +263,53 @@ async def _idle_pads_at_the_windows_end():
     rig = _GatedRig()
     rig.open()
     t0 = time.monotonic()
-    got = await asyncio.gather(*(rig.send(v) for v in (1, 2, 3)))
+    got = await asyncio.gather(*await rig.queued_together(1, 2, 3))
     assert time.monotonic() - t0 >= 0.1  # the queue delay, from arrival
     await rig.core.shutdown()
     assert rig.executions == [[1., 2., 3., 0.]]
     assert [float(r.outputs[0].data[0, 0]) for r in got] == [1., 2., 3.]
+
+
+async def _idle_row_that_fills_a_bucket_goes_at_once():
+    # nothing in flight and buckets from 1: a lone row is a full bucket, and
+    # waiting for a partner would only leave the chip idle
+    rig = _GatedRig(delay_us=1_000_000)
+    rig.open()
+    t0 = time.monotonic()
+    got = await rig.send(1)
+    assert time.monotonic() - t0 < 0.25
+    await rig.core.shutdown()
+    assert rig.executions == [[1.]]
+    assert got.outputs[0].data.shape == (1, 4)
+
+
+async def _idle_row_under_the_first_bucket_waits_its_window():
+    # buckets from 8: a pad row costs what a real one does, so a lone row
+    # at an idle chip still collects its partners for the queue delay
+    rig = _GatedRig(buckets=(8, 16, 32, 64), max_bs=64)
+    rig.open()
+    t0 = time.monotonic()
+    await rig.send(1)
+    assert time.monotonic() - t0 >= 0.1
+    await rig.core.shutdown()
+    assert rig.executions == [[1.] + [0.] * 7]
+
+
+async def _batch_early_counts():
+    # at an idle chip a lone row closes early (about its whole window not
+    # waited); three rows at once fill no bucket and wait theirs; a [32]
+    # request goes at once as the top bucket, which is no early close
+    rig = _GatedRig(delay_us=500_000)
+    rig.open()
+    await rig.send(1)
+    await asyncio.gather(*await rig.queued_together(1, 2, 3))
+    await rig.send(7, rows=32)
+    await rig.core.shutdown()
+    assert [len(e) for e in rig.executions] == [1, 4, 32]
+    (row,) = rig.core.statistics("gated")
+    early = row["inference_stats"]["batch_early"]
+    assert early["count"] == 1 and 400_000_000 <= early["ns"] <= 500_000_000
+    assert row["inference_stats"]["batch_hold"] == {"count": 0, "ns": 0}
 
 
 async def _no_bucket_small_enough_still_pads():
@@ -442,6 +499,10 @@ class _DeviceRig(_GatedRig):
         (row,) = self.core.statistics("gated")
         return row["inference_stats"]["batch_hold"]
 
+    def early_counter(self):
+        (row,) = self.core.statistics("gated")
+        return row["inference_stats"]["batch_early"]
+
 
 def _long(rows):
     return 0.6
@@ -496,13 +557,36 @@ async def _bucket_never_seen_closes_at_the_windows_end():
 
 async def _steps_shorter_than_the_lead_close_at_the_windows_end():
     # the host takes 40 ms to stand a batch on the device, so the lead is
-    # four times that: a step of 120 ms is not waited for
-    rig = _DeviceRig(lambda rows: 0.12, dispatch_s=0.04, delay_us=10_000)
+    # four times that: a step of 120 ms is not waited for, and the window
+    # of 400 ms still closes the batch behind it
+    rig = _DeviceRig(lambda rows: 0.12, dispatch_s=0.04, delay_us=400_000)
     await rig.teach(8)
     k, ahead = await rig.one_ahead(8)
+    t0 = time.monotonic()
     tasks = [ahead] + [rig.send(v) for v in (1, 2, 3)]
     await rig.until(lambda: len(rig.executions) == k + 2, "the batch of three")
     assert rig.executions[k + 1] == [1., 2., 3., 0.]
+    # the batch ahead ended inside the window: from then on the chip is
+    # idle, and three rows fill no bucket, so they wait it out
+    assert rig.began[k + 1] - t0 >= 0.35
+    await rig.finish(tasks)
+    assert rig.hold_counter() == {"count": 0, "ns": 0}
+    # only the two batches of 8 that filled a bucket at an idle chip
+    assert rig.early_counter()["count"] == 2
+
+
+async def _host_model_behind_one_closes_at_the_windows_end():
+    # ``execute`` is the whole step (a host-placed model), so the lead is
+    # four steps and the batch ahead is never waited for past the window:
+    # three rows behind it close at their window's end, while it still runs
+    rig = _DeviceRig(lambda rows: 0.3, dispatch_s=0.3, delay_us=100_000)
+    await rig.teach(8)
+    k, ahead = await rig.one_ahead(8)
+    t0 = time.monotonic()
+    tasks = [ahead] + [rig.send(v) for v in (1, 2, 3)]
+    await rig.until(lambda: len(rig.executions) == k + 2, "the batch of three")
+    assert rig.executions[k + 1] == [1., 2., 3., 0.]
+    assert 0.09 <= rig.began[k + 1] - t0 < 0.25
     await rig.finish(tasks)
     assert rig.hold_counter() == {"count": 0, "ns": 0}
 
@@ -591,14 +675,18 @@ async def _closed_loop_of_32_leaves_16_8_8():
 
 
 class TestBatcherBacklog:
-    """A batch is padded only while nothing of the model is in flight; with
-    a batch ahead it closes at the bucket it fills and carries the rest, or
-    stays open while that batch outlasts the host's lead; with two ahead it
-    stays open (``_DynamicBatcher``'s docstring)."""
+    """At an idle chip a batch goes as soon as its rows fill a bucket, and
+    is padded only where they fill none by its window's end; with a batch
+    ahead it closes at its window's end, or past it while that batch
+    outlasts the host's lead, at the bucket it fills, and carries the rest;
+    with two ahead it stays open (``_DynamicBatcher``'s docstring)."""
 
     @pytest.mark.parametrize("scenario", [
         _carry_21_behind_one,
         _idle_pads_at_the_windows_end,
+        _idle_row_that_fills_a_bucket_goes_at_once,
+        _idle_row_under_the_first_bucket_waits_its_window,
+        _batch_early_counts,
         _no_bucket_small_enough_still_pads,
         _three_row_requests_fewest_pad_rows,
         _top_bucket_request_passes_whole,
@@ -611,6 +699,7 @@ class TestBatcherBacklog:
         _the_fill_never_comes_closes_before_the_batch_ahead_ends,
         _bucket_never_seen_closes_at_the_windows_end,
         _steps_shorter_than_the_lead_close_at_the_windows_end,
+        _host_model_behind_one_closes_at_the_windows_end,
         _held_behind_one_stays_in_the_tiered_queue,
         _held_behind_one_at_shutdown_gets_503,
         _batch_hold_counts,

@@ -241,10 +241,15 @@ class _DynamicBatcher:
     observable: the batches of this model in flight (``_batch_tasks``),
     and with one in flight how long it still has to run.
 
-    * **None in flight**: the batch collects for the queue delay from its
-      first member's arrival, is padded to the smallest bucket ≥ its rows
-      and goes at once.  That is the latency path of an idle server and
-      the reason padding exists.
+    * **None in flight**: rows that fill a bucket exactly go at once: the
+      chip has nothing to run, and a partner would only be waited for.
+      Rows that fill none collect for the queue delay from the first
+      member's arrival, are padded to the smallest bucket ≥ their rows
+      and go.  A pad row costs the chip what a real one costs, so a model
+      whose buckets start above its requests' rows still collects
+      partners for it.  That is the latency path of an idle server and
+      the reason padding exists.  A batch forming behind one that ends
+      is under this rule from then on.
     * **One in flight**: at the window's end the batch closes at the
       bucket it fills: the longest prefix of the collected requests whose
       rows sum to a bucket exactly (else the prefix that leaves the fewest
@@ -253,6 +258,11 @@ class _DynamicBatcher:
       ahead like a real one; carried, its place goes to a request that
       arrives meanwhile (the reference's ``preferred_batch_size``:
       dispatch a preferred size from what is queued, leave the rest).
+      The queue delay still decides here: closed at once, a batch behind
+      one starts no sooner on the chip, and where the host needs about as
+      long as a step to stand a batch on the device (``bert_large``:
+      1.9 ms beside 2.0) one-row batches then run where one of two rows
+      would do, and the median request rides the queue's spread (PR 41).
     * **One in flight that outlasts the host's lead**: a batch short of
       the top bucket stays open past its window, until the batch ahead is
       expected to have no more than ``lead`` left to run, and then closes
@@ -318,6 +328,9 @@ class _DynamicBatcher:
         self._max_delay_ns = dbcfg.max_queue_delay_microseconds * 1000
         self._buckets = sorted(dbcfg.preferred_batch_size) or []
         self._max_bs = model.config.max_batch_size
+        # rows at which a batch is full and goes whatever is ahead
+        self._cap = min(self._buckets[-1], self._max_bs) \
+            if self._buckets else self._max_bs
         self._queue: TieredQueue = TieredQueue(
             core.qos.tiers, weights=core.qos.weights)
         self._task: Optional[asyncio.Task] = None
@@ -485,10 +498,8 @@ class _DynamicBatcher:
         window's end it stayed open for the one batch ahead (ns); no
         requests when every one taken had expired and the queue is
         empty."""
-        pending, carry, queue = self._pending, self._carry, self._queue
-        # rows at which the batch is full and goes whatever is ahead
-        cap = min(self._buckets[-1], self._max_bs) \
-            if self._buckets else self._max_bs
+        pending, carry, queue, cap = (self._pending, self._carry,
+                                      self._queue, self._cap)
         total = 0
         overflowed = False
         # when the window's end alone would have closed the batch, once
@@ -530,7 +541,11 @@ class _DynamicBatcher:
             window_end = pending[0][3] + self._max_delay_ns
             if not held_since and hold_until > max(window_end, now):
                 held_since = max(window_end, now)
-            timeout = (max(window_end, hold_until) - now) / 1e9
+            # an idle chip takes rows that fill a bucket at once; else the
+            # window's end, or the one batch ahead's hold past it
+            close_at = now if not ahead and self._bucket_for(total) == total \
+                else max(window_end, hold_until)
+            timeout = (close_at - now) / 1e9
             if timeout <= 0:
                 break
             try:
@@ -614,6 +629,10 @@ class _DynamicBatcher:
         total = sum(counts)
         padded = self._bucket_for(total) or total
         model = self._model
+        # a batch short of the top bucket assembled before its window's end
+        # was taken by an idle chip (the class docstring's rules)
+        window_end = pending[0][3] + self._max_delay_ns
+        t_assembly = time.monotonic_ns()
         # queue depth: the backlog the chosen bucket geometry leaves
         # waiting while this batch forms, sampled before any concat/pad
         step = StepRecord(
@@ -622,9 +641,10 @@ class _DynamicBatcher:
             members=[StepMember(count, p[6][0], p[4], p[3])
                      for p, count in zip(pending, counts)],
             carried=carried, held_ns=held_ns,
+            early_ns=max(0, window_end - t_assembly)
+            if total < self._cap else 0,
             queue_depth=self._queue.qsize(), batcher=self,
-            t_window_end=pending[0][3] + self._max_delay_ns,
-            t_assembly=time.monotonic_ns())
+            t_window_end=window_end, t_assembly=t_assembly)
         self._out.append(step)
         try:
             merged = {}

@@ -175,6 +175,8 @@ class ModelStats:
     batch_carry_rows: int = 0   # and the rows it left for the next batch
     batch_hold_count: int = 0   # executions whose close waited past the
     batch_hold_ns: int = 0      # window's end for the batch ahead; how long
+    batch_early_count: int = 0  # executions an idle chip took before their
+    batch_early_ns: int = 0     # window's end; the window time not waited
     # a formed step that found the chip out of this model's work: steps
     # with a part of the dry interval under each cause, and its ns
     # (``types.dry_split``; a step, not a row; a lower bound, blind across
@@ -247,6 +249,9 @@ class ModelStats:
             if step.held_ns:
                 self.batch_hold_count += 1
                 self.batch_hold_ns += step.held_ns
+            if step.early_ns:
+                self.batch_early_count += 1
+                self.batch_early_ns += step.early_ns
             if step.t_dry:
                 # the chip had run out of this model's work before the step
                 # reached it: the interval's four causes, a step (it is
@@ -375,6 +380,8 @@ class ModelStats:
             "batch_carry_rows": {"count": self.batch_carry_rows, "ns": 0},
             "batch_hold": {"count": self.batch_hold_count,
                            "ns": self.batch_hold_ns},
+            "batch_early": {"count": self.batch_early_count,
+                            "ns": self.batch_early_ns},
             "dry_no_request": {"count": self.dry_no_request_count,
                                "ns": self.dry_no_request_ns},
             "dry_window": {"count": self.dry_window_count,

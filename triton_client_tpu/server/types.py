@@ -222,6 +222,9 @@ class StepRecord:
     carried: int = 0       # rows the batcher closed the batch without
     held_ns: int = 0       # how long past its window's end the batcher kept
     #                        the batch open for the one batch ahead
+    early_ns: int = 0      # how long before its window's end a batch short
+    #                        of the top bucket was assembled: the batcher
+    #                        closed it, the chip having nothing to run
     queue_depth: int = 0   # requests left queued as the batch formed
     batcher: Any = None    # the _DynamicBatcher that formed it and learns
     #                        from it as it is booked (None: no batcher did)
