@@ -102,3 +102,35 @@ def test_the_hybrid_generation_fits_beside_its_weights(
     assert 0.3e9 < memory.temp_size_in_bytes < 1.2e9
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ragged" not in text
+
+
+def test_the_sparse_latent_step_fits_beside_its_weights(
+        one_chip, no_compile_cache, monkeypatch):
+    """``sparse_latent.forward`` at Hy4-preview's EP32 share for two prompts
+    of 8,192: 7.87 GB of arguments and 5.93 GB of temporaries (the four f32
+    streams are 1.61 GB, q, k and v of a block 1.61 GB), 13.8 GB together,
+    under the 14.5 GB that leaves room for the one-prompt program.  The
+    sparse-attention kernel is four ops (layer 0, layer 1, the scan over
+    the shared layers, the MTP module), named so that the trace's reader
+    finds them, and every grouped matmul is the megablox kernel."""
+    import re
+
+    from triton_client_tpu.models import latent_moe, sparse_latent
+
+    monkeypatch.setattr(latent_moe.jax, "default_backend", lambda: "tpu")
+    cfg = sparse_latent.HY4_PREVIEW_EP32_SHARE
+    on_chip = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
+        s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        on_chip, jax.eval_shape(lambda: sparse_latent.init_params(cfg)))
+    tokens = on_chip(jax.ShapeDtypeStruct((2, cfg.seq_len), jnp.int32))
+    compiled = jax.jit(lambda p, t: sparse_latent.forward(p, t, cfg)).lower(
+        params, tokens).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(7.874e9, rel=1e-3)
+    assert 4.5e9 < memory.temp_size_in_bytes < 6.5e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.5e9
+    text = compiled.as_text()
+    ops = set(re.findall(r"%(_dsa_call[.\d]*) = bf16\[128,8192,256\]", text))
+    assert len(ops) == 4
+    assert "ragged" not in text

@@ -53,7 +53,9 @@ EXTENSION = ("request",) + PER_ROW + (
     # a stack run several times over one set of weights (test_looped.py)
     "loop_steps", "loop_tokens",
     # greedy generation through two kinds of state (test_hybrid_conv.py)
-    "decode_steps", "decode_tokens")
+    "decode_steps", "decode_tokens",
+    # learned sparse attention (test_sparse_latent.py)
+    "dsa_queries", "dsa_pairs", "index_reused")
 
 
 def _echo(sleep_s):

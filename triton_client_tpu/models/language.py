@@ -321,7 +321,8 @@ def make_longctx_tpu() -> JaxModel:
 class _LazyBlock:
     """``_LazyTransformer``'s lazy first-request init for the blocks that
     hold their weights in bfloat16 (``module``: models/latent_moe.py,
-    models/block_diffusion.py, models/looped.py, models/hybrid_conv.py):
+    models/block_diffusion.py, models/looped.py, models/hybrid_conv.py,
+    models/sparse_latent.py):
     mesh from ``tr.serve_mesh``, weights drawn
     on the device leaf by leaf by ``module.init_params``, one jitted
     ``step(params, tokens, cfg)``.  Nothing is imported or allocated before
@@ -559,6 +560,52 @@ def make_lfm2_8b_a1b(cfg=None) -> JaxModel:
     fn.lower = lambda INPUT_IDS: run.lower(INPUT_IDS)
     # every token of prompt and answer but the last passes the expert layers
     return _counting_model(config, fn, cfg.seq_len + G - 1)
+
+
+def make_hy4_preview(cfg=None) -> JaxModel:
+    """Hy4-preview's block on one chip's share of an EP32 prefill pool
+    (``sparse_latent.HY4_PREVIEW_EP32_SHARE``; a test passes a tiny
+    ``cfg``): INT32 INPUT_IDS [S] → INT32 TOKENS [2] (the greedy next token,
+    then the multi-token-prediction module's greedy draft of the one after
+    it), FP32 LOGITS [2, vocabulary slice] (the rows that chose them), INT32
+    CHOSEN [full blocks, S, words(S)] (the bit planes each full indexer made
+    and the attention read: key ``s`` of query ``t`` is bit ``s // W`` of
+    word ``s % W`` of row ``t``) and INT32 ROUTES [expert blocks, S, k]
+    (each token's experts, of all routed).  One request is one prompt; a
+    prefill pool that drafts with MTP answers it with the first token and
+    its draft (and a cache handed on, which this model does not keep)."""
+    if cfg is None:
+        from .sparse_latent import HY4_PREVIEW_EP32_SHARE as cfg
+    from ..ops.sparse_attention import words
+    from .sparse_latent import flops_per_inference
+
+    S = cfg.seq_len
+    config = make_config(
+        "hy4_preview",
+        inputs=[("INPUT_IDS", "INT32", [S])],
+        outputs=[("TOKENS", "INT32", [2]),
+                 ("LOGITS", "FP32", [2, cfg.vocab_size]),
+                 ("CHOSEN", "INT32", [cfg.full_blocks, S, words(S)]),
+                 ("ROUTES", "INT32", [cfg.expert_blocks, S,
+                                      cfg.num_experts_per_tok])],
+        max_batch_size=2,
+        preferred_batch_sizes=[1, 2],
+        max_queue_delay_us=2000,
+        instance_kind="KIND_TPU",
+        parameters={"flops_per_inference": str(flops_per_inference(cfg))},
+    )
+    run = _LazyBlock(cfg, "hy4_preview", "sparse_latent", "forward")
+
+    def fn(INPUT_IDS):
+        out = run(INPUT_IDS)
+        return {"TOKENS": out["tokens"], "LOGITS": out["logits"],
+                "CHOSEN": out["chosen"], "ROUTES": out["routes"],
+                **{DEVICE_COUNTER + name: array
+                   for name, array in out["counters"].items()}}
+
+    # the cost analysis reads the program that ran (``make_sdar_30b_a3b``)
+    fn.lower = lambda INPUT_IDS: run.lower(INPUT_IDS)
+    return _counting_model(config, fn, cfg.seq_len)
 
 
 # Mixture-of-experts scorer: serves the flagship stack's MoE FFN path

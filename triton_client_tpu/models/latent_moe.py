@@ -37,7 +37,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -147,6 +147,10 @@ _LEAF_KEYS = {
     "exit_gate": 23, "exit_gate_bias": 24,
     # models/hybrid_conv.py's gated short convolution
     "w_in": 25, "conv_w": 26, "w_out": 27,
+    # models/sparse_latent.py: the attention's gate and sinks, the indexer,
+    # the four streams' mixing, the multi-token-prediction module's input
+    "w_g": 28, "sink": 29, "w_qi": 30, "w_ki": 31, "w_wi": 32,
+    "hc_phi": 33, "hc_alpha": 34, "hc_bias": 35, "eh_proj": 36,
 }
 
 #: the matrices ``TRITON_TPU_QUANT=int8`` stores as int8 (MLA and experts),
@@ -156,6 +160,7 @@ _INT8_CONTRACT = {
     "w_kb": (0,), "w_vb": (0,), "w_o": (0, 1),
     "w_q": (0,), "w_k": (0,), "w_v": (0,),
     "w_in": (0,), "w_out": (0,),
+    "w_g": (0,), "w_qi": (0,), "w_ki": (0,), "eh_proj": (0,),
     "w_gate": (0,), "w_up": (0,), "w_down": (0,),
     "we_gate": (1,), "we_up": (1,), "we_down": (1,),
     "ws_gate": (0,), "ws_up": (0,), "ws_down": (0,),
@@ -345,10 +350,18 @@ def _rotate(x, cos, sin):
 # The block
 # ---------------------------------------------------------------------------
 
-def _swiglu(h, gate, up, down):
+def _gated(g, u, limit: Optional[float]):
+    """``silu(g) * u`` in f32; with a ``limit``, ``silu(min(g, limit)) *
+    clip(u, -limit, limit)``."""
+    if limit is not None:
+        g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
+    return jax.nn.silu(g) * u
+
+
+def _swiglu(h, gate, up, down, limit: Optional[float] = None):
     g = jnp.dot(h, gate, preferred_element_type=jnp.float32)
     u = jnp.dot(h, up, preferred_element_type=jnp.float32)
-    a = (jax.nn.silu(g) * u).astype(h.dtype)
+    a = _gated(g, u, limit).astype(h.dtype)
     return jnp.dot(a, down, preferred_element_type=jnp.float32)
 
 
@@ -478,8 +491,9 @@ def _rows_by_run(group, batch: int, n_experts: int):
                    axis=1, dtype=jnp.int32)
 
 
-def _grouped_swiglu(blk, rows, sizes):
-    """``rows`` in groups of ``sizes`` through the layer's experts.  Where
+def _grouped_swiglu(blk, rows, sizes, limit: Optional[float] = None):
+    """``rows`` in groups of ``sizes`` through the layer's experts (SwiGLU
+    clamped at ``limit``, where one is given).  Where
     ``blk`` holds the experts of every layer as one stack (``first_group``
     says where this layer's begin), the stack is read in place: the other
     layers' groups get no rows."""
@@ -490,11 +504,12 @@ def _grouped_swiglu(blk, rows, sizes):
     with jax.named_scope("experts"):
         g = _grouped_matmul(rows, _w(blk, "we_gate"), sizes)
         u = _grouped_matmul(rows, _w(blk, "we_up"), sizes)
-        a = (jax.nn.silu(g) * u).astype(rows.dtype)
+        a = _gated(g, u, limit).astype(rows.dtype)
         return _grouped_matmul(a, _w(blk, "we_down"), sizes)
 
 
-def _all_held(blk, h, idx, weights, cfg, batch: int):
+def _all_held(blk, h, idx, weights, cfg, batch: int,
+              limit: Optional[float]):
     """``held_experts`` where every routed expert is held: all ``T*k`` pairs
     are computed, so there is no slack to leave and nothing to mask.  Sorted
     by expert, they are taken ``_PAIRS_A_PASS`` at a time (no temporary
@@ -525,7 +540,7 @@ def _all_held(blk, h, idx, weights, cfg, batch: int):
             taken = jnp.take(h, pairs // k, axis=0)
             sizes = jnp.clip(jnp.minimum(starts + counts, lo + C)
                              - jnp.maximum(starts, lo), 0, C)
-        return _grouped_swiglu(blk, taken, sizes).astype(h.dtype)
+        return _grouped_swiglu(blk, taken, sizes, limit).astype(h.dtype)
 
     if n_passes == 1:
         out = one_pass(0)
@@ -537,10 +552,11 @@ def _all_held(blk, h, idx, weights, cfg, batch: int):
     return y, rows
 
 
-def held_experts(blk, h, idx, weights, cfg, batch: int = 1):
+def held_experts(blk, h, idx, weights, cfg, batch: int = 1,
+                 limit: Optional[float] = None):
     """The held experts' part of the layer for ``h [T,D]`` (``batch`` equal
-    runs of tokens): ``(y [T,D] f32, rows routed to each held expert by run
-    [batch,E])``.  Pairs on held experts are
+    runs of tokens; SwiGLU clamped at ``limit``, where one is given): ``(y
+    [T,D] f32, rows routed to each held expert by run [batch,E])``.  Pairs on held experts are
     sorted by expert and taken a chunk at a time: gather the
     tokens' rows, one grouped SwiGLU over the experts the chunk spans,
     weight, add into ``y`` (in the activations' dtype, summed in f32).  The
@@ -551,7 +567,7 @@ def held_experts(blk, h, idx, weights, cfg, batch: int = 1):
     few of many keeps the chunk with slack and the 0/1-matmul combine, the
     faster there (PERF.md §6, PR 28)."""
     if cfg.n_routed_experts == cfg.routed_experts_total:
-        return _all_held(blk, h, idx, weights, cfg, batch)
+        return _all_held(blk, h, idx, weights, cfg, batch, limit)
     T, D = h.shape
     E, k = cfg.n_routed_experts, cfg.num_experts_per_tok
     # pairs a pass takes: a quarter over what even routing sends the held
@@ -581,7 +597,7 @@ def held_experts(blk, h, idx, weights, cfg, batch: int = 1):
             rows = jnp.take(h, token, axis=0)
             sizes = jnp.clip(jnp.minimum(starts + counts, lo + C)
                              - jnp.maximum(starts, lo), 0, C)
-        out = _grouped_swiglu(blk, rows, sizes)
+        out = _grouped_swiglu(blk, rows, sizes, limit)
         with jax.named_scope("combine"):
             # y[t] += the chunk's rows of token t, as one matmul against
             # the 0/1 matrix (token, row): a scatter-add of the same rows

@@ -210,6 +210,10 @@ class ModelStats:
     # greedy generation through two kinds of state (models/hybrid_conv.py):
     decode_steps: int = 0         # decode steps x the sequences they ran
     decode_tokens: int = 0        # tokens those steps yielded
+    # learned sparse attention (models/sparse_latent.py):
+    dsa_queries: int = 0          # query rows x attention blocks
+    dsa_pairs: int = 0            # (query, key) pairs those rows attended
+    index_reused: int = 0         # query rows x blocks that reused a choice
     lock: threading.Lock = field(default_factory=threading.Lock)
     # steps whose counters are still on the device: ({name: array with a
     # leading axis of batch rows}, real rows, tokens a row)
@@ -354,7 +358,8 @@ class ModelStats:
             self.expert_rows_busiest += int(
                 counts.sum(axis=0).max(axis=-1).sum())
         elif name in ("denoise_passes", "denoise_tokens", "loop_steps",
-                      "loop_tokens", "decode_steps", "decode_tokens"):
+                      "loop_tokens", "decode_steps", "decode_tokens",
+                      "dsa_queries", "dsa_pairs", "index_reused"):
             # a count a row [rows]
             setattr(self, name, getattr(self, name) + int(counts.sum()))
         elif name == "experts_touched":
@@ -402,6 +407,9 @@ class ModelStats:
             "loop_tokens": {"count": self.loop_tokens, "ns": 0},
             "decode_steps": {"count": self.decode_steps, "ns": 0},
             "decode_tokens": {"count": self.decode_tokens, "ns": 0},
+            "dsa_queries": {"count": self.dsa_queries, "ns": 0},
+            "dsa_pairs": {"count": self.dsa_pairs, "ns": 0},
+            "index_reused": {"count": self.index_reused, "ns": 0},
         }
 
 
