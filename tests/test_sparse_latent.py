@@ -15,6 +15,7 @@ int8 storage 0.036-0.048.
 """
 
 import dataclasses
+import functools
 import hashlib
 import inspect
 
@@ -31,6 +32,7 @@ from triton_client_tpu.models import language  # noqa: E402
 from triton_client_tpu.models import latent_moe as lm  # noqa: E402
 from triton_client_tpu.models import sparse_latent as sl  # noqa: E402
 from triton_client_tpu.ops import sparse_attention as sa  # noqa: E402
+from triton_client_tpu.ops import stream_mixing as sm  # noqa: E402
 from triton_client_tpu.server import ModelRegistry  # noqa: E402
 from triton_client_tpu.server.model import ModelStats  # noqa: E402
 from triton_client_tpu.server.testing import ServerHarness  # noqa: E402
@@ -283,6 +285,116 @@ def test_sinkhorn_leaves_rows_and_columns_summing_to_one(params):
         assert 0 < float(post.min()) and float(post.max()) < TINY.hc_magnitude
         # the coefficients follow the token
         assert float(jnp.abs(res[0, 0] - res[0, 1]).max()) > 1e-4
+
+
+def _streams_and_block(params, dt, seed=9):
+    """Streams of three prompts of 512 (1,536 tokens: three tiles of the
+    kernels at the tiny width) and layer 1's leaves, upcast for f32."""
+    p = _f32(params) if dt == jnp.float32 else params
+    blk = jax.tree_util.tree_map(lambda a: a[0], p["groups"][1])
+    X = 3 * jax.random.normal(jax.random.PRNGKey(seed), (
+        3, 512, TINY.hc_mult, TINY.hidden_size))
+    return blk, X
+
+
+@pytest.mark.parametrize("which", [0, 1], ids=["attention", "ffn"])
+@pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_hc_pre_kernel_is_the_plain_form(params, dt, which):
+    """The ``hc.pre`` kernel in the pallas interpreter over several tiles:
+    the normed input and, through ``post_and_res``, the coefficients of
+    ``mixing``.  In f32 the sums run in another order (a few units of
+    2^-24); in bfloat16 ``x`` rounds to Phi's dtype where the plain form
+    rounds it, and ``h`` (whose rows have an rms near one) may land a unit
+    of bf16 away on a few values in ten thousand, or 2^-12 where ``u``
+    cancels to near zero."""
+    blk, X4 = _streams_and_block(params, dt)
+    n, D = TINY.hc_mult, TINY.hidden_size
+    ln = ("ln_attn", "ln_ffn")[which]
+    X = X4.reshape(-1, n * D)
+    assert sm._rows(X.shape[0], 1) < X.shape[0]     # more than one tile
+    h, a = sm.hc_pre(X, blk["hc_phi"][which], blk["hc_alpha"][which],
+                     blk["hc_bias"][which], blk[ln], n=n, hc_eps=TINY.hc_eps,
+                     eps=TINY.rms_norm_eps, dt=dt, interpret=True)
+    pre, post, res = sl.mixing(blk, X4, which, TINY)
+    u = sum(pre[..., i, None] * X4[:, :, i] for i in range(n))
+    want = np.asarray(sl.tr._rmsnorm(u, blk[ln], TINY.rms_norm_eps).astype(
+        dt).reshape(-1, D), np.float64)
+    got = np.asarray(h, np.float64)
+    assert h.dtype == dt and a.shape == (X.shape[0], n * (n + 2))
+    kpost, kres = sl.post_and_res(a, blk, which, TINY)
+    if dt == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        close = dict(rtol=1e-6, atol=1e-6)
+    else:
+        off = np.abs(got - want)
+        assert (off <= 2.0 ** -7 * np.abs(want) + 2.0 ** -12).all()
+        assert (off > 0).mean() < 1e-3
+        close = dict(rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(kpost),
+                               np.asarray(post.reshape(-1, n)), **close)
+    np.testing.assert_allclose(np.asarray(kres),
+                               np.asarray(res.reshape(-1, n, n)), **close)
+
+
+@pytest.mark.parametrize("which", [0, 1], ids=["attention", "ffn"])
+@pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_hc_post_kernel_is_the_plain_form(params, dt, which):
+    """The ``hc.post`` kernel in the pallas interpreter over several tiles:
+    ``X_i <- sum_j H_res_ij X_j + H_post_i y`` with ``mixing``'s
+    coefficients, in f32 whatever the sublayer's dtype, the streams summed
+    in the plain form's order."""
+    blk, X4 = _streams_and_block(params, dt, seed=10 + which)
+    n, D = TINY.hc_mult, TINY.hidden_size
+    _, post, res = sl.mixing(blk, X4, which, TINY)
+    y = jax.random.normal(jax.random.PRNGKey(12), X4.shape[:2] + (D,))
+    want = jnp.stack([sum(res[..., i, j, None] * X4[:, :, j] for j in range(n))
+                      + post[..., i, None] * y for i in range(n)], axis=2)
+    got = sm.hc_post(X4.reshape(-1, n * D), y.reshape(-1, D),
+                     post.reshape(-1, n), res.reshape(-1, n, n),
+                     interpret=True)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(want.reshape(got.shape)),
+                               rtol=1e-6, atol=2e-6)
+
+
+def test_the_tiles_follow_the_shapes():
+    """Hy4-preview's ``[2,8192]`` step: 64 tokens a tile of ``hc.pre``
+    (Phi held once beside them), 32 of ``hc.post``; the tiny width fills a
+    tile of 512, a token count of 16 times an odd number takes tiles of 16,
+    and the kernels refuse what they cannot tile."""
+    cfg = sl.HY4_PREVIEW_EP32_SHARE
+    width, d = cfg.hc_mult * cfg.hidden_size, cfg.hidden_size
+    pre = 2 * (4 * width + 2 * d + 4 * 128) + 4 * d
+    assert sm._rows(16384, pre, width * 128 * 2 + 64 * d) == 64
+    assert sm._rows(16384, 8 * (2 * width + d + 128)) == 32
+    assert sm._rows(1536, 1) == 512 and sm._rows(48, 1) == 16
+    X = jax.random.normal(jax.random.PRNGKey(13), (48, 4 * 128))
+    got = sm.hc_post(X, jnp.ones((48, 128)), jnp.ones((48, 4)),
+                     jnp.broadcast_to(jnp.eye(4), (48, 4, 4)), interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(X + 1), rtol=1e-6)
+    with pytest.raises(ValueError, match="lane-aligned"):
+        sm.hc_post(X[:, :200], X[:, :50], jnp.ones((48, 4)),
+                   jnp.ones((48, 4, 4)), interpret=True)
+    with pytest.raises(ValueError, match="multiple of 16"):
+        sm.hc_post(X[:24], X[:24, :128], jnp.ones((24, 4)),
+                   jnp.ones((24, 4, 4)), interpret=True)
+
+
+def test_a_whole_forward_through_the_interpreted_kernels_is_the_plain_one(
+        monkeypatch, params, float32_out, tokens):
+    """Every sublayer of the tiny f32 program through the two kernels in the
+    pallas interpreter: the same keys, experts and tokens, logits within
+    float32's rounding of the plain forward's."""
+    monkeypatch.setattr(sl, "_sublayer", functools.partial(
+        sl._sublayer, interpret=True))
+    out = _forward(_f32(params), tokens)
+    assert _rel_l2_rows(out["logits"], float32_out["logits"]).max() < 1e-5
+    for name in ("tokens", "chosen", "routes"):
+        np.testing.assert_array_equal(np.asarray(out[name]),
+                                      np.asarray(float32_out[name]))
 
 
 # -- the expert layer ---------------------------------------------------------

@@ -29,7 +29,10 @@ has that the others have not:
   + b_post)``, ``H_res = Sinkhorn(exp(alpha_2 a_res + b_res))`` (4 x 4,
   rows and columns summing to one); F reads ``RMSNorm(sum_i H_pre_i X_i)``
   and ``X <- H_res X + H_post^T F``.  The streams start as four copies of
-  the embedding and are summed after the last block.
+  the embedding and are summed after the last block.  They travel as
+  ``X [B,S,n·D]``, a token's streams one after another; on a TPU each side
+  of the mixing is one pass over them, a kernel of
+  ``ops/stream_mixing.py``.
 * **SwiGLU clamped** at ``swiglu_limit`` (``latent_moe._gated``) in every
   FFN.
 * **A multi-token-prediction module** (DeepSeek-V3's form): ``m_t = W_eh
@@ -60,6 +63,7 @@ from jax import lax
 from . import latent_moe as lm
 from . import transformer as tr
 from ..ops import sparse_attention as sa
+from ..ops import stream_mixing as sm
 
 FULL, SHARED = "full", "shared"
 DENSE, SPARSE = "dense", "sparse"
@@ -317,18 +321,24 @@ def init_params(cfg: SparseLatentConfig, quantized: bool = False
 # The four streams
 # ---------------------------------------------------------------------------
 
-def sinkhorn(m, iterations: int, eps: float):
-    """``m [..., n, n]`` positive -> rows then columns normalised,
-    ``iterations`` times: close to doubly stochastic."""
-    for _ in range(iterations):
-        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
-        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
-    return m
+def sinkhorn(m, iterations: int, eps: float, axes=(-1, -2)):
+    """``m`` positive, ``n x n`` matrices on ``axes`` (a row runs along the
+    first, a column along the second: ``[..., n, n]`` by default) -> rows
+    then columns normalised, ``iterations`` times: close to doubly
+    stochastic."""
+    row, column = axes
+
+    def once(_, m):
+        m = m / (jnp.sum(m, axis=row, keepdims=True) + eps)
+        return m / (jnp.sum(m, axis=column, keepdims=True) + eps)
+
+    return lax.fori_loop(0, iterations, once, m)
 
 
 def mixing(blk, X, which: int, cfg: SparseLatentConfig):
-    """``X [B,S,n,D]`` f32 -> ``(H_pre [B,S,n], H_post [B,S,n], H_res
-    [B,S,n,n])`` of sublayer ``which`` (0 attention, 1 FFN)."""
+    """``X [B,S,n,D]`` (or ``[B,S,n·D]``) f32 -> ``(H_pre [B,S,n], H_post
+    [B,S,n], H_res [B,S,n,n])`` of sublayer ``which`` (0 attention, 1
+    FFN)."""
     n = cfg.hc_mult
     flat = X.reshape(X.shape[:2] + (-1,))
     x = flat * lax.rsqrt(jnp.mean(flat * flat, axis=-1, keepdims=True)
@@ -345,11 +355,57 @@ def mixing(blk, X, which: int, cfg: SparseLatentConfig):
     return pre, post, res
 
 
-def _sublayer(blk, X, which: int, ln: str, cfg: SparseLatentConfig, dt, fn):
-    """``X <- H_res X + H_post^T fn(RMSNorm(sum_i H_pre_i X_i))``; ``fn``
-    takes the normed input in ``dt`` and returns ``(y [B,S,D] f32,
-    extra)``."""
+def _streams(x, n: int):
+    """``x [..., D]`` -> ``n`` streams that start as copies of it, ``[...,
+    n·D]``."""
+    return jnp.concatenate([x] * n, axis=-1)
+
+
+def _summed(X, n: int):
+    """``X [..., n·D]`` -> the streams' sum ``[..., D]``."""
+    D = X.shape[-1] // n
+    return sum(X[..., i * D:(i + 1) * D] for i in range(n))
+
+
+def post_and_res(a, blk, which: int, cfg: SparseLatentConfig):
+    """``a [T, n (n + 2)]`` (every token's ``x Phi``) -> ``(H_post [T, n],
+    H_res [T, n, n])`` of sublayer ``which``, formed with the tokens last
+    (``mixing``'s arithmetic)."""
     n = cfg.hc_mult
+    alpha, bias = blk["hc_alpha"][which], blk["hc_bias"][which]
+    a = a.T
+    post = cfg.hc_magnitude * jax.nn.sigmoid(
+        alpha[1] * a[n:2 * n] + bias[n:2 * n, None])
+    res = jnp.exp(alpha[2] * a[2 * n:] + bias[2 * n:, None])
+    res = sinkhorn(res.reshape((n, n) + a.shape[1:]), cfg.sinkhorn_iterations,
+                   cfg.hc_eps, axes=(1, 0))
+    return post.T, jnp.moveaxis(res, -1, 0)
+
+
+def _sublayer(blk, X, which: int, ln: str, cfg: SparseLatentConfig, dt, fn,
+              interpret: bool = False):
+    """``X <- H_res X + H_post^T fn(RMSNorm(sum_i H_pre_i X_i))`` on the
+    streams ``X [B,S,n·D]`` f32 (a token's streams one after another);
+    ``fn`` takes the normed input in ``dt`` and returns ``(y [B,S,D] f32,
+    extra)``.  The two kernels of ``ops/stream_mixing.py`` on a TPU backend
+    (``interpret`` runs them in the pallas interpreter), the plain form
+    elsewhere."""
+    n = cfg.hc_mult
+    B, S = X.shape[:2]
+    if interpret or jax.default_backend() == "tpu":
+        X = X.reshape(B * S, -1)
+        with jax.named_scope("hc.pre"):
+            h, a = sm.hc_pre(X, blk["hc_phi"][which], blk["hc_alpha"][which],
+                             blk["hc_bias"][which], blk[ln], n=n,
+                             hc_eps=cfg.hc_eps, eps=cfg.rms_norm_eps, dt=dt,
+                             interpret=interpret)
+            post, res = post_and_res(a, blk, which, cfg)
+        y, extra = fn(h.reshape(B, S, -1))
+        with jax.named_scope("hc.post"):
+            X = sm.hc_post(X, y.reshape(B * S, -1), post, res,
+                           interpret=interpret)
+        return X.reshape(B, S, -1), extra
+    X = X.reshape(B, S, n, -1)
     with jax.named_scope("hc.pre"):
         pre, post, res = mixing(blk, X, which, cfg)
         u = sum(pre[..., i, None] * X[:, :, i] for i in range(n))
@@ -358,7 +414,7 @@ def _sublayer(blk, X, which: int, ln: str, cfg: SparseLatentConfig, dt, fn):
     with jax.named_scope("hc.post"):
         X = jnp.stack([sum(res[..., i, j, None] * X[:, :, j] for j in range(n))
                        + post[..., i, None] * y for i in range(n)], axis=2)
-    return X, extra
+    return X.reshape(B, S, -1), extra
 
 
 # ---------------------------------------------------------------------------
@@ -523,7 +579,7 @@ def _ffn(blk, h, mlp: str, cfg: SparseLatentConfig):
 
 
 def block(blk, X, kind, cfg: SparseLatentConfig, dt, cos, sin, chosen):
-    """One block on the streams ``X [B,S,n,D]`` f32 -> ``(X, the choice it
+    """One block on the streams ``X [B,S,n·D]`` f32 -> ``(X, the choice it
     attended over, its expert layer's routing (``_ffn``) or None)``."""
     mlp, _ = kind
     X, chosen = _sublayer(
@@ -564,7 +620,7 @@ def forward(params, tokens, cfg: SparseLatentConfig):
     dt = params["embed"].dtype
     cos, sin = _rotary(cfg, jnp.arange(S))
     e = lm._embed(params, tokens, cfg).astype(jnp.float32)
-    X = jnp.broadcast_to(e[:, :, None], (B, S, n, cfg.hidden_size))
+    X = _streams(e, n)
     chosen, pairs, rows, routes, planes = None, [], [], [], []
     for (kind, layers), stack in zip(groups(cfg), params["groups"]):
         def one(carry, blk, kind=kind):
@@ -584,7 +640,7 @@ def forward(params, tokens, cfg: SparseLatentConfig):
             routes.append(routed[1].swapaxes(0, 1))
         if made is not None:
             planes.append(made.swapaxes(0, 1))
-    h = jnp.sum(X, axis=2)
+    h = _summed(X, n)
     logits = _head(params, h[:, -1], params["final_ln"], cfg)
     token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     out_logits, out_tokens = [logits], [token]
@@ -597,13 +653,15 @@ def forward(params, tokens, cfg: SparseLatentConfig):
             m = jnp.concatenate(
                 [tr._rmsnorm(e, mtp["ln_e"], cfg.rms_norm_eps),
                  tr._rmsnorm(h, mtp["ln_h"], cfg.rms_norm_eps)], axis=-1)
-            m = jnp.dot(m.astype(dt), lm._w(mtp, "eh_proj"),
+            # two-dimensional, so that the product comes out laid out as
+            # the streams are (a [B,S,D] one came out turned, and the
+            # streams made from it took a copy)
+            m = jnp.dot(m.reshape(B * S, -1).astype(dt),
+                        lm._w(mtp, "eh_proj"),
                         preferred_element_type=jnp.float32)
-            X = jnp.broadcast_to(m[:, :, None], X.shape)
-            X, chosen, routed = block(mtp, X, (SPARSE, FULL), cfg, dt, cos,
-                                      sin, None)
-            draft = _head(params, jnp.sum(X[:, -1], axis=1), mtp["ln_out"],
-                          cfg)
+            X, chosen, routed = block(mtp, _streams(m, n).reshape(B, S, -1),
+                                      (SPARSE, FULL), cfg, dt, cos, sin, None)
+            draft = _head(params, _summed(X[:, -1], n), mtp["ln_out"], cfg)
         pairs.append(chosen[1])
         rows.append(routed[0][:, None])
         routes.append(routed[1][:, None])
