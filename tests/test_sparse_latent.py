@@ -222,14 +222,30 @@ def test_below_index_topk_the_sparse_attention_is_dense_causal_with_the_sink(
                                atol=2e-5)
 
 
-@pytest.mark.parametrize("n,k", [(300, 40), (1100, 200)])
-def test_the_kernel_reads_the_chosen_keys_and_no_others(n, k):
+@pytest.mark.parametrize("n,k,empty_rows", [
+    (300, 40, False), (1100, 200, False), (700, 90, False), (4160, 1000, False),
+    (1100, 200, True), (4000, 900, False)],
+    ids=["300-40", "1100-200", "single-tails", "words-256", "empty-rows",
+         "planes-32"])
+def test_the_kernel_reads_the_chosen_keys_and_no_others(n, k, empty_rows):
     """The kernel in the pallas interpreter against the plain form, where
     the choice binds: a query block of 128 and of 512 rows, a row of keys
-    longer than one chunk, padding of both."""
-    q, kk, v, sink = _qkv(n, seed=n)
+    longer than one plane, padding of both; blocks that finish with one,
+    two and three single planes after their wide steps (``single-tails``),
+    rows of 256 words (``words-256``), rows with no chosen key in a
+    wide step or none at all (``empty-rows``), and all 32 planes, the last
+    wide step reading bit 31, the word's sign (``planes-32``).  Sinks of
+    both signs."""
+    q, kk, v, _ = _qkv(n, seed=n)
+    sink = jnp.array([-3.0, 0.5, 4.0])
     scores = jax.random.normal(jax.random.PRNGKey(n), (2, n, n))
-    bits = sa.pack(sl.choose(scores, jnp.arange(n), k))
+    chosen = sl.choose(scores, jnp.arange(n), k)
+    if empty_rows:
+        rows, keys = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
+        W = sa.words(n)
+        wide_step = (keys // W >= 4) & (keys // W < 8)    # planes 4..7
+        chosen &= ~((rows % 2 == 1) & wide_step) & (rows % 5 != 0)
+    bits = sa.pack(chosen)
     got = sa.sparse_attention(q, kk, v, bits, sink, sm_scale=0.2,
                               interpret=True)
     want = sa.sparse_attention_reference(q, kk, v, bits, sink, sm_scale=0.2)
@@ -239,6 +255,28 @@ def test_the_kernel_reads_the_chosen_keys_and_no_others(n, k):
     other = sa.pack(sl.choose(-scores, jnp.arange(n), k))
     moved = sa.sparse_attention_reference(q, kk, v, other, sink, sm_scale=0.2)
     assert float(jnp.abs(moved - want)[:, :, k:].min(-1).max()) > 1e-3
+
+
+@pytest.mark.parametrize("S,D,dv,want", [
+    (8192, 256, 256, (512, 4, 32, 272, 64, 16)),  # Hy4-preview's, W = 256
+    (4160, 24, 40, (512, 4, 17, 89, 20, 9)),
+    (1100, 24, 40, (512, 4, 9, 21, 5, 1)),
+    (700, 24, 40, (128, 4, 6, 21, 3, 9)),
+    (300, 24, 40, (128, 4, 3, 6, 0, 6)),            # fewer planes than 4
+], ids=["served", "words-256", "block-512", "block-128", "few-planes"])
+def test_the_kernels_plan(S, D, dv, want):
+    """What the kernel walks at a shape, from the function the call itself
+    sizes its blocks with: every plane up to a block's last row, the wide
+    steps and the single planes after them adding up to it."""
+    W = sa.words(S)
+    plan = sa._dsa_plan(S, D, dv, W, jnp.bfloat16)
+    assert plan[:6] == want
+    walks = [min(sa._planes_to_row(i, plan.block, W), plan.n_planes)
+             for i in range(-(-S // plan.block))]
+    assert sum(walks) == plan.walked
+    assert plan.wide * plan.wide_steps + plan.single_steps == plan.walked
+    # the chip has 128 MiB of VMEM; the call scopes what the plan asks
+    assert plan.vmem_bytes < 100 << 20
 
 
 def test_a_shared_block_attends_over_its_full_blocks_choice(params):

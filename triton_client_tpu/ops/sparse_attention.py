@@ -13,18 +13,20 @@ Each head ``h`` has a learned sink ``z_h``, a logit with no value:
 
 The kernel is the looped form of ``ops/flash_attention.py`` with the mask
 read from the bits: one program per (batch·head, query block) holds the
-head's keys and values in VMEM and walks the chunks of keys up to its
-block's last row, every chunk masked.  The online softmax starts from the
-sink (``m = z_h``, ``l = 1``), so the sink is in the denominator and a row
-whose first chunks hold no chosen key adds exact zeros.  It does the causal
-half of the dense work, whatever the selection: a masked form.  Off TPU the
-plain form below runs (tests pass ``interpret``).
+head's keys and values in VMEM and walks the planes up to its block's last
+row, four planes to a step while four are left, every plane masked
+(``_dsa_plan`` says what a shape walks).  The online softmax starts from
+the sink (``m = z_h``, ``l = 1``), so the sink is in the denominator and a
+row whose first planes hold no chosen key adds exact zeros.  It does the
+causal half of the dense work, whatever the selection: a masked form.  Off
+TPU the plain form below runs (tests pass ``interpret``).
 """
 
 from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -75,40 +77,117 @@ def sparse_attention_reference(q, k, v, bits, sink, *, sm_scale):
     return o.astype(q.dtype)
 
 
+class _DsaPlan(NamedTuple):
+    """What the kernel walks at one shape (a test pins it, PERF.md quotes
+    it).  A plane is ``W`` keys: bit ``c`` of every word of a bit row."""
+    block: int          # query rows a program holds
+    wide: int           # bit planes a wide step takes in one dot
+    n_planes: int       # bit planes a row holds, ``ceil(S / W)``
+    walked: int         # planes a head walks, all its query blocks together
+    wide_steps: int     # of a head's steps, the wide ones
+    single_steps: int   # and the single planes that finish a block
+    vmem_bytes: int     # what the call asks the compiler to scope
+
+
+def _planes_to_row(q_block, block: int, width: int):
+    """Bit planes from plane 0 that hold a key at or before the
+    ``q_block``-th block's last row; past the row's planes for a padded
+    block, so the kernel and the plan clamp it to ``n_planes``."""
+    return ((q_block + 1) * block + width - 1) // width
+
+
+def _dsa_plan(S: int, D: int, Dv: int, W: int, dtype) -> _DsaPlan:
+    """Blocks of 512 rows from S = 1024 up, 128 below, as the looped flash
+    form takes them; a wide step is four planes (1,024 keys at W = 256)."""
+    block, wide = (512 if S >= 1024 else 128), 4
+    n_planes = -(-S // W)
+    walks = [min(_planes_to_row(i, block, W), n_planes)
+             for i in range(-(-S // block))]
+    # K and V of a head whole, q, o and the block's bit rows by the block,
+    # each double-buffered; the f32 scores of a wide step, their
+    # exponentials in f32 and packed, and as much again for the compiler
+    itemsize = jnp.dtype(dtype).itemsize
+    s_keys = n_planes * W
+    resident = 2 * itemsize * (
+        _round_up(D, 16) * s_keys + s_keys * _round_up(Dv, 128)
+        + block * (_round_up(D, 128) + _round_up(Dv, 128))) + 2 * 4 * block * W
+    scores = block * wide * W * 4
+    return _DsaPlan(block, wide, n_planes, sum(walks),
+                    sum(n // wide for n in walks),
+                    sum(n % wide for n in walks),
+                    resident + 5 * scores + (4 << 20))
+
+
 def _dsa_kernel(sink_ref, q_ref, kt_ref, v_ref, bits_ref, o_ref, *, scale,
-                block, width, n_chunks):
+                block, width, wide, n_planes):
     """One (batch·head, query block) program.  ``kt_ref`` holds the head's
     keys turned (``[D, S]``), ``v_ref`` its values, ``bits_ref`` the block's
     rows of bit planes ``[block, width]``, ``sink_ref`` a tile filled with
-    the head's sink."""
+    the head's sink.  It walks the planes up to its block's last row, the
+    same planes and pairs whatever the bits choose.
+
+    What decides the loop's shape (PERF.md §6): the looped flash form's
+    levers carried over to the bits, each with its chip number, ms a call
+    at ``[2,64,8192,256]`` with 2,048 keys chosen a query on one v5e:
+    39.93 before them, 32.55 now.  Each lever was taken out (→ kept) of
+    the kernel that also tested the plane's bit at the sign (33.01):
+
+    * **wide steps**: four planes, contiguous keys ``c·W .. (c+4)·W - 1``,
+      in one dot and one softmax update while that many are left below the
+      block's last row, then single planes; a plane's mask is a shift of
+      the same ``[block, W]`` bits tile, so four planes' masks lie side by
+      side on the lanes with no data moved.  40.29 → 33.01.
+    * **row sums spread over the 128 lanes**, summed across them once a
+      program: only the maximum is needed across lanes before the next
+      step.  The sink's ``exp(z - m)`` starts in lane 0 alone.  34.09 →
+      33.01.
+
+    Not taken: the plane's bit tested at the sign (``bits << (31 - c) <
+    0``), two VPU ops a score for three, yet 33.01 against the shift and
+    mask's 32.55; blocks of 1,024 rows, 34.43.  The mask stays: an
+    unchosen score never sets ``m``, which starts at the sink, so the
+    arithmetic is the plain form's."""
     pl = _pl()
     qi = pl.program_id(1)
     q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
     bits = bits_ref[0]
 
-    def step(c, carry):
+    def step(carry, c, planes):
+        """``planes`` planes from plane ``c`` in one dot."""
         m, l, acc = carry
         start = pl.multiple_of(c * width, width)
-        s = jnp.dot(q, kt_ref[0, :, pl.ds(start, width)],
-                    preferred_element_type=jnp.float32)   # [block, width]
-        chosen = jnp.bitwise_and(jnp.right_shift(bits, c), 1) != 0
-        s = jnp.where(chosen, s, _NEG_INF)
+        keys = planes * width
+        s = jnp.dot(q, kt_ref[0, :, pl.ds(start, keys)],
+                    preferred_element_type=jnp.float32)   # [block, keys]
+        # plane c + j's bit of its lanes' word, four planes side by side
+        on = [jnp.right_shift(bits, c + j) & 1 for j in range(planes)]
+        on = on[0] if planes == 1 else jnp.concatenate(on, axis=1)
+        s = jnp.where(on != 0, s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
-        v_blk = v_ref[0, pl.ds(start, width), :]
+        spread = p[:, :128]
+        for t in range(1, keys // 128):
+            spread = spread + p[:, t * 128:(t + 1) * 128]
+        v_blk = v_ref[0, pl.ds(start, keys), :]
         acc = corr * acc + jnp.dot(p.astype(v_blk.dtype), v_blk,
                                    preferred_element_type=jnp.float32)
-        return m_new, corr * l + jnp.sum(p, axis=-1, keepdims=True), acc
+        return m_new, corr * l + spread, acc
 
     z = jnp.max(sink_ref[0], axis=1, keepdims=True)[:1]     # [1, 1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block, 128), 1)
     carry = (jnp.broadcast_to(z, (block, 1)),
-             jnp.ones((block, 1), jnp.float32),
+             jnp.where(lane == 0, 1.0, 0.0).astype(jnp.float32),
              jnp.zeros((block, v_ref.shape[-1]), jnp.float32))
-    # the chunks that hold a key at or before the block's last row
-    last = jnp.minimum(((qi + 1) * block + width - 1) // width, n_chunks)
-    _, l, acc = jax.lax.fori_loop(0, last, step, carry)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    last = jnp.minimum(_planes_to_row(qi, block, width), n_planes)
+    n_wide = last // wide
+    if n_planes >= wide:        # else a wide slice outruns the row's keys
+        carry = jax.lax.fori_loop(
+            0, n_wide, lambda j, cr: step(cr, j * wide, wide), carry)
+    carry = jax.lax.fori_loop(
+        n_wide * wide, last, lambda c, cr: step(cr, c, 1), carry)
+    _, l, acc = carry
+    o_ref[0] = (acc / jnp.sum(l, axis=-1, keepdims=True)).astype(o_ref.dtype)
 
 
 def _pl():
@@ -131,9 +210,9 @@ def _dsa_call(q, k, v, bits, sink, sm_scale, interpret):
     B, H, S, D = q.shape
     Dv = v.shape[-1]
     width = bits.shape[-1]
-    block = 512 if S >= 1024 else 128
-    n_chunks = -(-S // width)
-    s_keys, s_rows = n_chunks * width, _round_up(S, block)
+    plan = _dsa_plan(S, D, Dv, width, q.dtype)
+    block = plan.block
+    s_keys, s_rows = plan.n_planes * width, _round_up(S, block)
 
     def pad(x, rows):
         return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, rows - S), (0, 0)])
@@ -144,14 +223,10 @@ def _dsa_call(q, k, v, bits, sink, sm_scale, interpret):
     bits = pad(bits, s_rows)               # padded rows choose no key
     tiles = jnp.broadcast_to(sink.astype(jnp.float32)[:, None, None],
                              (H, 8, 128))
-    itemsize = q.dtype.itemsize
-    vmem = (2 * itemsize * (D * s_keys + s_keys * _round_up(Dv, 128)
-                            + block * (_round_up(D, 128) + _round_up(Dv, 128)))
-            + 2 * 4 * block * width + 6 * 4 * block * max(width, Dv)
-            + (4 << 20))
     out = pl.pallas_call(
         functools.partial(_dsa_kernel, scale=sm_scale, block=block,
-                          width=width, n_chunks=n_chunks),
+                          width=width, wide=plan.wide,
+                          n_planes=plan.n_planes),
         out_shape=jax.ShapeDtypeStruct((B * H, s_rows, Dv), q.dtype),
         grid=(B * H, s_rows // block),
         in_specs=[
@@ -162,7 +237,8 @@ def _dsa_call(q, k, v, bits, sink, sm_scale, interpret):
             pl.BlockSpec((1, block, width), lambda bh, qi: (bh // H, qi, 0)),
         ],
         out_specs=pl.BlockSpec((1, block, Dv), lambda bh, qi: (bh, qi, 0)),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=plan.vmem_bytes),
         interpret=interpret,
     )(tiles, q, kt, v, bits)
     return out.reshape(B, H, s_rows, Dv)[:, :, :S]
