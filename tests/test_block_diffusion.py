@@ -27,6 +27,7 @@ from chipbench.files import load_json, load_module  # noqa: E402
 from chipbench.tests.tiny_sdar import TINY_SDAR, program_config  # noqa: E402
 from triton_client_tpu.models import block_diffusion as bd  # noqa: E402
 from triton_client_tpu.models import language  # noqa: E402
+from triton_client_tpu.models import parts  # noqa: E402
 from triton_client_tpu.server import ModelRegistry  # noqa: E402
 from triton_client_tpu.server.model import ModelStats  # noqa: E402
 from triton_client_tpu.server.testing import ServerHarness  # noqa: E402
@@ -142,11 +143,11 @@ def test_passes_through_the_cache_are_the_full_forward(params, tokens):
     def through_cache(p, prompt, blocks):
         cache, _, _ = bd.prefill(p, prompt, TINY)
         x, (k, v), _, _ = bd.block_pass(p, cache, blocks[:, :B], P, TINY)
-        first = bd._head(p, x, TINY)
+        first = parts.head(p, x, TINY)
         cache = tuple(jax.lax.dynamic_update_slice_in_dim(c, new, P, 3)
                       for c, new in zip(cache, (k, v)))
         x, _, _, _ = bd.block_pass(p, cache, blocks[:, B:], P + B, TINY)
-        return first, bd._head(p, x, TINY)
+        return first, parts.head(p, x, TINY)
 
     first, second = through_cache(p32, jnp.asarray(tokens),
                                   jnp.asarray(blocks))
@@ -204,8 +205,8 @@ def test_a_block_riding_the_next_blocks_pass_is_a_pass_of_its_own(two_blocks,
         for got, want in zip((k, v), first[1]):
             assert _rel_l2(got[..., :B, :], want) < 1e-6
     elif what == "logits":
-        assert _rel_l2(bd._head(p32, x[:, B:], TINY),
-                       bd._head(p32, lone[0], TINY)) < 1e-5
+        assert _rel_l2(parts.head(p32, x[:, B:], TINY),
+                       parts.head(p32, lone[0], TINY)) < 1e-5
         np.testing.assert_array_equal(np.asarray(routes[:, B:]),
                                       np.asarray(lone[3]))
     elif what == "counts":
@@ -413,25 +414,6 @@ def test_the_factory_serves_the_generation_and_its_counters(server, params,
     assert stats["experts_touched"]["count"] == int(
         want["counters"]["experts_touched"][2])
     assert 0 < stats["experts_touched"]["count"] <= 4 * blocks * 2 * 8
-
-
-def test_the_cost_analysis_reads_the_program_that_ran(tokens):
-    """A signature's cost comes from the step's own jitted program, lowered
-    where it was traced (``_LazyBlock.lower``), and is what tracing the
-    callable afresh with its weights as arguments gives."""
-    from triton_client_tpu.server.costs import analyze_jax_callable
-
-    model = language.make_sdar_30b_a3b(TINY)
-    ids = np.concatenate([tokens, tokens, tokens[:2]])
-    model.execute({"INPUT_IDS": ids}, {})
-    cost = model.analyze_cost({"INPUT_IDS": ids}, {})
-    fn = model._fn
-    assert cost is not None and hasattr(fn, "lower")
-    del fn.lower
-    afresh = analyze_jax_callable(fn, INPUT_IDS=ids)
-    assert (cost.flops, cost.bytes_accessed) == (afresh.flops,
-                                                 afresh.bytes_accessed)
-    assert cost.flops > 0
 
 
 def test_a_mesh_of_two_is_refused(monkeypatch):

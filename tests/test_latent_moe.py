@@ -25,6 +25,7 @@ from chipbench.files import load_json, load_module  # noqa: E402
 from chipbench.tests.tiny_kimi import TINY_KIMI, program_config  # noqa: E402
 from triton_client_tpu.models import language  # noqa: E402
 from triton_client_tpu.models import latent_moe as lm  # noqa: E402
+from triton_client_tpu.models import parts  # noqa: E402
 from triton_client_tpu.server import ModelRegistry  # noqa: E402
 from triton_client_tpu.server.model import ModelStats  # noqa: E402
 from triton_client_tpu.server.testing import ServerHarness  # noqa: E402
@@ -159,7 +160,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         cfg, blk, h = _expert_layer_inputs(first_expert)
         idx, weights = lm.route(blk, h, cfg)
         total = total + lm.held_experts(blk, h, idx, weights, cfg)[0]
-    shared = lm._swiglu(h, blk["ws_gate"], blk["ws_up"], blk["ws_down"])
+    shared = parts.swiglu(h, blk["ws_gate"], blk["ws_up"], blk["ws_down"])
     uncut, _ = _dense_reference(cfg, blk, h, range(16))
     w = REF.layer_weights(TINY_KIMI, 1)
     uncut = uncut + REF._swiglu(h, w["ws_gate"], w["ws_up"], w["ws_down"])
@@ -400,8 +401,8 @@ def test_all_held_is_the_dense_form_and_the_matmul_combine(T, monkeypatch):
         blk, h, idx, w, cfg, batch=4))(h, idx, weights)
     dense = sum(
         jnp.sum(jnp.where(idx == e, weights, 0.0), -1)[:, None]
-        * lm._swiglu(h, blk["we_gate"][e], blk["we_up"][e],
-                     blk["we_down"][e]) for e in range(E))
+        * parts.swiglu(h, blk["we_gate"][e], blk["we_up"][e],
+                       blk["we_down"][e]) for e in range(E))
     assert _rel_l2(y, dense) < 1e-5
     assert rows.shape == (4, E) and int(rows.sum()) == T * k
     np.testing.assert_array_equal(

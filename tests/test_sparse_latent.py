@@ -30,6 +30,7 @@ from chipbench.files import load_json, load_module  # noqa: E402
 from chipbench.tests.tiny_hy4 import TINY_HY4, program_config  # noqa: E402
 from triton_client_tpu.models import language  # noqa: E402
 from triton_client_tpu.models import latent_moe as lm  # noqa: E402
+from triton_client_tpu.models import parts  # noqa: E402
 from triton_client_tpu.models import sparse_latent as sl  # noqa: E402
 from triton_client_tpu.ops import sparse_attention as sa  # noqa: E402
 from triton_client_tpu.ops import stream_mixing as sm  # noqa: E402
@@ -286,7 +287,8 @@ def test_a_shared_block_attends_over_its_full_blocks_choice(params):
     full = jax.tree_util.tree_map(lambda a: a[0], p["groups"][1])
     shared = jax.tree_util.tree_map(lambda a: a[0], p["groups"][2])
     assert "w_qi" in full and "w_qi" not in shared
-    cos, sin = sl._rotary(TINY, jnp.arange(S))
+    cos, sin = parts.rotary(TINY.qk_rope_head_dim, TINY.rope_theta,
+                            jnp.arange(S))
     X = jax.random.normal(jax.random.PRNGKey(6), (2, S, TINY.hc_mult,
                                                   TINY.hidden_size))
     kinds = dict(sl.groups(TINY))
@@ -356,7 +358,7 @@ def test_the_hc_pre_kernel_is_the_plain_form(params, dt, which):
                      eps=TINY.rms_norm_eps, dt=dt, interpret=True)
     pre, post, res = sl.mixing(blk, X4, which, TINY)
     u = sum(pre[..., i, None] * X4[:, :, i] for i in range(n))
-    want = np.asarray(sl.tr._rmsnorm(u, blk[ln], TINY.rms_norm_eps).astype(
+    want = np.asarray(parts.rmsnorm(u, blk[ln], TINY.rms_norm_eps).astype(
         dt).reshape(-1, D), np.float64)
     got = np.asarray(h, np.float64)
     assert h.dtype == dt and a.shape == (X.shape[0], n * (n + 2))
@@ -469,9 +471,9 @@ def test_the_swiglu_clamp():
     g = jnp.array([-20.0, -1.0, 0.5, 9.0, 30.0])
     u = jnp.array([-30.0, 2.0, -0.5, 11.0, 4.0])
     np.testing.assert_allclose(
-        np.asarray(lm._gated(g, u, 10)),
+        np.asarray(parts.gated(g, u, 10)),
         np.asarray(jax.nn.silu(jnp.minimum(g, 10)) * jnp.clip(u, -10, 10)))
-    np.testing.assert_array_equal(np.asarray(lm._gated(g, u, None)),
+    np.testing.assert_array_equal(np.asarray(parts.gated(g, u, None)),
                                   np.asarray(jax.nn.silu(g) * u))
 
 

@@ -42,7 +42,6 @@ returns what the device counted on the way, a row of the batch each, for
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict, Optional
 
@@ -51,7 +50,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import latent_moe as lm
-from . import transformer as tr
+from . import parts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,9 +154,6 @@ SDAR_30B_A3B_STAGE = BlockDiffusionConfig(
 # Weights
 # ---------------------------------------------------------------------------
 
-_EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
-
-
 def _leaf_shapes(cfg: BlockDiffusionConfig):
     """``{leaf: (shape, scale of the normal draw)}`` of one layer; an
     expert's leaves are per expert."""
@@ -173,27 +169,13 @@ def _leaf_shapes(cfg: BlockDiffusionConfig):
 
 
 def _layer_params(cfg: BlockDiffusionConfig, layer: int):
-    """One layer's leaves in bfloat16, drawn leaf by leaf as
-    ``latent_moe._layer_params`` draws its own (same keys, an expert under
-    its id); the four norms are ones."""
-    root = jax.random.fold_in(jax.random.PRNGKey(cfg.weights_seed), layer)
-    ones = lambda n: jnp.ones((n,), jnp.bfloat16)  # noqa: E731
-    out = {"ln_attn": ones(cfg.hidden_size), "ln_ffn": ones(cfg.hidden_size),
-           "ln_qh": ones(cfg.head_dim), "ln_kh": ones(cfg.head_dim)}
-    for name, (shape, scale) in _leaf_shapes(cfg).items():
-        key = jax.random.fold_in(root, lm._LEAF_KEYS[name])
-        if name in _EXPERT_LEAVES:
-            out[name] = lm._draw_experts(
-                key, jnp.arange(cfg.num_experts), shape, scale)
-        else:
-            out[name] = lm._draw(key, shape, scale)
-    return out
-
-
-@functools.partial(jax.jit, donate_argnums=0)
-def _put(stack, leaf, at):
-    """``stack[at : at + len(leaf)] = leaf``, in place."""
-    return lax.dynamic_update_slice_in_dim(stack, leaf, at, 0)
+    """One layer's leaves in bfloat16, an expert under its id; the four
+    norms are ones."""
+    D, dh = cfg.hidden_size, cfg.head_dim
+    return parts.draw_layer(
+        cfg.weights_seed, layer, _leaf_shapes(cfg),
+        {"ln_attn": D, "ln_ffn": D, "ln_qh": dh, "ln_kh": dh},
+        jnp.arange(cfg.num_experts))
 
 
 def init_params(cfg: BlockDiffusionConfig, quantized: bool = False
@@ -201,67 +183,44 @@ def init_params(cfg: BlockDiffusionConfig, quantized: bool = False
     """``{"embed", "final_ln", "head", "layers": leaves stacked for the scan,
     "experts": every layer's experts as one stack [layers * experts, ...]}``.
     Quantised (the int8 control), the experts' leaves stay with their
-    layers, where the scan hands them to ``latent_moe._w`` a layer at a
-    time, and ``experts`` is empty.  A layer is drawn, written into the
-    stacks in place and let go before the next one exists: the 8.7 GB are
-    never held twice."""
-    prep = jax.jit(lm.quantize_weights) if quantized else (lambda x: x)
-    L, E = cfg.num_hidden_layers, cfg.num_experts
+    layers, where the scan hands them to ``parts.w`` a layer at a time, and
+    ``experts`` is empty.  A layer is drawn, written into the stacks in
+    place and let go before the next one exists: the 8.7 GB are never held
+    twice."""
+    prep = jax.jit(parts.quantize_weights) if quantized else (lambda x: x)
+    L = cfg.num_hidden_layers
     stacked, experts = {}, {}
     for i in range(L):
-        for name, leaf in prep(_layer_params(cfg, i)).items():
-            if name in _EXPERT_LEAVES and not quantized:
-                into, leaf, at = experts, leaf, i * E
-            else:
-                into, leaf, at = stacked, leaf[None], i
-            if i == 0:
-                into[name] = jnp.zeros((L * leaf.shape[0],) + leaf.shape[1:],
-                                       leaf.dtype)
-            into[name] = _put(into[name], leaf, at)
-    outer = jax.random.fold_in(jax.random.PRNGKey(cfg.weights_seed),
-                               lm._OUTER)
-    V, D = cfg.vocab_size, cfg.hidden_size
-    return {
-        "embed": lm._draw(jax.random.fold_in(outer, lm._LEAF_KEYS["embed"]),
-                          (V, D), 0.02),
-        "final_ln": jnp.ones((D,), jnp.bfloat16),
-        "head": lm._draw(jax.random.fold_in(outer, lm._LEAF_KEYS["head"]),
-                         (D, V), 0.02),
-        "layers": stacked,
-        "experts": experts,
-    }
+        layer = prep(_layer_params(cfg, i))
+        if not quantized:
+            parts.stack(experts, {name: layer.pop(name)
+                                  for name in parts.EXPERT_LEAVES}, i, L,
+                        flat=True)
+        parts.stack(stacked, layer, i, L)
+    return dict(parts.outer_params(cfg), layers=stacked, experts=experts)
 
 
 # ---------------------------------------------------------------------------
 # The layer
 # ---------------------------------------------------------------------------
 
-def _rotary(cfg: BlockDiffusionConfig, positions):
-    """``(cos, sin)`` as ``[len(positions), head_dim / 2]`` f32."""
-    half = cfg.head_dim // 2
-    inv_freq = 1.0 / cfg.rope_theta ** (
-        jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    return jnp.cos(ang), jnp.sin(ang)
-
-
 def _qkv(blk, x, cfg: BlockDiffusionConfig, cos, sin):
     """``x [b,S,D]`` -> q ``[b,H,S,dh]``, k and v ``[b,Hkv,S,dh]``: q and k
     normed over the head and rotated, as the cache holds them."""
-    h = tr._rmsnorm(x, blk["ln_attn"], cfg.rms_norm_eps)
-    q = jnp.einsum("bsd,dhk->bhsk", h, lm._w(blk, "w_q"))
-    k = jnp.einsum("bsd,dhk->bhsk", h, lm._w(blk, "w_k"))
-    v = jnp.einsum("bsd,dhk->bhsk", h, lm._w(blk, "w_v"))
+    h = parts.rmsnorm(x, blk["ln_attn"], cfg.rms_norm_eps)
+    q = jnp.einsum("bsd,dhk->bhsk", h, parts.w(blk, "w_q"))
+    k = jnp.einsum("bsd,dhk->bhsk", h, parts.w(blk, "w_k"))
+    v = jnp.einsum("bsd,dhk->bhsk", h, parts.w(blk, "w_v"))
     with jax.named_scope("qk_norm"):
-        q = tr._rmsnorm(q, blk["ln_qh"], cfg.rms_norm_eps)
-        k = tr._rmsnorm(k, blk["ln_kh"], cfg.rms_norm_eps)
+        q = parts.rmsnorm(q, blk["ln_qh"], cfg.rms_norm_eps)
+        k = parts.rmsnorm(k, blk["ln_kh"], cfg.rms_norm_eps)
     with jax.named_scope("rope"):
-        q, k = lm._rotate(q, cos, sin), lm._rotate(k, cos, sin)
+        q, k = parts.rotate(q, cos, sin), parts.rotate(k, cos, sin)
     return q, k, v
 
 
 def _out_proj(blk, x, o):
-    return x + jnp.einsum("bhsk,hkd->bsd", o, lm._w(blk, "w_o"))
+    return x + jnp.einsum("bhsk,hkd->bsd", o, parts.w(blk, "w_o"))
 
 
 @jax.named_scope("moe")
@@ -269,7 +228,7 @@ def _moe(blk, x, cfg: BlockDiffusionConfig):
     """``x [b,S,D]`` -> ``(x + the experts' part, rows routed to each expert
     by batch row [b,E], the experts each token chose [b,S,k])``."""
     b, S, D = x.shape
-    h = tr._rmsnorm(x, blk["ln_ffn"], cfg.rms_norm_eps).reshape(b * S, D)
+    h = parts.rmsnorm(x, blk["ln_ffn"], cfg.rms_norm_eps).reshape(b * S, D)
     with jax.named_scope("router"):
         idx, weights = lm.route(blk, h, cfg)
     y, rows = lm.held_experts(blk, h, idx, weights, cfg, batch=b)
@@ -296,18 +255,6 @@ def _scan_layers(params, cfg: BlockDiffusionConfig, x, layer_fn, *scanned):
                               params["layers"], scanned))
 
 
-def _embed(params, tokens, cfg):
-    with jax.named_scope("embed"):
-        return jnp.take(params["embed"],
-                        jnp.clip(tokens, 0, cfg.vocab_size - 1), axis=0)
-
-
-def _head(params, x, cfg):
-    with jax.named_scope("head"):
-        h = tr._rmsnorm(x, params["final_ln"], cfg.rms_norm_eps)
-        return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
-
-
 # ---------------------------------------------------------------------------
 # Prefill, a pass, the loop
 # ---------------------------------------------------------------------------
@@ -320,7 +267,8 @@ def prefill(params, tokens, cfg: BlockDiffusionConfig,
     each ``[L,b,Hkv,P + new_tokens,dh]`` with the prompt's part written."""
     from ..ops import flash_attention
 
-    cos, sin = _rotary(cfg, jnp.arange(tokens.shape[1]))
+    cos, sin = parts.rotary(cfg.head_dim, cfg.rope_theta,
+                            jnp.arange(tokens.shape[1]))
 
     def layer(blk, x):
         with jax.named_scope("attention"):
@@ -331,10 +279,10 @@ def prefill(params, tokens, cfg: BlockDiffusionConfig,
         x, rows, _ = _moe(blk, x, cfg)
         return x, (k, v, rows)
 
-    x, (k, v, rows) = _scan_layers(params, cfg, _embed(params, tokens, cfg),
-                                   layer)
+    x, (k, v, rows) = _scan_layers(
+        params, cfg, parts.embed(params, tokens, cfg), layer)
     room = [(0, 0)] * 3 + [(0, cfg.new_tokens), (0, 0)]
-    logits = _head(params, x, cfg) if want_logits else None
+    logits = parts.head(params, x, cfg) if want_logits else None
     return (jnp.pad(k, room), jnp.pad(v, room)), rows.transpose(1, 0, 2), \
         logits
 
@@ -353,7 +301,8 @@ def block_pass(params, cache, tokens, start, cfg: BlockDiffusionConfig):
     over it gives against a cache that holds them: ``generate`` runs a
     block's final tokens so beside the next block's first pass."""
     R, B = tokens.shape[1], cfg.block_length
-    cos, sin = _rotary(cfg, start + jnp.arange(R))
+    cos, sin = parts.rotary(cfg.head_dim, cfg.rope_theta,
+                            start + jnp.arange(R))
     group = cfg.num_attention_heads // cfg.num_key_value_heads
     scale = 1.0 / math.sqrt(cfg.head_dim)
     block = jnp.arange(R) // B
@@ -382,16 +331,8 @@ def block_pass(params, cache, tokens, start, cfg: BlockDiffusionConfig):
         return x, (k, v, rows, routes)
 
     x, (k, v, rows, routes) = _scan_layers(
-        params, cfg, _embed(params, tokens, cfg), layer, *cache)
+        params, cfg, parts.embed(params, tokens, cfg), layer, *cache)
     return x, (k, v), rows.transpose(1, 0, 2), routes.transpose(1, 2, 0, 3)
-
-
-def _touched(rows):
-    """``rows [b,L,E]`` -> experts with at least one row, counted over the
-    batch's rows ``0 .. r`` for every ``r`` ``[b]`` and summed over the
-    layers: the host takes the entry of its last row that is not padding."""
-    return jnp.sum(jnp.cumsum(rows, axis=0) > 0, axis=(1, 2),
-                   dtype=jnp.int32)
 
 
 def generate(params, tokens, cfg: BlockDiffusionConfig):
@@ -443,7 +384,7 @@ def generate(params, tokens, cfg: BlockDiffusionConfig):
     def end_pass(s, x, rows, routes):
         """The end of a pass: the head over the open block's ``x [b,B,D]``,
         the commit rule, and the pass's ``rows`` among the counters."""
-        lg = _head(params, x, cfg)                              # [b,B,V]
+        lg = parts.head(params, x, cfg)                         # [b,B,V]
         with jax.named_scope("confidence"):
             conf = jnp.max(jax.nn.softmax(lg, axis=-1), axis=-1)
             best = jnp.argmax(lg, axis=-1).astype(jnp.int32)
@@ -474,7 +415,7 @@ def generate(params, tokens, cfg: BlockDiffusionConfig):
                 "last_routes": jnp.where(moved[..., None], my_routes,
                                          s["last_routes"]),
                 "rows": s["rows"] + rows,
-                "touched": s["touched"] + _touched(rows),
+                "touched": s["touched"] + parts.touched(rows),
             }
 
     def one_block(n, state, rides=True):

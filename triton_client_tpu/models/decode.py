@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import transformer as tr
+from .parts import rmsnorm
 
 
 
@@ -136,7 +137,7 @@ def _w(blk, name, dtype):
 
 
 def _project_qkv(blk, x, cfg: tr.TransformerConfig):
-    h = tr._rmsnorm(x, blk["ln1"], cfg.norm_eps)
+    h = rmsnorm(x, blk["ln1"], cfg.norm_eps)
     q = jnp.einsum("bsd,dhk->bhsk", h, _w(blk, "wq", h.dtype))
     k = jnp.einsum("bsd,dhk->bhsk", h, _w(blk, "wk", h.dtype))
     v = jnp.einsum("bsd,dhk->bhsk", h, _w(blk, "wv", h.dtype))
@@ -147,7 +148,7 @@ def _ffn(blk, x, cfg: tr.TransformerConfig):
     """FFN for the decode stack: ``tr._ffn_apply``'s math minus the mesh
     psums (single shard; GSPMD re-inserts collectives when the serve mesh
     shards the hidden/expert dims). Dense SiLU or routed MoE top-k."""
-    h = tr._rmsnorm(x, blk["ln2"], cfg.norm_eps)
+    h = rmsnorm(x, blk["ln2"], cfg.norm_eps)
     if cfg.moe:
         gate = jnp.einsum("bsd,de->bse", h.astype(jnp.float32),
                           _w(blk, "router", jnp.float32))
@@ -230,7 +231,7 @@ def _decode_layer(blk, x, kc, vc, pos, cfg: tr.TransformerConfig):
 
 
 def _head(params, x, cfg: tr.TransformerConfig):
-    h = tr._rmsnorm(x, params["final_ln"], cfg.norm_eps)
+    h = rmsnorm(x, params["final_ln"], cfg.norm_eps)
     return jnp.einsum("bsd,dv->bsv", h.astype(jnp.float32),
                       params["head"].astype(jnp.float32))
 

@@ -46,9 +46,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import block_diffusion as bd
 from . import latent_moe as lm
-from . import transformer as tr
+from . import parts
 
 CONV, ATTENTION = "conv", "full_attention"
 
@@ -222,24 +221,15 @@ def _leaf_shapes(cfg: HybridConvConfig, layer: int):
 
 
 def _layer_params(cfg: HybridConvConfig, layer: int):
-    """One layer's leaves in bfloat16 (the selection bias upcast to f32),
-    drawn leaf by leaf under ``latent_moe``'s keys, an expert under its id;
-    every norm is ones."""
-    root = jax.random.fold_in(jax.random.PRNGKey(cfg.weights_seed), layer)
-    ones = lambda n: jnp.ones((n,), jnp.bfloat16)  # noqa: E731
-    out = {"ln_op": ones(cfg.hidden_size), "ln_ffn": ones(cfg.hidden_size)}
+    """One layer's leaves in bfloat16 (the selection bias upcast to f32), an
+    expert under its id; every norm is ones."""
+    D, dh = cfg.hidden_size, cfg.head_dim
+    norms = {"ln_op": D, "ln_ffn": D}
     if cfg.layer_types[layer] == ATTENTION:
-        out.update(ln_qh=ones(cfg.head_dim), ln_kh=ones(cfg.head_dim))
-    for name, (shape, scale) in _leaf_shapes(cfg, layer).items():
-        key = jax.random.fold_in(root, lm._LEAF_KEYS[name])
-        if name in bd._EXPERT_LEAVES:
-            out[name] = lm._draw_experts(
-                key, jnp.arange(cfg.num_experts), shape, scale)
-        elif name == "router_bias":
-            out[name] = lm._draw(key, shape, scale).astype(jnp.float32)
-        else:
-            out[name] = lm._draw(key, shape, scale)
-    return out
+        norms.update(ln_qh=dh, ln_kh=dh)
+    return parts.draw_layer(cfg.weights_seed, layer, _leaf_shapes(cfg, layer),
+                            norms, jnp.arange(cfg.num_experts),
+                            ("router_bias",))
 
 
 def init_params(cfg: HybridConvConfig, quantized: bool = False
@@ -249,35 +239,23 @@ def init_params(cfg: HybridConvConfig, quantized: bool = False
     the periods, "experts": every expert layer's experts as one stack
     [expert layers * experts, ...]}``.  Quantised (the int8 control), the
     experts' leaves stay with their layers, where the scan hands them to
-    ``latent_moe._w`` a layer at a time, and ``experts`` is empty.  A layer
-    is drawn, written into the stacks in place and let go before the next
-    one exists."""
-    prep = jax.jit(lm.quantize_weights) if quantized else (lambda x: x)
-    first, p, E = cfg.num_dense_layers, cfg.period, cfg.num_experts
+    ``parts.w`` a layer at a time, and ``experts`` is empty.  A layer is
+    drawn, written into the stacks in place and let go before the next one
+    exists."""
+    prep = jax.jit(parts.quantize_weights) if quantized else (lambda x: x)
+    first, p, n = cfg.num_dense_layers, cfg.period, cfg.n_expert_layers
     dense = [prep(_layer_params(cfg, i)) for i in range(first)]
     periods = [{} for _ in range(p)]
     experts = {}
-    for i in range(cfg.n_expert_layers):
-        for name, leaf in prep(_layer_params(cfg, first + i)).items():
-            if name in bd._EXPERT_LEAVES and not quantized:
-                into, at, room = experts, i * E, cfg.n_expert_layers
-            else:
-                into, leaf, at = periods[i % p], leaf[None], i // p
-                room = cfg.n_expert_layers // p
-            if name not in into:
-                into[name] = jnp.zeros(
-                    (room * leaf.shape[0],) + leaf.shape[1:], leaf.dtype)
-            into[name] = bd._put(into[name], leaf, at)
-    outer = jax.random.fold_in(jax.random.PRNGKey(cfg.weights_seed),
-                               lm._OUTER)
-    return {
-        "embed": lm._draw(jax.random.fold_in(outer, lm._LEAF_KEYS["embed"]),
-                          (cfg.vocab_size, cfg.hidden_size), 0.02),
-        "final_ln": jnp.ones((cfg.hidden_size,), jnp.bfloat16),
-        "dense": dense,
-        "periods": tuple(periods),
-        "experts": experts,
-    }
+    for i in range(n):
+        layer = prep(_layer_params(cfg, first + i))
+        if not quantized:
+            parts.stack(experts, {name: layer.pop(name)
+                                  for name in parts.EXPERT_LEAVES}, i, n,
+                        flat=True)
+        parts.stack(periods[i % p], layer, i // p, n // p)
+    return dict(parts.outer_params(cfg, tied=True), dense=dense,
+                periods=tuple(periods), experts=experts)
 
 
 # ---------------------------------------------------------------------------
@@ -291,8 +269,8 @@ def _short_conv(blk, x, before, cfg: HybridConvConfig):
     first position (zeros at position 0) -> ``(x + op, the last
     conv_L_cache - 1 rows of z: what the next position's step reads)``."""
     S = x.shape[1]
-    w_in = lm._w(blk, "w_in")
-    n = tr._rmsnorm(x, blk["ln_op"], cfg.norm_eps).astype(w_in.dtype)
+    w_in = parts.w(blk, "w_in")
+    n = parts.rmsnorm(x, blk["ln_op"], cfg.norm_eps).astype(w_in.dtype)
     with jax.named_scope("in_proj"):
         gate_in, gate_out, u = jnp.split(jnp.dot(n, w_in), 3, axis=-1)
         z = gate_in * u
@@ -303,7 +281,7 @@ def _short_conv(blk, x, before, cfg: HybridConvConfig):
                 for j in range(cfg.conv_L_cache))
         gated = (gate_out.astype(jnp.float32) * c).astype(z.dtype)
     with jax.named_scope("out_proj"):
-        out = jnp.dot(gated, lm._w(blk, "w_out"),
+        out = jnp.dot(gated, parts.w(blk, "w_out"),
                       preferred_element_type=jnp.float32)
     with jax.named_scope("conv_state"):
         return x + out, window[:, S:]
@@ -313,28 +291,29 @@ def _qkv(blk, x, cfg: HybridConvConfig, cos, sin):
     """``x [b,S,D]`` f32 -> q ``[b,H,S,dh]``, k and v ``[b,Hkv,S,dh]`` in
     the matrices' dtype: q and k normed over the head and rotated, as the
     cache holds them."""
-    w_q = lm._w(blk, "w_q")
-    n = tr._rmsnorm(x, blk["ln_op"], cfg.norm_eps).astype(w_q.dtype)
+    w_q = parts.w(blk, "w_q")
+    n = parts.rmsnorm(x, blk["ln_op"], cfg.norm_eps).astype(w_q.dtype)
     q = jnp.einsum("bsd,dhk->bhsk", n, w_q)
-    k = jnp.einsum("bsd,dhk->bhsk", n, lm._w(blk, "w_k"))
-    v = jnp.einsum("bsd,dhk->bhsk", n, lm._w(blk, "w_v"))
+    k = jnp.einsum("bsd,dhk->bhsk", n, parts.w(blk, "w_k"))
+    v = jnp.einsum("bsd,dhk->bhsk", n, parts.w(blk, "w_v"))
     with jax.named_scope("qk_norm"):
-        q = tr._rmsnorm(q, blk["ln_qh"], cfg.norm_eps)
-        k = tr._rmsnorm(k, blk["ln_kh"], cfg.norm_eps)
+        q = parts.rmsnorm(q, blk["ln_qh"], cfg.norm_eps)
+        k = parts.rmsnorm(k, blk["ln_kh"], cfg.norm_eps)
     with jax.named_scope("rope"):
-        return lm._rotate(q, cos, sin), lm._rotate(k, cos, sin), v
+        return parts.rotate(q, cos, sin), parts.rotate(k, cos, sin), v
 
 
 def _attention_out(blk, x, o):
-    return x + jnp.einsum("bhsk,hkd->bsd", o, lm._w(blk, "w_o"),
+    return x + jnp.einsum("bhsk,hkd->bsd", o, parts.w(blk, "w_o"),
                           preferred_element_type=jnp.float32)
 
 
 @jax.named_scope("dense_ffn")
 def _dense_ffn(blk, x, cfg: HybridConvConfig):
-    w_gate = lm._w(blk, "w_gate")
-    n = tr._rmsnorm(x, blk["ln_ffn"], cfg.norm_eps).astype(w_gate.dtype)
-    return x + lm._swiglu(n, w_gate, lm._w(blk, "w_up"), lm._w(blk, "w_down"))
+    w_gate = parts.w(blk, "w_gate")
+    n = parts.rmsnorm(x, blk["ln_ffn"], cfg.norm_eps).astype(w_gate.dtype)
+    return x + parts.swiglu(n, w_gate, parts.w(blk, "w_up"),
+                            parts.w(blk, "w_down"))
 
 
 @jax.named_scope("moe")
@@ -343,7 +322,7 @@ def _moe(blk, x, cfg: HybridConvConfig):
     expert by batch row [b,E], the experts each position chose
     [b,S,k])``."""
     b, S, D = x.shape
-    h = tr._rmsnorm(x, blk["ln_ffn"], cfg.norm_eps).astype(
+    h = parts.rmsnorm(x, blk["ln_ffn"], cfg.norm_eps).astype(
         blk["router"].dtype).reshape(b * S, D)
     with jax.named_scope("router"):
         idx, weights = lm.route(blk, h, cfg)
@@ -400,7 +379,7 @@ def _head(params, x, cfg: HybridConvConfig):
     -> logits ``[b,V]`` f32."""
     with jax.named_scope("head"):
         embed = params["embed"]
-        h = tr._rmsnorm(x, params["final_ln"], cfg.norm_eps).astype(
+        h = parts.rmsnorm(x, params["final_ln"], cfg.norm_eps).astype(
             embed.dtype)
         return jnp.einsum("bd,vd->bv", h, embed,
                           preferred_element_type=jnp.float32)
@@ -441,7 +420,7 @@ def prefill(params, tokens, cfg: HybridConvConfig):
     from ..ops import flash_attention
 
     b, P = tokens.shape
-    cos, sin = bd._rotary(cfg, jnp.arange(P))
+    cos, sin = parts.rotary(cfg.head_dim, cfg.rope_theta, jnp.arange(P))
     nothing = jnp.zeros((b, cfg.conv_L_cache - 1, cfg.hidden_size),
                         params["embed"].dtype)
 
@@ -457,7 +436,7 @@ def prefill(params, tokens, cfg: HybridConvConfig):
             o = flash_attention(q, k, v, causal=True)
             return _attention_out(blk, x, o), held
 
-    x = bd._embed(params, tokens, cfg).astype(jnp.float32)
+    x = parts.embed(params, tokens, cfg).astype(jnp.float32)
     x, state, rows, chose = _layers(
         params, cfg, x, _empty_state(params, cfg, b, P + cfg.new_tokens), op)
     return _head(params, x[:, -1], cfg), state, rows, chose
@@ -471,7 +450,8 @@ def decode_step(params, state, token, pos, cfg: HybridConvConfig):
     that the next position reads, over the oldest -> ``(logits [b,V] f32,
     the state, rows routed [b, expert layers, E], the experts chosen [b,
     expert layers, k])``."""
-    cos, sin = bd._rotary(cfg, jnp.reshape(pos, (1,)))
+    cos, sin = parts.rotary(cfg.head_dim, cfg.rope_theta,
+                            jnp.reshape(pos, (1,)))
     group = cfg.num_attention_heads // cfg.num_key_value_heads
     scale = 1.0 / math.sqrt(cfg.head_dim)
 
@@ -497,7 +477,7 @@ def decode_step(params, state, token, pos, cfg: HybridConvConfig):
                                values).reshape(q.shape)
             return _attention_out(blk, x, o), held
 
-    x = bd._embed(params, token[:, None], cfg).astype(jnp.float32)
+    x = parts.embed(params, token[:, None], cfg).astype(jnp.float32)
     x, state, rows, chose = _layers(params, cfg, x, state, op)
     return _head(params, x[:, 0], cfg), state, rows, chose[:, 0]
 
@@ -540,7 +520,7 @@ def generate(params, tokens, cfg: HybridConvConfig):
                                                  P + i - 1, 1)
         return (state, out, routes, token,
                 jnp.where(i == 1, logits, second), logits, rows + routed,
-                touched + bd._touched(routed), steps + 1)
+                touched + parts.touched(routed), steps + 1)
 
     out = jnp.zeros((b, G), jnp.int32).at[:, 0].set(token)
     with jax.named_scope("decode"):

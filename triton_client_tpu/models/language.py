@@ -322,11 +322,10 @@ class _LazyBlock:
     """``_LazyTransformer``'s lazy first-request init for the blocks that
     hold their weights in bfloat16 (``module``: models/latent_moe.py,
     models/block_diffusion.py, models/looped.py, models/hybrid_conv.py,
-    models/sparse_latent.py):
-    mesh from ``tr.serve_mesh``, weights drawn
-    on the device leaf by leaf by ``module.init_params``, one jitted
-    ``step(params, tokens, cfg)``.  Nothing is imported or allocated before
-    the first call."""
+    models/sparse_latent.py; ``_served_block`` serves them): mesh from
+    ``tr.serve_mesh``, weights drawn on the device leaf by leaf by
+    ``module.init_params``, one jitted ``step(params, tokens, cfg)``.
+    Nothing is imported or allocated before the first call."""
 
     def __init__(self, cfg, model_name: str, module: str, step: str):
         self.cfg = cfg
@@ -397,6 +396,48 @@ def _counting_model(config, fn, tokens_per_row: int) -> JaxModel:
     return model
 
 
+def _served_block(name: str, module: str, step: str, cfg, outputs,
+                  tokens_per_row: int, counters="counters",
+                  max_batch_size: int = 16) -> JaxModel:
+    """A drawn block served as the model ``name``: INT32 INPUT_IDS
+    [seq_len] -> ``outputs``, each ``(name, datatype, dims, key)``: ``key``
+    of the step's answer (a dict's key, a tuple's index).  ``counters`` is
+    where the answer holds the device counters (the key of their dict, or
+    ``{counter: key}``); they go to ``ModelStats`` with ``tokens_per_row``,
+    the tokens a row of the batch passes the expert layers with.  The
+    batcher's buckets are ``max_batch_size`` and its half.  The step
+    is ``module.step(params, tokens, cfg)``, one program a signature
+    (``_LazyBlock``), and the cost analysis of a signature reads the program
+    that ran it: it does not trace, lower and load the step a second time
+    from ``fn`` (``costs.analyze_jax_callable``; 2.4-3.8 s a bucket at
+    ``sdar_30b_a3b``)."""
+    import importlib
+
+    block = importlib.import_module(f"{__package__}.{module}")
+    config = make_config(
+        name,
+        inputs=[("INPUT_IDS", "INT32", [cfg.seq_len])],
+        outputs=[spec[:3] for spec in outputs],
+        max_batch_size=max_batch_size,
+        preferred_batch_sizes=[max_batch_size // 2, max_batch_size],
+        max_queue_delay_us=2000,
+        instance_kind="KIND_TPU",
+        parameters={"flops_per_inference": str(
+            block.flops_per_inference(cfg))},
+    )
+    run = _LazyBlock(cfg, name, module, step)
+
+    def fn(INPUT_IDS):
+        out = run(INPUT_IDS)
+        counted = (out[counters] if isinstance(counters, str)
+                   else {c: out[key] for c, key in counters.items()})
+        return {**{spec[0]: out[spec[3]] for spec in outputs},
+                **{DEVICE_COUNTER + c: array for c, array in counted.items()}}
+
+    fn.lower = lambda INPUT_IDS: run.lower(INPUT_IDS)
+    return _counting_model(config, fn, tokens_per_row)
+
+
 def make_kimi_k2(cfg=None) -> JaxModel:
     """Kimi-K2-Instruct's block on one chip's share of an EP32 prefill pool
     (``latent_moe.KIMI_K2_EP32_SHARE``; a test passes a tiny ``cfg``):
@@ -405,25 +446,10 @@ def make_kimi_k2(cfg=None) -> JaxModel:
     logits (and a cache handed on, which this model does not keep)."""
     if cfg is None:
         from .latent_moe import KIMI_K2_EP32_SHARE as cfg
-    from .latent_moe import flops_per_inference
-
-    config = make_config(
-        "kimi_k2",
-        inputs=[("INPUT_IDS", "INT32", [cfg.seq_len])],
-        outputs=[("LOGITS", "FP32", [cfg.vocab_size])],
-        max_batch_size=2,
-        preferred_batch_sizes=[1, 2],
-        max_queue_delay_us=2000,
-        instance_kind="KIND_TPU",
-        parameters={"flops_per_inference": str(flops_per_inference(cfg))},
-    )
-    run = _LazyBlock(cfg, "kimi_k2", "latent_moe", "forward")
-
-    def fn(INPUT_IDS):
-        logits, rows = run(INPUT_IDS)
-        return {"LOGITS": logits, DEVICE_COUNTER + "expert_rows": rows}
-
-    return _counting_model(config, fn, cfg.seq_len)
+    return _served_block(
+        "kimi_k2", "latent_moe", "forward", cfg,
+        [("LOGITS", "FP32", [cfg.vocab_size], 0)], cfg.seq_len,
+        counters={"expert_rows": 1}, max_batch_size=2)
 
 
 def make_sdar_30b_a3b(cfg=None) -> JaxModel:
@@ -439,43 +465,20 @@ def make_sdar_30b_a3b(cfg=None) -> JaxModel:
     completion that does not stream."""
     if cfg is None:
         from .block_diffusion import SDAR_30B_A3B_STAGE as cfg
-    from .block_diffusion import flops_per_inference
-
     G = cfg.new_tokens
-    config = make_config(
-        "sdar_30b_a3b",
-        inputs=[("INPUT_IDS", "INT32", [cfg.seq_len])],
-        outputs=[("TOKENS", "INT32", [G]), ("COMMIT_PASS", "INT32", [G]),
-                 ("LOGITS", "FP32", [2, cfg.vocab_size]),
-                 ("ROUTES", "INT32", [2, cfg.num_hidden_layers,
-                                      cfg.num_experts_per_tok])],
-        max_batch_size=16,
-        preferred_batch_sizes=[8, 16],
-        max_queue_delay_us=2000,
-        instance_kind="KIND_TPU",
-        parameters={"flops_per_inference": str(flops_per_inference(cfg))},
-    )
-    run = _LazyBlock(cfg, "sdar_30b_a3b", "block_diffusion", "generate")
     # every token of a request passes the expert layers once in the prefill
     # or once in each pass of its block and once more, final, riding the
     # next block's first pass, which the last block's tokens never do (the
     # published rule; a threshold that ends a block early makes this an
     # upper bound)
-    tokens_per_row = (cfg.seq_len + G * (cfg.denoising_steps + 1)
-                      - cfg.block_length)
-
-    def fn(INPUT_IDS):
-        out = run(INPUT_IDS)
-        return {"TOKENS": out["tokens"], "COMMIT_PASS": out["commit_pass"],
-                "LOGITS": out["logits"], "ROUTES": out["routes"],
-                **{DEVICE_COUNTER + name: array
-                   for name, array in out["counters"].items()}}
-
-    # the cost analysis of a signature reads the program that ran it, and
-    # does not trace, lower and load the loop a second time from ``fn``
-    # (``costs.analyze_jax_callable``): 2.4-3.8 s a bucket of set-up
-    fn.lower = lambda INPUT_IDS: run.lower(INPUT_IDS)
-    return _counting_model(config, fn, tokens_per_row)
+    return _served_block(
+        "sdar_30b_a3b", "block_diffusion", "generate", cfg,
+        [("TOKENS", "INT32", [G], "tokens"),
+         ("COMMIT_PASS", "INT32", [G], "commit_pass"),
+         ("LOGITS", "FP32", [2, cfg.vocab_size], "logits"),
+         ("ROUTES", "INT32", [2, cfg.num_hidden_layers,
+                              cfg.num_experts_per_tok], "routes")],
+        cfg.seq_len + G * (cfg.denoising_steps + 1) - cfg.block_length)
 
 
 def make_ouro_2_6b(cfg=None) -> JaxModel:
@@ -490,31 +493,13 @@ def make_ouro_2_6b(cfg=None) -> JaxModel:
     tokens: a completion that does not stream."""
     if cfg is None:
         from .looped import OURO_2_6B as cfg
-    from .looped import flops_per_inference
-
     G = cfg.new_tokens
-    config = make_config(
-        "ouro_2_6b",
-        inputs=[("INPUT_IDS", "INT32", [cfg.seq_len])],
-        outputs=[("TOKENS", "INT32", [G]),
-                 ("LOGITS", "FP32", [2, cfg.vocab_size]),
-                 ("EXIT_PDF", "FP32", [2, cfg.total_ut_steps])],
-        max_batch_size=16,
-        preferred_batch_sizes=[8, 16],
-        max_queue_delay_us=2000,
-        instance_kind="KIND_TPU",
-        parameters={"flops_per_inference": str(flops_per_inference(cfg))},
-    )
-    run = _LazyBlock(cfg, "ouro_2_6b", "looped", "generate")
-
-    def fn(INPUT_IDS):
-        out = run(INPUT_IDS)
-        return {"TOKENS": out["tokens"], "LOGITS": out["logits"],
-                "EXIT_PDF": out["exit_pdf"],
-                **{DEVICE_COUNTER + name: array
-                   for name, array in out["counters"].items()}}
-
-    return _counting_model(config, fn, cfg.seq_len + G - 1)
+    return _served_block(
+        "ouro_2_6b", "looped", "generate", cfg,
+        [("TOKENS", "INT32", [G], "tokens"),
+         ("LOGITS", "FP32", [2, cfg.vocab_size], "logits"),
+         ("EXIT_PDF", "FP32", [2, cfg.total_ut_steps], "exit_pdf")],
+        cfg.seq_len + G - 1)
 
 
 def make_lfm2_8b_a1b(cfg=None) -> JaxModel:
@@ -526,40 +511,20 @@ def make_lfm2_8b_a1b(cfg=None) -> JaxModel:
     reads what the prefill handed over of both kinds of state; the last,
     which has read every cached key) and INT32 ROUTES [P + G - 1, expert
     layers, experts a token] (the experts every position of prompt and
-    answer but the last chose, for a reference that recomputes the rows).  One request is one prompt,
-    answered whole by ``G`` tokens: a completion that does not stream."""
+    answer but the last chose, for a reference that recomputes the rows).
+    One request is one prompt, answered whole by ``G`` tokens: a completion
+    that does not stream."""
     if cfg is None:
         from .hybrid_conv import LFM2_8B_A1B_STAGE as cfg
-    from .hybrid_conv import flops_per_inference
-
     G = cfg.new_tokens
-    config = make_config(
-        "lfm2_8b_a1b",
-        inputs=[("INPUT_IDS", "INT32", [cfg.seq_len])],
-        outputs=[("TOKENS", "INT32", [G]),
-                 ("LOGITS", "FP32", [3, cfg.vocab_size]),
-                 ("ROUTES", "INT32", [cfg.seq_len + G - 1,
-                                      cfg.n_expert_layers,
-                                      cfg.num_experts_per_tok])],
-        max_batch_size=16,
-        preferred_batch_sizes=[8, 16],
-        max_queue_delay_us=2000,
-        instance_kind="KIND_TPU",
-        parameters={"flops_per_inference": str(flops_per_inference(cfg))},
-    )
-    run = _LazyBlock(cfg, "lfm2_8b_a1b", "hybrid_conv", "generate")
-
-    def fn(INPUT_IDS):
-        out = run(INPUT_IDS)
-        return {"TOKENS": out["tokens"], "LOGITS": out["logits"],
-                "ROUTES": out["routes"],
-                **{DEVICE_COUNTER + name: array
-                   for name, array in out["counters"].items()}}
-
-    # the cost analysis reads the program that ran (``make_sdar_30b_a3b``)
-    fn.lower = lambda INPUT_IDS: run.lower(INPUT_IDS)
     # every token of prompt and answer but the last passes the expert layers
-    return _counting_model(config, fn, cfg.seq_len + G - 1)
+    return _served_block(
+        "lfm2_8b_a1b", "hybrid_conv", "generate", cfg,
+        [("TOKENS", "INT32", [G], "tokens"),
+         ("LOGITS", "FP32", [3, cfg.vocab_size], "logits"),
+         ("ROUTES", "INT32", [cfg.seq_len + G - 1, cfg.n_expert_layers,
+                              cfg.num_experts_per_tok], "routes")],
+        cfg.seq_len + G - 1)
 
 
 def make_hy4_preview(cfg=None) -> JaxModel:
@@ -577,35 +542,16 @@ def make_hy4_preview(cfg=None) -> JaxModel:
     if cfg is None:
         from .sparse_latent import HY4_PREVIEW_EP32_SHARE as cfg
     from ..ops.sparse_attention import words
-    from .sparse_latent import flops_per_inference
 
     S = cfg.seq_len
-    config = make_config(
-        "hy4_preview",
-        inputs=[("INPUT_IDS", "INT32", [S])],
-        outputs=[("TOKENS", "INT32", [2]),
-                 ("LOGITS", "FP32", [2, cfg.vocab_size]),
-                 ("CHOSEN", "INT32", [cfg.full_blocks, S, words(S)]),
-                 ("ROUTES", "INT32", [cfg.expert_blocks, S,
-                                      cfg.num_experts_per_tok])],
-        max_batch_size=2,
-        preferred_batch_sizes=[1, 2],
-        max_queue_delay_us=2000,
-        instance_kind="KIND_TPU",
-        parameters={"flops_per_inference": str(flops_per_inference(cfg))},
-    )
-    run = _LazyBlock(cfg, "hy4_preview", "sparse_latent", "forward")
-
-    def fn(INPUT_IDS):
-        out = run(INPUT_IDS)
-        return {"TOKENS": out["tokens"], "LOGITS": out["logits"],
-                "CHOSEN": out["chosen"], "ROUTES": out["routes"],
-                **{DEVICE_COUNTER + name: array
-                   for name, array in out["counters"].items()}}
-
-    # the cost analysis reads the program that ran (``make_sdar_30b_a3b``)
-    fn.lower = lambda INPUT_IDS: run.lower(INPUT_IDS)
-    return _counting_model(config, fn, cfg.seq_len)
+    return _served_block(
+        "hy4_preview", "sparse_latent", "forward", cfg,
+        [("TOKENS", "INT32", [2], "tokens"),
+         ("LOGITS", "FP32", [2, cfg.vocab_size], "logits"),
+         ("CHOSEN", "INT32", [cfg.full_blocks, S, words(S)], "chosen"),
+         ("ROUTES", "INT32", [cfg.expert_blocks, S,
+                              cfg.num_experts_per_tok], "routes")],
+        S, max_batch_size=2)
 
 
 # Mixture-of-experts scorer: serves the flagship stack's MoE FFN path

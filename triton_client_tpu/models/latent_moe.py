@@ -35,7 +35,6 @@ the absorbed form (``W_kb`` folded into q, ``W_vb`` into the output).
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -43,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import transformer as tr
+from . import parts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,42 +131,7 @@ KIMI_K2_EP32_SHARE = LatentMoEConfig(
 # Weights
 # ---------------------------------------------------------------------------
 
-_OUTER = 1 << 16  # the "layer" of embedding, final norm and head
-
-#: every matrix of a layer: (leaf, key index).  The index, not the order
-#: here, decides a leaf's key, so a new leaf never moves an old one's values.
-_LEAF_KEYS = {
-    "w_qa": 0, "w_qb_nope": 1, "w_qb_rope": 2, "w_kva": 3, "w_kb": 4,
-    "w_vb": 5, "w_o": 6, "w_gate": 7, "w_up": 8, "w_down": 9, "router": 10,
-    "router_bias": 11, "we_gate": 12, "we_up": 13, "we_down": 14,
-    "ws_gate": 15, "ws_up": 16, "ws_down": 17, "embed": 18, "head": 19,
-    # models/block_diffusion.py's attention (grouped-query heads)
-    "w_q": 20, "w_k": 21, "w_v": 22,
-    # models/looped.py's exit gate
-    "exit_gate": 23, "exit_gate_bias": 24,
-    # models/hybrid_conv.py's gated short convolution
-    "w_in": 25, "conv_w": 26, "w_out": 27,
-    # models/sparse_latent.py: the attention's gate and sinks, the indexer,
-    # the four streams' mixing, the multi-token-prediction module's input
-    "w_g": 28, "sink": 29, "w_qi": 30, "w_ki": 31, "w_wi": 32,
-    "hc_phi": 33, "hc_alpha": 34, "hc_bias": 35, "eh_proj": 36,
-}
-
-#: the matrices ``TRITON_TPU_QUANT=int8`` stores as int8 (MLA and experts),
-#: with the axes each contracts over: one scale an output channel
-_INT8_CONTRACT = {
-    "w_qa": (0,), "w_qb_nope": (0,), "w_qb_rope": (0,), "w_kva": (0,),
-    "w_kb": (0,), "w_vb": (0,), "w_o": (0, 1),
-    "w_q": (0,), "w_k": (0,), "w_v": (0,),
-    "w_in": (0,), "w_out": (0,),
-    "w_g": (0,), "w_qi": (0,), "w_ki": (0,), "eh_proj": (0,),
-    "w_gate": (0,), "w_up": (0,), "w_down": (0,),
-    "we_gate": (1,), "we_up": (1,), "we_down": (1,),
-    "ws_gate": (0,), "ws_up": (0,), "ws_down": (0,),
-}
-
-
-def _leaf_shapes(cfg: LatentMoEConfig, dense: bool) -> Dict[str, Tuple]:
+def leaf_shapes(cfg: LatentMoEConfig, dense: bool) -> Dict[str, Tuple]:
     """``{leaf: (shape, scale of the normal draw)}`` of one layer; a routed
     expert's leaves are per expert (the leading axis is added by the draw)."""
     D, H = cfg.hidden_size, cfg.num_attention_heads
@@ -202,91 +166,33 @@ def _leaf_shapes(cfg: LatentMoEConfig, dense: bool) -> Dict[str, Tuple]:
     return shapes
 
 
-@functools.partial(jax.jit, static_argnames=("shape", "scale"))
-def _draw(key, shape, scale):
-    """An f32 normal draw times ``scale``, rounded to bfloat16 once."""
-    return (jax.random.normal(key, shape, jnp.float32)
-            * scale).astype(jnp.bfloat16)
-
-
-@functools.partial(jax.jit, static_argnames=("shape", "scale"))
-def _draw_experts(key, ids, shape, scale):
-    """An expert's weights follow its id, whichever chip holds it."""
-    return jax.vmap(lambda e: _draw(jax.random.fold_in(key, e), shape,
-                                    scale))(ids)
+def norms(cfg) -> Dict[str, int]:
+    """A latent-attention layer's norms and their widths."""
+    return {"ln_attn": cfg.hidden_size, "ln_q": cfg.q_lora_rank,
+            "ln_kv": cfg.kv_lora_rank, "ln_ffn": cfg.hidden_size}
 
 
 def _layer_params(cfg: LatentMoEConfig, layer: int) -> Dict[str, jax.Array]:
-    """One layer's leaves in bfloat16 (the selection bias upcast to f32),
-    drawn on the default device leaf by leaf."""
-    dense = layer < cfg.first_k_dense_replace
-    root = jax.random.fold_in(jax.random.PRNGKey(cfg.weights_seed), layer)
-    held = cfg.first_expert + jnp.arange(cfg.n_routed_experts)
-    out = {"ln_attn": jnp.ones((cfg.hidden_size,), jnp.bfloat16),
-           "ln_q": jnp.ones((cfg.q_lora_rank,), jnp.bfloat16),
-           "ln_kv": jnp.ones((cfg.kv_lora_rank,), jnp.bfloat16),
-           "ln_ffn": jnp.ones((cfg.hidden_size,), jnp.bfloat16)}
-    for name, (shape, scale) in _leaf_shapes(cfg, dense).items():
-        key = jax.random.fold_in(root, _LEAF_KEYS[name])
-        if name.startswith("we_"):
-            out[name] = _draw_experts(key, held, shape, scale)
-        elif name == "router_bias":
-            # a bfloat16 value like every leaf, added to f32 scores
-            out[name] = _draw(key, shape, scale).astype(jnp.float32)
-        else:
-            out[name] = _draw(key, shape, scale)
-    return out
-
-
-def quantize_weights(layer: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
-    """Weight-only int8 storage of a layer's MLA and expert matrices
-    (symmetric, one f32 scale an output channel, as
-    ``tr.quantize_layer_weights``); norms, router and bias stay as drawn."""
-    out = dict(layer)
-    for name, axes in _INT8_CONTRACT.items():
-        if name not in layer:
-            continue
-        w = layer[name].astype(jnp.float32)
-        amax = jnp.max(jnp.abs(w), axis=axes, keepdims=True)
-        scale = jnp.maximum(amax, 1e-12) / 127.0
-        out[name] = jnp.clip(jnp.round(w / scale), -127, 127).astype(jnp.int8)
-        out[name + "_scale"] = scale
-    return out
+    """One layer's leaves in bfloat16 (the selection bias upcast to f32)."""
+    return parts.draw_layer(
+        cfg.weights_seed, layer,
+        leaf_shapes(cfg, layer < cfg.first_k_dense_replace), norms(cfg),
+        cfg.first_expert + jnp.arange(cfg.n_routed_experts),
+        ("router_bias",))
 
 
 def init_params(cfg: LatentMoEConfig, quantized: bool = False) -> Dict[str, Any]:
     """``{"embed", "final_ln", "head", "dense": [layer...], "experts":
     stacked layer}``: the expert layers' leaves are stacked on a leading
-    axis for the scan, one leaf at a time."""
-    prep = jax.jit(quantize_weights) if quantized else (lambda layer: layer)
+    axis for the scan, a layer at a time."""
+    prep = jax.jit(parts.quantize_weights) if quantized else (lambda x: x)
     n_dense = cfg.first_k_dense_replace
     dense = [prep(_layer_params(cfg, i)) for i in range(n_dense)]
-    layers = [prep(_layer_params(cfg, i))
-              for i in range(n_dense, cfg.num_hidden_layers)]
     experts = {}
-    for name in list(layers[0]):
-        experts[name] = jnp.stack([layer.pop(name) for layer in layers])
-    outer = jax.random.fold_in(jax.random.PRNGKey(cfg.weights_seed), _OUTER)
-    V, D = cfg.vocab_size, cfg.hidden_size
-    return {
-        "embed": _draw(jax.random.fold_in(outer, _LEAF_KEYS["embed"]),
-                       (V, D), 0.02),
-        "final_ln": jnp.ones((D,), jnp.bfloat16),
-        "head": _draw(jax.random.fold_in(outer, _LEAF_KEYS["head"]),
-                      (D, V), 0.02),
-        "dense": dense,
-        "experts": experts,
-    }
-
-
-def _w(blk, name):
-    """A matrix as it is held (bfloat16 when serving), dequantised on the
-    fly where it is stored as int8 (``decode._w``'s form)."""
-    w = blk[name]
-    scale = blk.get(name + "_scale")
-    if scale is None:
-        return w
-    return w.astype(jnp.bfloat16) * scale.astype(jnp.bfloat16)
+    for i in range(cfg.n_expert_layers):
+        parts.stack(experts, prep(_layer_params(cfg, n_dense + i)), i,
+                    cfg.n_expert_layers)
+    return dict(parts.outer_params(cfg), dense=dense, experts=experts)
 
 
 # ---------------------------------------------------------------------------
@@ -337,61 +243,39 @@ def _rotary(cfg: LatentMoEConfig, positions):
     return jnp.cos(ang) * multiplier, jnp.sin(ang) * multiplier
 
 
-def _rotate(x, cos, sin):
-    """Half-split pairs, the zoo's layout: ``x[..., :h]`` with ``x[..., h:]``;
-    ``cos``/``sin`` broadcast against ``x[..., :h]``."""
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
 # ---------------------------------------------------------------------------
 # The block
 # ---------------------------------------------------------------------------
 
-def _gated(g, u, limit: Optional[float]):
-    """``silu(g) * u`` in f32; with a ``limit``, ``silu(min(g, limit)) *
-    clip(u, -limit, limit)``."""
-    if limit is not None:
-        g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
-    return jax.nn.silu(g) * u
-
-
-def _swiglu(h, gate, up, down, limit: Optional[float] = None):
-    g = jnp.dot(h, gate, preferred_element_type=jnp.float32)
-    u = jnp.dot(h, up, preferred_element_type=jnp.float32)
-    a = _gated(g, u, limit).astype(h.dtype)
-    return jnp.dot(a, down, preferred_element_type=jnp.float32)
-
-
-def _latents(blk, h, cfg: LatentMoEConfig, cos, sin):
+def latents(blk, h, cfg: LatentMoEConfig, cos, sin):
     """The two low-rank paths of a row of tokens ``h [..., D]``: the query's
     two parts per head, the normed kv latent and the rotated shared key."""
     with jax.named_scope("q_proj"):
-        c_q = tr._rmsnorm(jnp.dot(h, _w(blk, "w_qa")), blk["ln_q"],
-                          cfg.rms_norm_eps)
-        q_nope = jnp.einsum("...sr,rhk->...hsk", c_q, _w(blk, "w_qb_nope"))
-        q_rope = jnp.einsum("...sr,rhk->...hsk", c_q, _w(blk, "w_qb_rope"))
+        c_q = parts.rmsnorm(jnp.dot(h, parts.w(blk, "w_qa")), blk["ln_q"],
+                            cfg.rms_norm_eps)
+        q_nope = jnp.einsum("...sr,rhk->...hsk", c_q,
+                            parts.w(blk, "w_qb_nope"))
+        q_rope = jnp.einsum("...sr,rhk->...hsk", c_q,
+                            parts.w(blk, "w_qb_rope"))
     with jax.named_scope("kv_proj"):
-        kva = jnp.dot(h, _w(blk, "w_kva"))
-        c_kv = tr._rmsnorm(kva[..., :cfg.kv_lora_rank], blk["ln_kv"],
-                           cfg.rms_norm_eps)
+        kva = jnp.dot(h, parts.w(blk, "w_kva"))
+        c_kv = parts.rmsnorm(kva[..., :cfg.kv_lora_rank], blk["ln_kv"],
+                             cfg.rms_norm_eps)
         k_rope = kva[..., cfg.kv_lora_rank:]
     with jax.named_scope("rope"):
-        q_rope = _rotate(q_rope, cos, sin)
-        k_rope = _rotate(k_rope, cos, sin)
+        q_rope = parts.rotate(q_rope, cos, sin)
+        k_rope = parts.rotate(k_rope, cos, sin)
     return q_nope, q_rope, c_kv, k_rope
 
 
 @jax.named_scope("mla")
 def _mla(blk, x, cfg: LatentMoEConfig, cos, sin):
     """Prefill: ``x [B,S,D]`` -> ``(x + attention, (c_kv, k_rope))``."""
-    h = tr._rmsnorm(x, blk["ln_attn"], cfg.rms_norm_eps)
-    q_nope, q_rope, c_kv, k_rope = _latents(blk, h, cfg, cos, sin)
+    h = parts.rmsnorm(x, blk["ln_attn"], cfg.rms_norm_eps)
+    q_nope, q_rope, c_kv, k_rope = latents(blk, h, cfg, cos, sin)
     with jax.named_scope("kv_proj"):
-        k_nope = jnp.einsum("bsc,chk->bhsk", c_kv, _w(blk, "w_kb"))
-        v = jnp.einsum("bsc,chk->bhsk", c_kv, _w(blk, "w_vb"))
+        k_nope = jnp.einsum("bsc,chk->bhsk", c_kv, parts.w(blk, "w_kb"))
+        v = jnp.einsum("bsc,chk->bhsk", c_kv, parts.w(blk, "w_vb"))
     with jax.named_scope("rope"):
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate(
@@ -403,14 +287,15 @@ def _mla(blk, x, cfg: LatentMoEConfig, cos, sin):
         o = flash_attention(q, k, v, causal=True,
                             sm_scale=softmax_scale(cfg))
     with jax.named_scope("out_proj"):
-        out = jnp.einsum("bhsk,hkd->bsd", o, _w(blk, "w_o"))
+        out = jnp.einsum("bhsk,hkd->bsd", o, parts.w(blk, "w_o"))
     return x + out, (c_kv, k_rope)
 
 
 @jax.named_scope("dense_ffn")
 def _dense_ffn(blk, x, cfg: LatentMoEConfig):
-    h = tr._rmsnorm(x, blk["ln_ffn"], cfg.rms_norm_eps)
-    y = _swiglu(h, _w(blk, "w_gate"), _w(blk, "w_up"), _w(blk, "w_down"))
+    h = parts.rmsnorm(x, blk["ln_ffn"], cfg.rms_norm_eps)
+    y = parts.swiglu(h, parts.w(blk, "w_gate"), parts.w(blk, "w_up"),
+                     parts.w(blk, "w_down"))
     return x + y.astype(x.dtype)
 
 
@@ -502,10 +387,10 @@ def _grouped_swiglu(blk, rows, sizes, limit: Optional[float] = None):
         sizes = lax.dynamic_update_slice(
             jnp.zeros(blk["we_gate"].shape[:1], sizes.dtype), sizes, (first,))
     with jax.named_scope("experts"):
-        g = _grouped_matmul(rows, _w(blk, "we_gate"), sizes)
-        u = _grouped_matmul(rows, _w(blk, "we_up"), sizes)
-        a = _gated(g, u, limit).astype(rows.dtype)
-        return _grouped_matmul(a, _w(blk, "we_down"), sizes)
+        g = _grouped_matmul(rows, parts.w(blk, "we_gate"), sizes)
+        u = _grouped_matmul(rows, parts.w(blk, "we_up"), sizes)
+        a = parts.gated(g, u, limit).astype(rows.dtype)
+        return _grouped_matmul(a, parts.w(blk, "we_down"), sizes)
 
 
 def _all_held(blk, h, idx, weights, cfg, batch: int,
@@ -618,33 +503,21 @@ def _moe_ffn(blk, x, cfg: LatentMoEConfig):
     """``x [B,S,D]`` -> ``(x + held experts' part + shared expert,
     rows routed to each held expert by batch row [B,E])``."""
     B, S, D = x.shape
-    h = tr._rmsnorm(x, blk["ln_ffn"], cfg.rms_norm_eps).reshape(B * S, D)
+    h = parts.rmsnorm(x, blk["ln_ffn"], cfg.rms_norm_eps).reshape(B * S, D)
     with jax.named_scope("router"):
         idx, weights = route(blk, h, cfg)
     y, rows = held_experts(blk, h, idx, weights, cfg, batch=B)
     with jax.named_scope("shared_expert"):
-        y = y + _swiglu(h, _w(blk, "ws_gate"), _w(blk, "ws_up"),
-                        _w(blk, "ws_down"))
+        y = y + parts.swiglu(h, parts.w(blk, "ws_gate"),
+                             parts.w(blk, "ws_up"), parts.w(blk, "ws_down"))
     with jax.named_scope("combine"):
         return x + y.astype(x.dtype).reshape(B, S, D), rows
-
-
-def _embed(params, tokens, cfg: LatentMoEConfig):
-    with jax.named_scope("embed"):
-        ids = jnp.clip(tokens, 0, cfg.vocab_size - 1)
-        return jnp.take(params["embed"], ids, axis=0)
-
-
-def _head(params, x_last, cfg: LatentMoEConfig):
-    with jax.named_scope("head"):
-        h = tr._rmsnorm(x_last, params["final_ln"], cfg.rms_norm_eps)
-        return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
 
 
 def _run(params, tokens, cfg: LatentMoEConfig, want_cache: bool):
     S = tokens.shape[1]
     cos, sin = _rotary(cfg, jnp.arange(S))
-    x = _embed(params, tokens, cfg)
+    x = parts.embed(params, tokens, cfg)
     latents = []
     for blk in params["dense"]:
         x, latent = _mla(blk, x, cfg, cos, sin)
@@ -663,7 +536,7 @@ def _run(params, tokens, cfg: LatentMoEConfig, want_cache: bool):
             jnp.concatenate([jnp.stack([lat[i] for lat in latents]),
                              scanned[i]]) if latents else scanned[i]
             for i in range(2))
-    return _head(params, x[:, -1], cfg), rows.transpose(1, 0, 2), cache
+    return parts.head(params, x[:, -1], cfg), rows.transpose(1, 0, 2), cache
 
 
 def prefill(params, tokens, cfg: LatentMoEConfig):
@@ -687,12 +560,12 @@ def _mla_decode(blk, x, c_kv_cache, k_rope_cache, pos, cfg, cos, sin):
     ``k_rope_cache [B,S,dr]`` (this token's latents written at ``pos``):
     ``W_kb`` is folded into the query and ``W_vb`` into the output, so
     attention runs over the latents and no per-head key or value exists."""
-    h = tr._rmsnorm(x, blk["ln_attn"], cfg.rms_norm_eps)[:, None]   # [B,1,D]
-    q_nope, q_rope, c_kv, k_rope = _latents(blk, h, cfg, cos, sin)
+    h = parts.rmsnorm(x, blk["ln_attn"], cfg.rms_norm_eps)[:, None]   # [B,1,D]
+    q_nope, q_rope, c_kv, k_rope = latents(blk, h, cfg, cos, sin)
     c_kv_cache = lax.dynamic_update_slice_in_dim(c_kv_cache, c_kv, pos, 1)
     k_rope_cache = lax.dynamic_update_slice_in_dim(k_rope_cache, k_rope,
                                                    pos, 1)
-    q_lat = jnp.einsum("bhsk,chk->bhsc", q_nope, _w(blk, "w_kb"))
+    q_lat = jnp.einsum("bhsk,chk->bhsc", q_nope, parts.w(blk, "w_kb"))
     scores = (jnp.einsum("bhsc,btc->bhst", q_lat, c_kv_cache,
                          preferred_element_type=jnp.float32)
               + jnp.einsum("bhsk,btk->bhst", q_rope, k_rope_cache,
@@ -701,8 +574,8 @@ def _mla_decode(blk, x, c_kv_cache, k_rope_cache, pos, cfg, cos, sin):
     seen = jnp.arange(c_kv_cache.shape[1]) <= pos
     p = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
     o_lat = jnp.einsum("bhst,btc->bhsc", p.astype(x.dtype), c_kv_cache)
-    o = jnp.einsum("bhsc,chk->bhsk", o_lat, _w(blk, "w_vb"))
-    out = jnp.einsum("bhsk,hkd->bsd", o, _w(blk, "w_o"))
+    o = jnp.einsum("bhsc,chk->bhsk", o_lat, parts.w(blk, "w_vb"))
+    out = jnp.einsum("bhsk,hkd->bsd", o, parts.w(blk, "w_o"))
     return x + out[:, 0], c_kv_cache, k_rope_cache
 
 
@@ -711,7 +584,7 @@ def decode_step(params, token, cache, pos, cfg: LatentMoEConfig):
     cache's sequence axis is as long as the caller allocated."""
     c_kv_all, k_rope_all = cache
     cos, sin = _rotary(cfg, jnp.reshape(pos, (1,)))
-    x = _embed(params, token, cfg)
+    x = parts.embed(params, token, cfg)
     n_dense = len(params["dense"])
     new_ckv, new_kr = [], []
     for i, blk in enumerate(params["dense"]):
@@ -732,7 +605,7 @@ def decode_step(params, token, cache, pos, cfg: LatentMoEConfig):
         (params["experts"], c_kv_all[n_dense:], k_rope_all[n_dense:]))
     cache = (jnp.concatenate([jnp.stack(new_ckv), ckv]),
              jnp.concatenate([jnp.stack(new_kr), kr]))
-    return _head(params, x, cfg), cache
+    return parts.head(params, x, cfg), cache
 
 
 # ---------------------------------------------------------------------------

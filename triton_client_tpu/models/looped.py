@@ -48,9 +48,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from . import block_diffusion as bd
-from . import latent_moe as lm
-from . import transformer as tr
+from . import parts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,15 +135,9 @@ def _leaf_shapes(cfg: LoopedConfig):
 
 
 def _layer_params(cfg: LoopedConfig, layer: int):
-    """One layer's leaves in bfloat16, drawn leaf by leaf under
-    ``latent_moe``'s keys; the four norms are ones."""
-    root = jax.random.fold_in(jax.random.PRNGKey(cfg.weights_seed), layer)
-    out = {name: jnp.ones((cfg.hidden_size,), jnp.bfloat16)
-           for name in _NORMS}
-    for name, (shape, scale) in _leaf_shapes(cfg).items():
-        out[name] = lm._draw(jax.random.fold_in(root, lm._LEAF_KEYS[name]),
-                             shape, scale)
-    return out
+    """One layer's leaves in bfloat16; the four norms are ones."""
+    return parts.draw_layer(cfg.weights_seed, layer, _leaf_shapes(cfg),
+                            dict.fromkeys(_NORMS, cfg.hidden_size))
 
 
 def init_params(cfg: LoopedConfig, quantized: bool = False) -> Dict[str, Any]:
@@ -153,29 +145,14 @@ def init_params(cfg: LoopedConfig, quantized: bool = False) -> Dict[str, Any]:
     "layers": leaves stacked for the scan}``.  A layer is drawn, written
     into the stacks in place and let go before the next one exists.
     Quantised (the int8 control), a layer's seven matrices are stored as
-    ``latent_moe.quantize_weights`` stores them."""
-    prep = jax.jit(lm.quantize_weights) if quantized else (lambda x: x)
+    ``parts.quantize_weights`` stores them."""
+    prep = jax.jit(parts.quantize_weights) if quantized else (lambda x: x)
     L = cfg.num_hidden_layers
     stacked = {}
     for i in range(L):
-        for name, leaf in prep(_layer_params(cfg, i)).items():
-            if i == 0:
-                stacked[name] = jnp.zeros((L,) + leaf.shape, leaf.dtype)
-            stacked[name] = bd._put(stacked[name], leaf[None], i)
-    outer = jax.random.fold_in(jax.random.PRNGKey(cfg.weights_seed),
-                               lm._OUTER)
-    V, D = cfg.vocab_size, cfg.hidden_size
-
-    def draw(name, shape):
-        return lm._draw(jax.random.fold_in(outer, lm._LEAF_KEYS[name]),
-                        shape, 0.02)
-
-    return {"embed": draw("embed", (V, D)),
-            "final_ln": jnp.ones((D,), jnp.bfloat16),
-            "exit_gate": draw("exit_gate", (D,)),
-            "exit_gate_bias": draw("exit_gate_bias", (1,)),
-            "head": draw("head", (D, V)),
-            "layers": stacked}
+        parts.stack(stacked, prep(_layer_params(cfg, i)), i, L)
+    return dict(parts.outer_params(cfg, exit_gate=(cfg.hidden_size,),
+                                   exit_gate_bias=(1,)), layers=stacked)
 
 
 # ---------------------------------------------------------------------------
@@ -185,38 +162,38 @@ def init_params(cfg: LoopedConfig, quantized: bool = False) -> Dict[str, Any]:
 def _qkv(blk, x, cfg: LoopedConfig, cos, sin):
     """``x [b,S,D]`` f32 -> q, k, v ``[b,H,S,dh]`` in the matrices' dtype,
     q and k rotated as the cache holds them."""
-    w_q = lm._w(blk, "w_q")
-    u = tr._rmsnorm(x, blk["ln_attn"], cfg.rms_norm_eps).astype(w_q.dtype)
+    w_q = parts.w(blk, "w_q")
+    u = parts.rmsnorm(x, blk["ln_attn"], cfg.rms_norm_eps).astype(w_q.dtype)
     q = jnp.einsum("bsd,dhk->bhsk", u, w_q)
-    k = jnp.einsum("bsd,dhk->bhsk", u, lm._w(blk, "w_k"))
-    v = jnp.einsum("bsd,dhk->bhsk", u, lm._w(blk, "w_v"))
+    k = jnp.einsum("bsd,dhk->bhsk", u, parts.w(blk, "w_k"))
+    v = jnp.einsum("bsd,dhk->bhsk", u, parts.w(blk, "w_v"))
     with jax.named_scope("rope"):
-        return lm._rotate(q, cos, sin), lm._rotate(k, cos, sin), v
+        return parts.rotate(q, cos, sin), parts.rotate(k, cos, sin), v
 
 
 def _attention_out(blk, x, o, cfg: LoopedConfig):
     """The heads' output ``o [b,H,S,dh]`` through the out projection,
     normed, joins the stream ``x``."""
-    a = jnp.einsum("bhsk,hkd->bsd", o, lm._w(blk, "w_o"),
+    a = jnp.einsum("bhsk,hkd->bsd", o, parts.w(blk, "w_o"),
                    preferred_element_type=jnp.float32)
     with jax.named_scope("sandwich_norm"):
-        return x + tr._rmsnorm(a, blk["ln_attn_out"], cfg.rms_norm_eps)
+        return x + parts.rmsnorm(a, blk["ln_attn_out"], cfg.rms_norm_eps)
 
 
 @jax.named_scope("ffn")
 def _ffn(blk, x, cfg: LoopedConfig):
-    w_gate = lm._w(blk, "w_gate")
-    n = tr._rmsnorm(x, blk["ln_ffn"], cfg.rms_norm_eps).astype(w_gate.dtype)
-    y = lm._swiglu(n, w_gate, lm._w(blk, "w_up"), lm._w(blk, "w_down"))
+    w_gate = parts.w(blk, "w_gate")
+    n = parts.rmsnorm(x, blk["ln_ffn"], cfg.rms_norm_eps).astype(w_gate.dtype)
+    y = parts.swiglu(n, w_gate, parts.w(blk, "w_up"), parts.w(blk, "w_down"))
     with jax.named_scope("sandwich_norm"):
-        return x + tr._rmsnorm(y, blk["ln_ffn_out"], cfg.rms_norm_eps)
+        return x + parts.rmsnorm(y, blk["ln_ffn_out"], cfg.rms_norm_eps)
 
 
 def _close_step(params, x, cfg: LoopedConfig):
     """What closes a loop step: the final norm, whose output is the next
     step's input, and the exit gate over it -> ``(x, g [b,S] f32)``."""
     with jax.named_scope("final_norm"):
-        x = tr._rmsnorm(x, params["final_ln"], cfg.rms_norm_eps)
+        x = parts.rmsnorm(x, params["final_ln"], cfg.rms_norm_eps)
     with jax.named_scope("exit_gate"):
         g = jnp.einsum("bsd,d->bs", x,
                        params["exit_gate"].astype(jnp.float32)) \
@@ -229,7 +206,7 @@ def _stack(params, cfg: LoopedConfig, tokens, cache, layer_fn):
     layer-steps; ``layer_fn(blk, x, cache, t, l) -> (x, cache)`` is the
     layer at step ``t``.  Returns ``(cache, the last position after each
     step's final norm [T,b,D] f32, the gate at every position [T,b,S])``."""
-    x = bd._embed(params, tokens, cfg).astype(jnp.float32)
+    x = parts.embed(params, tokens, cfg).astype(jnp.float32)
     layers = (jnp.arange(cfg.num_hidden_layers), params["layers"])
 
     def loop_step(carry, t):
@@ -284,7 +261,7 @@ def prefill(params, tokens, cfg: LoopedConfig):
     from ..ops import flash_attention
 
     b, P = tokens.shape
-    cos, sin = bd._rotary(cfg, jnp.arange(P))
+    cos, sin = parts.rotary(cfg.head_dim, cfg.rope_theta, jnp.arange(P))
     room = (cfg.total_ut_steps, cfg.num_hidden_layers, P + cfg.new_tokens,
             cfg.num_attention_heads, b, cfg.head_dim)
     cache = (jnp.zeros(room, _compute_dtype(params)),) * 2
@@ -305,7 +282,8 @@ def decode_step(params, cache, token, pos, cfg: LoopedConfig):
     """``token [b]`` at position ``pos`` through every loop step, each
     layer-step writing its key and value at ``pos`` and attending to its
     own cache up to there -> ``(cache, x [T,b,D], gates [T,b])``."""
-    cos, sin = bd._rotary(cfg, jnp.reshape(pos, (1,)))
+    cos, sin = parts.rotary(cfg.head_dim, cfg.rope_theta,
+                            jnp.reshape(pos, (1,)))
     scale = 1.0 / math.sqrt(cfg.head_dim)
     seen = jnp.arange(cache[0].shape[2]) <= pos
 

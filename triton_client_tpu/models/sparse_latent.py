@@ -2,8 +2,8 @@
 Hy4's block.
 
 The sixth block of the zoo, beside ``latent_moe.py``'s, whose latent
-projections, router, held-expert layer, draw and head it shares.  What it
-has that the others have not:
+projections, router and held-expert layer it shares.  What it has that the
+others have not:
 
 * **An indexer that picks the keys a query reads** (DeepSeek-V3.2's
   lightning indexer).  From the query latent ``c_q`` and the normed input
@@ -33,7 +33,7 @@ has that the others have not:
   ``X [B,S,n·D]``, a token's streams one after another; on a TPU each side
   of the mixing is one pass over them, a kernel of
   ``ops/stream_mixing.py``.
-* **SwiGLU clamped** at ``swiglu_limit`` (``latent_moe._gated``) in every
+* **SwiGLU clamped** at ``swiglu_limit`` (``parts.gated``) in every
   FFN.
 * **A multi-token-prediction module** (DeepSeek-V3's form): ``m_t = W_eh
   [RMSNorm(Emb(x_{t+1})); RMSNorm(h_t)]`` (``h_t`` the main stack's summed
@@ -61,7 +61,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import latent_moe as lm
-from . import transformer as tr
+from . import parts
 from ..ops import sparse_attention as sa
 from ..ops import stream_mixing as sm
 
@@ -231,7 +231,7 @@ def _leaf_shapes(cfg: SparseLatentConfig, block: int) -> Dict[str, Tuple]:
     fan = lambda k: 1.0 / math.sqrt(k)  # noqa: E731
     mlp, indexer = cfg.layer_kinds[block]
     # latent_moe's attention and FFN leaves under their names
-    shapes = lm._leaf_shapes(cfg, mlp == DENSE)
+    shapes = lm.leaf_shapes(cfg, mlp == DENSE)
     shapes.update({
         "w_g": ((D, H, dv), fan(D)),
         # a logit a head; the published initial value is 0, drawn here so
@@ -254,29 +254,22 @@ def _leaf_shapes(cfg: SparseLatentConfig, block: int) -> Dict[str, Tuple]:
 
 def _block_params(cfg: SparseLatentConfig, block: int) -> Dict[str, jax.Array]:
     """One block's leaves in bfloat16 (the selection bias and the mixing's
-    scalars upcast to f32), drawn leaf by leaf under ``latent_moe``'s keys
-    from ``fold_in(PRNGKey(weights_seed), block)``, an expert under its id;
-    norms are ones, the indexer key's LayerNorm bias zeros."""
-    root = jax.random.fold_in(jax.random.PRNGKey(cfg.weights_seed), block)
-    held = cfg.first_expert + jnp.arange(cfg.n_routed_experts)
-    ones = lambda k: jnp.ones((k,), jnp.bfloat16)  # noqa: E731
+    scalars upcast to f32), drawn from ``fold_in(PRNGKey(weights_seed),
+    block)``, an expert under its id; norms are ones, the indexer key's
+    LayerNorm bias zeros."""
     D = cfg.hidden_size
-    out = {"ln_attn": ones(D), "ln_q": ones(cfg.q_lora_rank),
-           "ln_kv": ones(cfg.kv_lora_rank), "ln_ffn": ones(D)}
     shapes = _leaf_shapes(cfg, block)
+    norms = lm.norms(cfg)
     if "w_ki" in shapes:
-        out.update(ln_ki=ones(cfg.index_head_dim),
-                   ln_ki_bias=jnp.zeros((cfg.index_head_dim,), jnp.bfloat16))
+        norms["ln_ki"] = cfg.index_head_dim
     if "eh_proj" in shapes:
-        out.update(ln_e=ones(D), ln_h=ones(D), ln_out=ones(D))
-    for name, (shape, scale) in shapes.items():
-        key = jax.random.fold_in(root, lm._LEAF_KEYS[name])
-        if name.startswith("we_"):
-            out[name] = lm._draw_experts(key, held, shape, scale)
-        elif name in ("router_bias", "hc_alpha", "hc_bias"):
-            out[name] = lm._draw(key, shape, scale).astype(jnp.float32)
-        else:
-            out[name] = lm._draw(key, shape, scale)
+        norms.update(ln_e=D, ln_h=D, ln_out=D)
+    out = parts.draw_layer(
+        cfg.weights_seed, block, shapes, norms,
+        cfg.first_expert + jnp.arange(cfg.n_routed_experts),
+        ("router_bias", "hc_alpha", "hc_bias"))
+    if "w_ki" in shapes:
+        out["ln_ki_bias"] = jnp.zeros((cfg.index_head_dim,), jnp.bfloat16)
     return out
 
 
@@ -296,25 +289,17 @@ def init_params(cfg: SparseLatentConfig, quantized: bool = False
                 ) -> Dict[str, Any]:
     """``{"embed", "final_ln", "head", "groups": [stacked leaves of a run],
     "mtp": the module's block (none without one)}``; a run's leaves are
-    stacked one leaf at a time."""
-    prep = jax.jit(lm.quantize_weights) if quantized else (lambda b: b)
+    stacked a block at a time."""
+    prep = jax.jit(parts.quantize_weights) if quantized else (lambda x: x)
     stacks = []
     for _, layers in groups(cfg):
-        blocks = [prep(_block_params(cfg, i)) for i in layers]
-        stacks.append({name: jnp.stack([b.pop(name) for b in blocks])
-                       for name in list(blocks[0])})
-    outer = jax.random.fold_in(jax.random.PRNGKey(cfg.weights_seed), lm._OUTER)
-    V, D = cfg.vocab_size, cfg.hidden_size
-    return {
-        "embed": lm._draw(jax.random.fold_in(outer, lm._LEAF_KEYS["embed"]),
-                          (V, D), 0.02),
-        "final_ln": jnp.ones((D,), jnp.bfloat16),
-        "head": lm._draw(jax.random.fold_in(outer, lm._LEAF_KEYS["head"]),
-                         (D, V), 0.02),
-        "groups": stacks,
-        "mtp": (prep(_block_params(cfg, cfg.num_hidden_layers))
-                if cfg.num_nextn_predict_layers else None),
-    }
+        stacks.append({})
+        for i, layer in enumerate(layers):
+            parts.stack(stacks[-1], prep(_block_params(cfg, layer)), i,
+                        len(layers))
+    return dict(parts.outer_params(cfg), groups=stacks,
+                mtp=(prep(_block_params(cfg, cfg.num_hidden_layers))
+                     if cfg.num_nextn_predict_layers else None))
 
 
 # ---------------------------------------------------------------------------
@@ -409,7 +394,7 @@ def _sublayer(blk, X, which: int, ln: str, cfg: SparseLatentConfig, dt, fn,
     with jax.named_scope("hc.pre"):
         pre, post, res = mixing(blk, X, which, cfg)
         u = sum(pre[..., i, None] * X[:, :, i] for i in range(n))
-        h = tr._rmsnorm(u, blk[ln], cfg.rms_norm_eps).astype(dt)
+        h = parts.rmsnorm(u, blk[ln], cfg.rms_norm_eps).astype(dt)
     y, extra = fn(h)
     with jax.named_scope("hc.post"):
         X = jnp.stack([sum(res[..., i, j, None] * X[:, :, j] for j in range(n))
@@ -421,19 +406,9 @@ def _sublayer(blk, X, which: int, ln: str, cfg: SparseLatentConfig, dt, fn,
 # The indexer and the choice of keys
 # ---------------------------------------------------------------------------
 
-def _rotary(cfg: SparseLatentConfig, positions):
-    """``(cos, sin)`` ``[len(positions), qk_rope_head_dim / 2]`` f32, the
-    default rotary at ``rope_theta``."""
-    half = cfg.qk_rope_head_dim // 2
-    inv_freq = 1.0 / cfg.rope_theta ** (
-        jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    return jnp.cos(ang), jnp.sin(ang)
-
-
 def _rope_part(x, cos, sin, dr: int):
     """``x [..., S, Di]`` with its first ``dr`` rotated (half-split pairs)."""
-    return jnp.concatenate([lm._rotate(x[..., :dr], cos, sin), x[..., dr:]],
+    return jnp.concatenate([parts.rotate(x[..., :dr], cos, sin), x[..., dr:]],
                            axis=-1)
 
 
@@ -491,9 +466,10 @@ def indexer(blk, h, c_q, cfg: SparseLatentConfig, cos, sin):
     B, S = h.shape[:2]
     dr, k = cfg.qk_rope_head_dim, cfg.index_k
     with jax.named_scope("dsa.indexer"):
-        iq = jnp.einsum("bsr,rhd->bshd", c_q, lm._w(blk, "w_qi"))
+        iq = jnp.einsum("bsr,rhd->bshd", c_q, parts.w(blk, "w_qi"))
         iq = _rope_part(iq.swapaxes(1, 2), cos, sin, dr).swapaxes(1, 2)
-        ik = jnp.dot(h, lm._w(blk, "w_ki"), preferred_element_type=jnp.float32)
+        ik = jnp.dot(h, parts.w(blk, "w_ki"),
+                     preferred_element_type=jnp.float32)
         mean = jnp.mean(ik, axis=-1, keepdims=True)
         var = jnp.mean((ik - mean) ** 2, axis=-1, keepdims=True)
         ik = ((ik - mean) * lax.rsqrt(var + cfg.index_norm_eps)
@@ -529,15 +505,15 @@ def _attention(blk, h, cfg: SparseLatentConfig, cos, sin, chosen):
     block's own indexer's choice where it has one (a full block), else
     ``chosen``, the full block's before it."""
     with jax.named_scope("mla"):
-        q_nope, q_rope, c_kv, k_rope = lm._latents(blk, h, cfg, cos, sin)
+        q_nope, q_rope, c_kv, k_rope = lm.latents(blk, h, cfg, cos, sin)
     if "w_qi" in blk:
-        c_q = tr._rmsnorm(jnp.dot(h, lm._w(blk, "w_qa")), blk["ln_q"],
-                          cfg.rms_norm_eps)
+        c_q = parts.rmsnorm(jnp.dot(h, parts.w(blk, "w_qa")), blk["ln_q"],
+                            cfg.rms_norm_eps)
         chosen = indexer(blk, h, c_q, cfg, cos, sin)
     with jax.named_scope("mla"):
         with jax.named_scope("kv_proj"):
-            k_nope = jnp.einsum("bsc,chk->bhsk", c_kv, lm._w(blk, "w_kb"))
-            v = jnp.einsum("bsc,chk->bhsk", c_kv, lm._w(blk, "w_vb"))
+            k_nope = jnp.einsum("bsc,chk->bhsk", c_kv, parts.w(blk, "w_kb"))
+            v = jnp.einsum("bsc,chk->bhsk", c_kv, parts.w(blk, "w_vb"))
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope[:, None], k_nope.shape[:3]
@@ -546,11 +522,11 @@ def _attention(blk, h, cfg: SparseLatentConfig, cos, sin, chosen):
         o = sa.sparse_attention(q, k, v, chosen[0], blk["sink"],
                                 sm_scale=1.0 / math.sqrt(cfg.qk_head_dim))
     with jax.named_scope("gate"):
-        g = jnp.einsum("bsd,dhk->bhsk", h, lm._w(blk, "w_g"))
+        g = jnp.einsum("bsd,dhk->bhsk", h, parts.w(blk, "w_g"))
         o = (jax.nn.sigmoid(g.astype(jnp.float32)) * o.astype(jnp.float32)
              ).astype(h.dtype)
     with jax.named_scope("out_proj"):
-        out = jnp.einsum("bhsk,hkd->bsd", o, lm._w(blk, "w_o"),
+        out = jnp.einsum("bhsk,hkd->bsd", o, parts.w(blk, "w_o"),
                          preferred_element_type=jnp.float32)
     return out, chosen
 
@@ -562,8 +538,9 @@ def _ffn(blk, h, mlp: str, cfg: SparseLatentConfig):
     limit = cfg.swiglu_limit
     if mlp == DENSE:
         with jax.named_scope("dense_ffn"):
-            return lm._swiglu(h, lm._w(blk, "w_gate"), lm._w(blk, "w_up"),
-                              lm._w(blk, "w_down"), limit), None
+            return parts.swiglu(h, parts.w(blk, "w_gate"),
+                                parts.w(blk, "w_up"), parts.w(blk, "w_down"),
+                                limit), None
     B, S, D = h.shape
     flat = h.reshape(B * S, D)
     with jax.named_scope("moe"):
@@ -572,9 +549,9 @@ def _ffn(blk, h, mlp: str, cfg: SparseLatentConfig):
         y, rows = lm.held_experts(blk, flat, idx, weights, cfg, batch=B,
                                   limit=limit)
         with jax.named_scope("shared_expert"):
-            y = y + lm._swiglu(flat, lm._w(blk, "ws_gate"),
-                               lm._w(blk, "ws_up"), lm._w(blk, "ws_down"),
-                               limit)
+            y = y + parts.swiglu(flat, parts.w(blk, "ws_gate"),
+                                 parts.w(blk, "ws_up"),
+                                 parts.w(blk, "ws_down"), limit)
     return y.reshape(B, S, D), (rows, idx.reshape(B, S, -1))
 
 
@@ -594,7 +571,7 @@ def _head(params, x, ln, cfg: SparseLatentConfig):
     """``x [B,D]`` f32 -> logits ``[B,V]`` f32: the head in f32
     (``enable_lm_head_fp32``)."""
     with jax.named_scope("head"):
-        h = tr._rmsnorm(x, ln, cfg.rms_norm_eps)
+        h = parts.rmsnorm(x, ln, cfg.rms_norm_eps)
         return jnp.dot(h, params["head"].astype(jnp.float32),
                        precision=lax.Precision.HIGHEST)
 
@@ -618,8 +595,9 @@ def forward(params, tokens, cfg: SparseLatentConfig):
     B, S = tokens.shape
     n = cfg.hc_mult
     dt = params["embed"].dtype
-    cos, sin = _rotary(cfg, jnp.arange(S))
-    e = lm._embed(params, tokens, cfg).astype(jnp.float32)
+    cos, sin = parts.rotary(cfg.qk_rope_head_dim, cfg.rope_theta,
+                            jnp.arange(S))
+    e = parts.embed(params, tokens, cfg).astype(jnp.float32)
     X = _streams(e, n)
     chosen, pairs, rows, routes, planes = None, [], [], [], []
     for (kind, layers), stack in zip(groups(cfg), params["groups"]):
@@ -649,15 +627,15 @@ def forward(params, tokens, cfg: SparseLatentConfig):
         mtp = params["mtp"]
         with jax.named_scope("mtp"):
             nxt = jnp.concatenate([tokens[:, 1:], token[:, None]], axis=1)
-            e = lm._embed(params, nxt, cfg).astype(jnp.float32)
+            e = parts.embed(params, nxt, cfg).astype(jnp.float32)
             m = jnp.concatenate(
-                [tr._rmsnorm(e, mtp["ln_e"], cfg.rms_norm_eps),
-                 tr._rmsnorm(h, mtp["ln_h"], cfg.rms_norm_eps)], axis=-1)
+                [parts.rmsnorm(e, mtp["ln_e"], cfg.rms_norm_eps),
+                 parts.rmsnorm(h, mtp["ln_h"], cfg.rms_norm_eps)], axis=-1)
             # two-dimensional, so that the product comes out laid out as
             # the streams are (a [B,S,D] one came out turned, and the
             # streams made from it took a copy)
             m = jnp.dot(m.reshape(B * S, -1).astype(dt),
-                        lm._w(mtp, "eh_proj"),
+                        parts.w(mtp, "eh_proj"),
                         preferred_element_type=jnp.float32)
             X, chosen, routed = block(mtp, _streams(m, n).reshape(B, S, -1),
                                       (SPARSE, FULL), cfg, dt, cos, sin, None)

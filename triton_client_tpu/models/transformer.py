@@ -45,6 +45,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import parallel
+from .parts import rmsnorm
 
 MESH_AXES = ("dp", "pp", "ep", "sp", "tp")
 
@@ -433,13 +434,6 @@ def _cast(w, dtype):
     return w.astype(dtype)
 
 
-@jax.named_scope("norm")
-def _rmsnorm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    r = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * r).astype(x.dtype) * scale.astype(x.dtype)
-
-
 def _rope(q, k, positions, theta):
     # q,k: [B, Hl, S, K]; positions: [S]
     Kd = q.shape[-1]
@@ -557,7 +551,7 @@ def _out_proj(blk, o):
 
 @jax.named_scope("attention")
 def _attn_apply(blk, x, cfg: TransformerConfig):
-    h = _rmsnorm(x, blk["ln1"], cfg.norm_eps)
+    h = rmsnorm(x, blk["ln1"], cfg.norm_eps)
     q, k, v = _qkv_proj(blk, h)
     Sc = x.shape[1]
     positions = lax.axis_index("sp") * Sc + jnp.arange(Sc)
@@ -567,7 +561,7 @@ def _attn_apply(blk, x, cfg: TransformerConfig):
 
 @jax.named_scope("ffn")
 def _ffn_apply(blk, x, cfg: TransformerConfig):
-    h = _rmsnorm(x, blk["ln2"], cfg.norm_eps)
+    h = rmsnorm(x, blk["ln2"], cfg.norm_eps)
     if cfg.moe:
         gate = jnp.einsum("bsd,de->bse", h.astype(jnp.float32),
                           blk["router"].astype(jnp.float32))
@@ -699,7 +693,7 @@ def _local_loss(params, tokens, labels, cfg: TransformerConfig,
     x = jnp.take(params["embed"].astype(cfg.dtype), tokens, axis=0)
     x_mbs = x.reshape(n_micro, mb, Sc, cfg.d_model)
     outs = _pipeline_apply(params, x_mbs, cfg)
-    h = _rmsnorm(outs, params["final_ln"], cfg.norm_eps)
+    h = rmsnorm(outs, params["final_ln"], cfg.norm_eps)
     logits = jnp.einsum("nbsd,dv->nbsv", h.astype(jnp.float32),
                         params["head"].astype(jnp.float32))
     logp = jax.nn.log_softmax(logits, axis=-1)
@@ -844,7 +838,7 @@ def make_forward(mesh: Mesh, cfg: TransformerConfig, n_micro: int = 1,
         outs = jnp.where(is_last, outs, 0.0).astype(jnp.float32)
         outs = lax.psum(outs, "pp").astype(cfg.dtype)
         with jax.named_scope("head"):
-            h = _rmsnorm(outs, params["final_ln"], cfg.norm_eps)
+            h = rmsnorm(outs, params["final_ln"], cfg.norm_eps)
             head = params["head"]
             if head_cols is not None:
                 head = head[:, :head_cols]
