@@ -141,3 +141,49 @@ def test_the_sparse_latent_step_fits_beside_its_weights(
         r"%(_hc_post_call[.\d]*) = f32\[16384,24576\]", text))
     assert len(pre) == len(post) == 8
     assert "ragged" not in text
+
+
+def test_the_window_moe_step_fits_beside_its_weights(
+        one_chip, no_compile_cache, monkeypatch):
+    """``window_moe.forward`` at Trinity-Mini's stage 0 for one prompt of
+    32,768: 8.61 GB of arguments and 3.72 GB of temporaries (6.51 GB with
+    the four expert layers unrolled as one period of the pattern, where the
+    one scan over every layer holds one layer's at a time), 12.3 GB
+    together.  The windowed kernel is one op, called by the five sliding
+    layers, and asks for 22 MiB of VMEM (the window's keys alone); the full
+    layer's looped kernel is one op too, and scopes 56.5 MiB at 32,768
+    (a head's K and V, 16 MiB, double-buffered); every grouped matmul is
+    the megablox kernel."""
+    import functools
+    import importlib
+    import re
+
+    import triton_client_tpu.ops as ops
+    from triton_client_tpu.models import latent_moe, window_moe
+
+    fa = importlib.import_module("triton_client_tpu.ops.flash_attention")
+    monkeypatch.setattr(ops, "flash_attention", functools.partial(
+        ops.flash_attention, force=True))
+    monkeypatch.setattr(latent_moe.jax, "default_backend", lambda: "tpu")
+    cfg = window_moe.TRINITY_MINI_STAGE
+    on_chip = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
+        s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        on_chip, jax.eval_shape(lambda: window_moe.init_params(cfg)))
+    tokens = on_chip(jax.ShapeDtypeStruct((1, cfg.seq_len), jnp.int32))
+    compiled = jax.jit(lambda p, t: window_moe.forward(p, t, cfg)).lower(
+        params, tokens).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(8.613e9, rel=1e-3)
+    assert 3.2e9 < memory.temp_size_in_bytes < 4.3e9
+    text = compiled.as_text()
+    for name in ("_window_call", "_flash_call"):
+        ops_named = set(re.findall(
+            rf"%({name}[.\d]*) = bf16\[32,32768,128\]", text))
+        assert len(ops_named) == 1, (name, ops_named)
+    window = fa._window_plan(32768, 128, 128, jnp.bfloat16, 2048)
+    full = fa._loop_plan(32768, 128, 128, jnp.bfloat16, True)
+    assert (window.vmem_bytes, full.vmem_bytes) == (23_068_672, 59_244_544)
+    for scoped in (window.vmem_bytes, full.vmem_bytes):
+        assert str(scoped) in text
+    assert "ragged" not in text

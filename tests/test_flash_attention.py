@@ -441,3 +441,159 @@ def test_grouped_head_gradients_match_reference():
         assert g.shape == w.shape
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
                                    atol=2e-4)
+
+
+# -- the windowed form ------------------------------------------------------
+
+@pytest.mark.parametrize("S,W,group,dv,dtype,tol", [
+    (40, 8, 2, 16, *_F32),         # S and W under one block of 128
+    (300, 128, 1, 16, *_F32),      # S not a multiple of the block; W = block
+    (300, 100, 2, 32, *_F32),      # W not a multiple of the block, dv != d
+    (300, 1000, 1, 16, *_F32),     # W >= S: the causal mask whole
+    (1100, 700, 2, 16, *_F32),     # blocks of 512, S padded, an edge of two
+    (2048, 1024, 4, 16, *_F32),    # W a multiple of the block: one edge
+    (2048, 1536, 2, 16, *_BF16),   # bf16 operands, a wide step of two
+], ids=["tiny", "odd-S-W=block", "odd-W", "W>=S", "edge-of-two",
+        "W=2-blocks", "bf16-wide"])
+def test_the_windowed_kernel_matches_the_reference(S, W, group, dv, dtype,
+                                                   tol):
+    """Row ``i`` sees keys ``i - W < j <= i``: the windowed form in the
+    interpreter against the reference with the same ``window``, at the
+    edges a walk can meet."""
+    H = 4
+    q = _rand((1, H, S, 16), dtype, 1)
+    k = _rand((1, H // group, S, 16), dtype, 2)
+    v = _rand((1, H // group, S, dv), dtype, 3)
+    got = flash_attention(q, k, v, causal=True, window=W, interpret=True)
+    want = flash_attention_reference(q, k, v, causal=True, window=W)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=tol,
+                               atol=tol)
+    # the window binds wherever it is shorter than the row
+    full = flash_attention_reference(q, k, v, causal=True)
+    assert np.allclose(np.asarray(want, np.float32),
+                       np.asarray(full, np.float32)) == (W >= S)
+    # off the chip, without the interpreter, the reference answers
+    plain = flash_attention(q, k, v, causal=True, window=W)
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(want))
+
+
+def test_a_key_outside_the_window_carries_no_weight():
+    """Changing the keys and values before position ``c`` moves no row
+    ``i`` with ``i - W + 1 >= c``, and every row before ``c``."""
+    S, W = 600, 200
+    c = S - 2 * W
+    q, k, v = (_rand((1, 2, S, 16), jnp.float32, s) for s in (1, 2, 3))
+    out = flash_attention(q, k, v, causal=True, window=W, interpret=True)
+    far = jnp.arange(S)[None, None, :, None] < c
+    moved = flash_attention(q, jnp.where(far, 100.0, k),
+                            jnp.where(far, -100.0, v), causal=True,
+                            window=W, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out[:, :, c + W - 1:]),
+                                  np.asarray(moved[:, :, c + W - 1:]))
+    assert not np.isclose(np.asarray(out[:, :, :c]),
+                          np.asarray(moved[:, :, :c])).any(axis=-1).any()
+
+
+@pytest.mark.parametrize("S,W,want", [
+    # (block, wide, padded S, span, walked, masked).  Trinity-Mini's
+    # sliding layers: 310 blocks of 512 x 512 walked over 64 q-blocks, not
+    # the 2,080 of the causal half; each program reads 2,560 keys
+    (32768, 2048, (512, 1536, 32768, 2560, 310, 124)),
+    (8192, 2048, (512, 1536, 8192, 2560, 70, 28)),
+    (1100, 700, (512, 512, 1536, 1536, 6, 6)),
+    (300, 1000, (128, 256, 384, 384, 6, 3)),
+    (40, 8, (128, 128, 128, 128, 1, 1)),
+], ids=["trinity", "8k", "edge-of-two", "W>=S", "tiny"])
+def test_the_windowed_forms_plan(S, W, want):
+    """What the windowed form walks at a shape, from the function the call
+    sizes itself with; a program's walk is its window's blocks alone."""
+    import importlib
+
+    fa = importlib.import_module("triton_client_tpu.ops.flash_attention")
+    plan = fa._window_plan(S, 128, 128, jnp.bfloat16, W)
+    assert plan[:6] == want
+    n = plan.seq_pad // plan.block
+    walks = [fa._window_blocks(i, W, plan.block) for i in range(n)]
+    assert sum(i - lo + 1 for i, (lo, _) in enumerate(walks)) == plan.walked
+    # the span a program reads holds its walk, from the first key it needs
+    for i, (lo, whole_from) in enumerate(walks):
+        assert lo <= whole_from <= i
+        assert (i - lo + 1) * plan.block <= plan.span
+        assert lo * plan.block <= max(0, i * plan.block - W + 1)
+        assert whole_from * plan.block >= (i + 1) * plan.block - W \
+            or whole_from == i
+    if (S, W) == (32768, 2048):
+        # 65,012,736 pairs attended of 81,264,640 walked: 80%
+        attended = sum(min(t + 1, W) for t in range(S))
+        assert attended == 65_012_736
+        assert plan.walked * plan.block ** 2 == 81_264_640
+        # a whole head's K and V are 16 MiB; the window's 1.25 MiB
+        assert plan.vmem_bytes == 23_068_672
+
+
+def test_a_window_cuts_the_plain_causal_mask_only():
+    q = _rand((1, 2, 64, 16), jnp.float32, 0)
+    for kw in ({"causal": False}, {"mask_block": 4}):
+        with pytest.raises(ValueError, match="window"):
+            flash_attention(q, q, q, window=8, interpret=True, **kw)
+
+
+def test_windowed_gradients_match_reference():
+    q = _rand((1, 4, 64, 16), jnp.float32, 1)
+    k = _rand((1, 2, 64, 16), jnp.float32, 2)
+    v = _rand((1, 2, 64, 16), jnp.float32, 3)
+
+    def loss(fn, **kw):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, causal=True, window=10,
+                                          **kw) ** 2)
+
+    got = jax.grad(loss(flash_attention, interpret=True), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(flash_attention_reference), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-4)
+
+
+def _lowering_digest(monkeypatch, *args):
+    """sha256 of ``_flash_call``'s lowering for the chip at ``args`` (q, k,
+    v shapes in bf16, then the static arguments), with each kernel's body
+    printed without source locations: the text names no line of any
+    file."""
+    import hashlib
+    import importlib
+
+    from jax._src import tpu_custom_call
+
+    def plain(module, **_):
+        return (module.operation.get_asm(enable_debug_info=False).encode(),
+                tpu_custom_call.tpu.private_has_communication(
+                    module.operation))
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", plain)
+    fa = importlib.import_module("triton_client_tpu.ops.flash_attention")
+    shapes = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in args[:3]]
+    text = fa._flash_call.trace(*shapes, *args[3:]).lower(
+        lowering_platforms=("tpu",)).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("args,digest", [
+    (((32, 16, 384, 64),) * 3 + (False, None, False, 1), "920bb8ba226acf28"),
+    (((2, 64, 8192, 192),) * 2 + ((2, 64, 8192, 128), True, 0.0625, False,
+                                  1), "6d65e2a02ce06eea"),
+    (((16, 32, 1024, 128),) + ((16, 4, 1024, 128),) * 2
+     + (True, None, False, 4), "2a6f355766521325"),
+    (((16, 32, 512, 64),) + ((16, 8, 512, 64),) * 2
+     + (True, None, False, 1), "e2cfe30e0c941c24"),
+    (((1, 32, 32768, 128),) + ((1, 4, 32768, 128),) * 2
+     + (True, None, False, 1), "7f16212206452420"),
+], ids=["bert_large", "kimi_k2", "sdar_30b_a3b", "lfm2_8b_a1b",
+        "trinity-full-layer"])
+def test_the_calls_without_a_window_lower_as_before(monkeypatch, args,
+                                                    digest):
+    """The whole-row and looped forms at the benchmark's shapes lower for
+    the chip to the text they lowered to before the windowed form existed
+    (the digests were read from the tree without it): a window of ``None``
+    leaves every existing caller's program as it was."""
+    assert _lowering_digest(monkeypatch, *args) == digest

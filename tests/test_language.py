@@ -11,7 +11,7 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from chipbench.tests import (tiny_hy4, tiny_kimi, tiny_lfm2,  # noqa: E402
-                             tiny_ouro, tiny_sdar)
+                             tiny_ouro, tiny_sdar, tiny_trinity)
 from triton_client_tpu.models import language  # noqa: E402
 
 MODELS = os.path.join(os.path.dirname(os.path.dirname(
@@ -22,11 +22,12 @@ TINY = {"kimi_k2": (tiny_kimi, "TINY_KIMI"),
         "sdar_30b_a3b": (tiny_sdar, "TINY_SDAR"),
         "ouro_2_6b": (tiny_ouro, "TINY_OURO"),
         "lfm2_8b_a1b": (tiny_lfm2, "TINY_LFM2"),
-        "hy4_preview": (tiny_hy4, "TINY_HY4")}
+        "hy4_preview": (tiny_hy4, "TINY_HY4"),
+        "trinity_mini": (tiny_trinity, "TINY_TRINITY")}
 
 #: the drawn blocks, which share ``parts`` and reach into no sibling
 BLOCKS = ("latent_moe", "block_diffusion", "looped", "hybrid_conv",
-          "sparse_latent")
+          "sparse_latent", "window_moe")
 
 
 @pytest.mark.parametrize("name", list(TINY))

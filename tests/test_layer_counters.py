@@ -55,7 +55,9 @@ EXTENSION = ("request",) + PER_ROW + (
     # greedy generation through two kinds of state (test_hybrid_conv.py)
     "decode_steps", "decode_tokens",
     # learned sparse attention (test_sparse_latent.py)
-    "dsa_queries", "dsa_pairs", "index_reused")
+    "dsa_queries", "dsa_pairs", "index_reused",
+    # sliding-window attention (test_window_moe.py)
+    "window_queries", "window_pairs")
 
 
 def _echo(sleep_s):

@@ -405,7 +405,8 @@ def _served_block(name: str, module: str, step: str, cfg, outputs,
     where the answer holds the device counters (the key of their dict, or
     ``{counter: key}``); they go to ``ModelStats`` with ``tokens_per_row``,
     the tokens a row of the batch passes the expert layers with.  The
-    batcher's buckets are ``max_batch_size`` and its half.  The step
+    batcher's buckets are ``max_batch_size`` and its half (one bucket at
+    a batch of one).  The step
     is ``module.step(params, tokens, cfg)``, one program a signature
     (``_LazyBlock``), and the cost analysis of a signature reads the program
     that ran it: it does not trace, lower and load the step a second time
@@ -419,7 +420,8 @@ def _served_block(name: str, module: str, step: str, cfg, outputs,
         inputs=[("INPUT_IDS", "INT32", [cfg.seq_len])],
         outputs=[spec[:3] for spec in outputs],
         max_batch_size=max_batch_size,
-        preferred_batch_sizes=[max_batch_size // 2, max_batch_size],
+        preferred_batch_sizes=sorted({max(1, max_batch_size // 2),
+                                      max_batch_size}),
         max_queue_delay_us=2000,
         instance_kind="KIND_TPU",
         parameters={"flops_per_inference": str(
@@ -552,6 +554,26 @@ def make_hy4_preview(cfg=None) -> JaxModel:
          ("ROUTES", "INT32", [cfg.expert_blocks, S,
                               cfg.num_experts_per_tok], "routes")],
         S, max_batch_size=2)
+
+
+def make_trinity_mini(cfg=None) -> JaxModel:
+    """Trinity-Mini's block on stage 0 of a pipeline over its 32 layers
+    (``window_moe.TRINITY_MINI_STAGE``; a test passes a tiny ``cfg``):
+    INT32 INPUT_IDS [S] → INT32 TOKENS [1] (the greedy next token), FP32
+    LOGITS [1, vocabulary] (its row) and INT32 ROUTES [expert layers, S, k]
+    (every token's experts, for a reference that recomputes the rows).  One
+    request is one prompt; a prefill pool answers it with the first token
+    (and a cache handed on, which this model does not keep)."""
+    if cfg is None:
+        from .window_moe import TRINITY_MINI_STAGE as cfg
+    S = cfg.seq_len
+    return _served_block(
+        "trinity_mini", "window_moe", "forward", cfg,
+        [("TOKENS", "INT32", [1], "tokens"),
+         ("LOGITS", "FP32", [1, cfg.vocab_size], "logits"),
+         ("ROUTES", "INT32", [cfg.n_expert_layers, S,
+                              cfg.num_experts_per_tok], "routes")],
+        S, max_batch_size=1)
 
 
 # Mixture-of-experts scorer: serves the flagship stack's MoE FFN path

@@ -2,14 +2,15 @@
 store, and the parts of a layer two or more of them run.
 
 A drawn block (``latent_moe``, ``block_diffusion``, ``looped``,
-``hybrid_conv``, ``sparse_latent``) holds weights made here in bfloat16 on
-the device, leaf by leaf: leaf ``name`` of layer ``l`` is a normal draw from
-``fold_in(fold_in(PRNGKey(weights_seed), l), LEAF_KEYS[name])``, a routed
-expert's under ``fold_in(that, expert id)``, and the embedding, final norm
-and head are the "layer" ``OUTER``.  The bf16 values are the checkpoint: a
-reference draws the same numbers from the same keys.  A new block adds its
-leaves' indices to ``LEAF_KEYS`` (and its matrices to ``INT8_CONTRACT``)
-and keeps its shapes, its norms and its stacks' layout to itself.
+``hybrid_conv``, ``sparse_latent``, ``window_moe``) holds weights made here
+in bfloat16 on the device, leaf by leaf: leaf ``name`` of layer ``l`` is a
+normal draw from ``fold_in(fold_in(PRNGKey(weights_seed), l),
+LEAF_KEYS[name])``, a routed expert's under ``fold_in(that, expert id)``,
+and the embedding, final norm and head are the "layer" ``OUTER``.  The
+bf16 values are the checkpoint: a reference draws the same numbers from the
+same keys.  A new block adds its leaves' indices to ``LEAF_KEYS`` (and its
+matrices to ``INT8_CONTRACT``) and keeps its shapes, its norms and its
+stacks' layout to itself.
 """
 
 from __future__ import annotations

@@ -452,6 +452,7 @@ def register_all(registry: ModelRegistry) -> None:
     registry.register_model(language.make_ouro_2_6b())
     registry.register_model(language.make_lfm2_8b_a1b())
     registry.register_model(language.make_hy4_preview())
+    registry.register_model(language.make_trinity_mini())
     from .decode import DecodeModel, make_llama_generate
 
     decode = DecodeModel()

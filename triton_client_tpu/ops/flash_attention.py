@@ -20,6 +20,12 @@ chip.  Two forms of one kernel, chosen from the shape (``_flash_call``):
   block on the diagonal (or the one the padding starts in) and no other,
   and takes the blocks before it four at a time (``_loop_plan`` says what
   a shape walks).  Operands are ``[B·H, S, D]``, k ``[B·H, D, S]``.
+* **windowed** (a causal mask that also hides the keys ``window`` or more
+  positions back: the sliding layers of a model that mixes them with full
+  ones): the looped form's walk cut to the window (``_window_call``, its
+  own jitted name).  A program reads from HBM only the keys of its q-block's
+  window, starts its walk there, takes the whole blocks in wide steps and
+  masks the window's left edge and the diagonal alone (``_window_plan``).
 
 ``flash_attention`` pads S to the block size and masks the padding away, so
 any sequence length works. On a TPU backend the kernel is compiled by
@@ -34,7 +40,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -43,14 +49,17 @@ _NEG_INF = -1e30
 
 
 def flash_attention_reference(q, k, v, *, causal: bool = True, sm_scale=None,
-                              mask_block: int = 1):
+                              mask_block: int = 1,
+                              window: Optional[int] = None):
     """Plain-jnp attention with the same signature/semantics as the kernel.
 
     q: [B, H, S, D]; k: [B, Hkv, S, D]; v: [B, Hkv, S, Dv] with ``H`` a
     multiple of ``Hkv`` (query head ``h`` reads key/value head ``h // (H //
     Hkv)``); returns [B, H, S, Dv] in q.dtype.  ``mask_block`` widens the
     causal mask to blocks: row ``i`` sees key ``j`` where ``j // mask_block
-    <= i // mask_block`` (1 is the plain causal mask).
+    <= i // mask_block`` (1 is the plain causal mask).  A ``window`` (causal
+    only) hides the keys that lie ``window`` or more positions back: row
+    ``i`` sees key ``j`` where ``i - window < j <= i``.
     """
     B, H, S, D = q.shape
     group = H // k.shape[1]
@@ -63,6 +72,8 @@ def flash_attention_reference(q, k, v, *, causal: bool = True, sm_scale=None,
     if causal:
         idx = jnp.arange(S)
         mask = (idx[:, None] | (mask_block - 1)) >= idx[None, :]
+        if window is not None:
+            mask = mask & (idx[:, None] - idx[None, :] < window)
         s = jnp.where(mask, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
@@ -119,6 +130,55 @@ def _loop_plan(S: int, D: int, Dv: int, dtype, causal: bool) -> _LoopPlan:
                      resident + 5 * scores + (4 << 20))
 
 
+class _WindowPlan(NamedTuple):
+    """What the windowed form does at one shape (a test pins it, PERF.md
+    quotes it); blocks are counted ``block`` keys wide."""
+    block: int          # query rows a program holds; keys of a narrow step
+    wide: int           # keys of a wide step, a multiple of ``block``
+    seq_pad: int        # S rounded up to the block
+    span: int           # keys a program reads from HBM: its window's blocks
+    walked: int         # k-blocks a head walks, all its q-blocks together
+    masked: int         # of them, those that take the mask (edge, diagonal)
+    vmem_bytes: int     # what the call asks the compiler to scope
+
+
+def _window_blocks(q_block, window: int, block: int):
+    """``(lo, whole_from)`` of the ``q_block``-th program: its walk covers
+    k-blocks ``lo .. q_block``; ``lo .. whole_from - 1`` hold the window's
+    left edge (keys some row of the block may not see), ``whole_from ..
+    q_block - 1`` are whole, ``q_block`` is the diagonal.  ``q_block`` may
+    be traced: the kernel, its index maps and the plan share this."""
+    big, small = ((jnp.maximum, jnp.minimum) if isinstance(q_block, jax.Array)
+                  else (max, min))
+    lo = big(0, q_block - -(-window // block))
+    return lo, small(big(lo, q_block + 1 - window // block), q_block)
+
+
+def _window_plan(S: int, D: int, Dv: int, dtype, window: int) -> _WindowPlan:
+    """The looped form's blocks; a wide step takes the whole blocks of a
+    full window at once, up to four (three at a window of four blocks),
+    and no more than S holds."""
+    block = 512 if S >= 1024 else 128
+    seq_pad = _round_up(S, block)
+    n = seq_pad // block
+    span = block * min(n, -(-window // block) + 1)
+    wide = block * max(1, min(4, window // block - 1, n - 1))
+    walked = masked = 0
+    for i in range(n):
+        lo, whole_from = _window_blocks(i, window, block)
+        walked += i - lo + 1
+        masked += whole_from - lo + 1
+    # the window's K and V and q, o by the block, each double-buffered in
+    # tiles of (16, 128); the scores of a wide step as the looped form has
+    itemsize = jnp.dtype(dtype).itemsize
+    resident = 2 * itemsize * (
+        _round_up(D, 16) * span + span * _round_up(Dv, 128)
+        + block * (_round_up(D, 128) + _round_up(Dv, 128)))
+    scores = block * wide * 4
+    return _WindowPlan(block, wide, seq_pad, span, walked, masked,
+                       resident + 5 * scores + (4 << 20))
+
+
 def _loop_kernel(q_ref, kt_ref, v_ref, o_ref, *, scale, causal, block, wide,
                  seq_len, n_blocks, mask_block=1):
     """Long rows: one (batch·head, q-block) program.  Refs carry a leading
@@ -166,7 +226,6 @@ def _loop_kernel(q_ref, kt_ref, v_ref, o_ref, *, scale, causal, block, wide,
     padded = n_blocks * block > seq_len
 
     def step(carry, start, width, masked):
-        m, l, acc = carry
         start = pl.multiple_of(start, width)
         s = jnp.dot(q, kt_ref[0, :, pl.ds(start, width)],
                     preferred_element_type=jnp.float32)  # [block, width]
@@ -182,22 +241,10 @@ def _loop_kernel(q_ref, kt_ref, v_ref, o_ref, *, scale, causal, block, wide,
                 valid = below if valid is None else jnp.logical_and(
                     valid, below)
             s = jnp.where(valid, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        # the row sums stay spread over the 128 lanes: one cross-lane
-        # reduction a program, at the end, and not one a step
-        spread = p[:, :128]
-        for t in range(1, width // 128):
-            spread = spread + p[:, t * 128:(t + 1) * 128]
-        v_blk = v_ref[0, pl.ds(start, width), :]
-        acc_new = corr * acc + jnp.dot(
-            p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32)
-        return m_new, corr * l + spread, acc_new
+        return _softmax_step(carry, s,
+                             lambda: v_ref[0, pl.ds(start, width), :])
 
-    carry = (jnp.full((block, 1), _NEG_INF, jnp.float32),
-             jnp.zeros((block, 128), jnp.float32),
-             jnp.zeros((block, v_ref.shape[-1]), jnp.float32))  # follows v
+    carry = _softmax_start(block, v_ref.shape[-1])
     whole = _whole_blocks(q_block, n_blocks, causal, padded)
     per_wide = wide // block
     n_wide = whole // per_wide
@@ -208,9 +255,90 @@ def _loop_kernel(q_ref, kt_ref, v_ref, o_ref, *, scale, causal, block, wide,
         lambda j, c: step(c, j * block, block, False), carry)
     if causal or padded:
         carry = step(carry, whole * block, block, True)
+    _softmax_end(carry, o_ref)
+
+
+def _softmax_start(block: int, dv: int):
+    """The online softmax's carry before the first step: the row maxima,
+    the row sums spread over 128 lanes, the accumulator (as wide as v)."""
+    return (jnp.full((block, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((block, 128), jnp.float32),
+            jnp.zeros((block, dv), jnp.float32))
+
+
+def _softmax_step(carry, s, values):
+    """The carry after a step's f32 scores ``s [block, width]``;
+    ``values()`` loads the step's ``[width, Dv]`` values, after the
+    exponentials."""
+    m, l, acc = carry
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    # the row sums stay spread over the 128 lanes: one cross-lane
+    # reduction a program, at the end, and not one a step
+    spread = p[:, :128]
+    for t in range(1, s.shape[1] // 128):
+        spread = spread + p[:, t * 128:(t + 1) * 128]
+    v_blk = values()
+    acc_new = corr * acc + jnp.dot(
+        p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32)
+    return m_new, corr * l + spread, acc_new
+
+
+def _softmax_end(carry, o_ref):
     _, l, acc = carry
     out = acc / jnp.sum(l, axis=-1, keepdims=True)
     o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _window_kernel(q_ref, kt_ref, v_ref, o_ref, *, scale, block, wide,
+                   window, seq_len, n_blocks):
+    """The windowed form: one (batch·key/value head, q-block, query head of
+    the group) program.  ``kt_ref [D, span]`` (k turned) and ``v_ref [span,
+    Dv]`` hold the keys of the q-block's window alone, from k-block ``lo``
+    on (``_window_blocks``); the query heads of a group run one after the
+    other over the same window, which is fetched once for them.  The walk
+    is the looped form's: the whole blocks first (``wide`` keys a step while
+    that many are left, then single blocks), each without iota, compare or
+    select; then the window's left edge and the diagonal, each masked by
+    ``i - window < j <= i`` (and ``j < S`` where S was padded).  A row that
+    sees no key of an edge block takes exponentials of 0 there, which the
+    diagonal's finite maximum (its own key) multiplies by exactly 0."""
+    pl = _pl()
+    q_block = pl.program_id(1)
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
+    padded = n_blocks * block > seq_len
+    lo, whole_from = _window_blocks(q_block, window, block)
+
+    def step(carry, j, width, masked):
+        start = pl.multiple_of((j - lo) * block, block)
+        s = jnp.dot(q, kt_ref[:, pl.ds(start, width)],
+                    preferred_element_type=jnp.float32)  # [block, width]
+        if masked:
+            k_idx = j * block + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            q_idx = q_block * block + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            valid = jnp.logical_and(k_idx <= q_idx, q_idx - k_idx < window)
+            if padded:
+                valid = jnp.logical_and(valid, k_idx < seq_len)
+            s = jnp.where(valid, s, _NEG_INF)
+        return _softmax_step(carry, s,
+                             lambda: v_ref[pl.ds(start, width), :])
+
+    carry = _softmax_start(block, v_ref.shape[-1])
+    per_wide = wide // block
+    n_wide = (q_block - whole_from) // per_wide
+    carry = jax.lax.fori_loop(
+        0, n_wide,
+        lambda t, c: step(c, whole_from + t * per_wide, wide, False), carry)
+    carry = jax.lax.fori_loop(
+        whole_from + n_wide * per_wide, q_block,
+        lambda j, c: step(c, j, block, False), carry)
+    carry = jax.lax.fori_loop(
+        lo, whole_from, lambda j, c: step(c, j, block, True), carry)
+    carry = step(carry, q_block, block, True)
+    _softmax_end(carry, o_ref)
 
 
 def _row_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, seq_len):
@@ -336,6 +464,58 @@ def _loop_call(q, k, v, causal, scale, interpret, mask_block=1):
     return out[:, :, :S, :] if Sp > S else out
 
 
+@functools.partial(jax.jit, static_argnames=("window", "sm_scale",
+                                             "interpret"))
+def _window_call(q, k, v, window, sm_scale, interpret):
+    """Causal attention in which row ``i`` sees keys ``i - window < j <=
+    i``: the windowed form at any shape, under a name of its own so that a
+    trace tells it from ``_flash_call``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, S, D = q.shape
+    Hkv, Dv = k.shape[1], v.shape[-1]
+    if H % Hkv or Hkv != v.shape[1]:
+        raise ValueError(f"{H} query heads over {Hkv} key and "
+                         f"{v.shape[1]} value heads")
+    if window < 1:
+        raise ValueError(f"window {window}: at least 1")
+    group = H // Hkv
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
+    plan = _window_plan(S, D, Dv, q.dtype, window)
+    blk, Sp, span = plan.block, plan.seq_pad, plan.span
+    if Sp > S:
+        zeros = [(0, 0), (0, 0), (0, Sp - S), (0, 0)]
+        q, k, v = (jnp.pad(x, zeros) for x in (q, k, v))
+    q, k, v = (x.reshape(-1, Sp, x.shape[-1]) for x in (q, k, v))
+
+    def first_key(qi):  # where the program's window starts, in keys
+        return _window_blocks(qi, window, blk)[0] * blk
+
+    heads = lambda b, qi, g: (b * group + g, qi, 0)  # noqa: E731
+    kernel = functools.partial(
+        _window_kernel, scale=scale, block=blk, wide=plan.wide,
+        window=window, seq_len=S, n_blocks=Sp // blk)
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((B * H, Sp, Dv), q.dtype),
+        grid=(B * Hkv, Sp // blk, group),
+        in_specs=[
+            pl.BlockSpec((1, blk, D), heads),
+            pl.BlockSpec((None, pl.Element(D), pl.Element(span)),
+                         lambda b, qi, g: (b, 0, first_key(qi))),
+            pl.BlockSpec((None, pl.Element(span), pl.Element(Dv)),
+                         lambda b, qi, g: (b, first_key(qi), 0)),
+        ],
+        out_specs=pl.BlockSpec((1, blk, Dv), heads),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=plan.vmem_bytes),
+        interpret=interpret,
+    )(q, k.transpose(0, 2, 1), v)
+    out = out.reshape(B, H, Sp, Dv)
+    return out[:, :, :S, :] if Sp > S else out
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "sm_scale", "interpret",
                                              "mask_block"))
 def _flash_call(q, k, v, causal, sm_scale, interpret, mask_block=1):
@@ -354,17 +534,23 @@ def _flash_call(q, k, v, causal, sm_scale, interpret, mask_block=1):
     return _loop_call(q, k, v, causal, scale, interpret, mask_block)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, sm_scale, interpret, mask_block):
-    return _flash_call(q, k, v, causal, sm_scale, interpret, mask_block)
+def _call(q, k, v, causal, sm_scale, interpret, mask_block, window):
+    if window is None:
+        return _flash_call(q, k, v, causal, sm_scale, interpret, mask_block)
+    return _window_call(q, k, v, window, sm_scale, interpret)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, interpret, mask_block):
-    return (_flash_call(q, k, v, causal, sm_scale, interpret, mask_block),
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, sm_scale, interpret, mask_block, window):
+    return _call(q, k, v, causal, sm_scale, interpret, mask_block, window)
+
+
+def _flash_fwd(q, k, v, causal, sm_scale, interpret, mask_block, window):
+    return (_call(q, k, v, causal, sm_scale, interpret, mask_block, window),
             (q, k, v))
 
 
-def _flash_bwd(causal, sm_scale, interpret, mask_block, res, g):
+def _flash_bwd(causal, sm_scale, interpret, mask_block, window, res, g):
     # Backward recomputes attention through the jnp reference and takes its
     # VJP — the standard flash trade (no stored [S,S] probabilities costs a
     # recompute); XLA fuses it into one fp32 pass.
@@ -372,7 +558,7 @@ def _flash_bwd(causal, sm_scale, interpret, mask_block, res, g):
     _, vjp = jax.vjp(
         lambda q_, k_, v_: flash_attention_reference(
             q_, k_, v_, causal=causal, sm_scale=sm_scale,
-            mask_block=mask_block),
+            mask_block=mask_block, window=window),
         q, k, v)
     return vjp(g)
 
@@ -381,16 +567,18 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, sm_scale=None,
-                    mask_block: int = 1, interpret: bool = False,
-                    force: bool = False):
+                    mask_block: int = 1, window: Optional[int] = None,
+                    interpret: bool = False, force: bool = False):
     """Attention over [B, H, S, D] tensors without the scores in HBM;
     differentiable.  ``v`` may have another width than ``q`` and ``k``; the
     output and the VJP follow it.  ``k`` and ``v`` may have fewer heads than
     ``q`` (grouped queries: head ``h`` reads ``h // (H // Hkv)``), and a
     causal mask may run over blocks of ``mask_block`` rows (a power of two;
-    row ``i`` sees key ``j`` where ``j // mask_block <= i // mask_block``).
-    Block shapes and the kernel's form (whole-row or looped) follow the
-    operands: see :func:`_flash_call`.
+    row ``i`` sees key ``j`` where ``j // mask_block <= i // mask_block``)
+    or be cut to a ``window`` (row ``i`` sees key ``j`` where ``i - window <
+    j <= i``: the windowed form, :func:`_window_call`).  Block shapes and
+    the kernel's form (whole-row or looped) follow the operands: see
+    :func:`_flash_call`.
 
     On a TPU backend (or with ``force``) this runs the compiled pallas
     kernel, and a Mosaic refusal raises — no fallback.  Off TPU, with
@@ -398,8 +586,11 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale=None,
     kernel cannot compile there); ``interpret`` runs the kernel in the
     pallas interpreter — slow, for tests.
     """
+    if window is not None and (not causal or mask_block != 1):
+        raise ValueError("a window cuts the plain causal mask: causal, "
+                         "mask_block 1")
     if not (interpret or force) and jax.default_backend() != "tpu":
         return flash_attention_reference(q, k, v, causal=causal,
                                          sm_scale=sm_scale,
-                                         mask_block=mask_block)
-    return _flash(q, k, v, causal, sm_scale, interpret, mask_block)
+                                         mask_block=mask_block, window=window)
+    return _flash(q, k, v, causal, sm_scale, interpret, mask_block, window)

@@ -214,6 +214,9 @@ class ModelStats:
     dsa_queries: int = 0          # query rows x attention blocks
     dsa_pairs: int = 0            # (query, key) pairs those rows attended
     index_reused: int = 0         # query rows x blocks that reused a choice
+    # sliding-window attention (models/window_moe.py):
+    window_queries: int = 0       # query rows x windowed layers
+    window_pairs: int = 0         # (query, key) pairs those layers attended
     lock: threading.Lock = field(default_factory=threading.Lock)
     # steps whose counters are still on the device: ({name: array with a
     # leading axis of batch rows}, real rows, tokens a row)
@@ -359,7 +362,8 @@ class ModelStats:
                 counts.sum(axis=0).max(axis=-1).sum())
         elif name in ("denoise_passes", "denoise_tokens", "loop_steps",
                       "loop_tokens", "decode_steps", "decode_tokens",
-                      "dsa_queries", "dsa_pairs", "index_reused"):
+                      "dsa_queries", "dsa_pairs", "index_reused",
+                      "window_queries", "window_pairs"):
             # a count a row [rows]
             setattr(self, name, getattr(self, name) + int(counts.sum()))
         elif name == "experts_touched":
@@ -410,6 +414,8 @@ class ModelStats:
             "dsa_queries": {"count": self.dsa_queries, "ns": 0},
             "dsa_pairs": {"count": self.dsa_pairs, "ns": 0},
             "index_reused": {"count": self.index_reused, "ns": 0},
+            "window_queries": {"count": self.window_queries, "ns": 0},
+            "window_pairs": {"count": self.window_pairs, "ns": 0},
         }
 
 
